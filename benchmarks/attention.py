@@ -40,10 +40,9 @@ def main():
     args = ap.parse_args()
     if args.window is not None and args.window < 1:
         raise SystemExit(f"--window must be >= 1, got {args.window}")
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(args.world)
+    select_platform(args.platform, args.world)
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P

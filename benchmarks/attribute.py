@@ -41,7 +41,9 @@ def log(*a):
 
 def build_args(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default=None)
+    ap.add_argument(
+        "--platform", default=os.environ.get("TPU_DIST_PLATFORM")
+    )
     ap.add_argument(
         "--programs", default="engine_dp_fsdp_int8",
         help="comma-separated canonical analysis programs to attribute "
@@ -212,15 +214,9 @@ def main(argv=None) -> int:
         args.iters = min(args.iters, 2)
         args.warmup = 1
         args.stages, args.vocab, args.dim, args.seq = 3, 128, 16, 8
-    n_devices = 8
-    if args.platform == "cpu" or os.environ.get("TPU_DIST_PLATFORM") == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(n_devices)
-    else:
-        from tpu_dist.utils.platform import pin_cpu_if_backend_dead
-
-        pin_cpu_if_backend_dead(n_devices)
+    select_platform(args.platform, 8)
 
     errors: list[str] = []
     reports = []

@@ -41,14 +41,9 @@ def main():
     )
     args = ap.parse_args()
     n_sim = 8 if args.mode != "dense" else None  # sharded smoke needs a mesh
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(n_sim)
-    elif args.platform is None:
-        from tpu_dist.utils.platform import pin_cpu_if_backend_dead
-
-        pin_cpu_if_backend_dead(n_sim)
+    select_platform(args.platform, n_sim)
 
     import jax
 

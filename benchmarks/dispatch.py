@@ -147,10 +147,9 @@ def _sweep_row(
 
 def main(argv=None):
     args = build_args(argv)
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu()
+    select_platform(args.platform)
     import jax
 
     from tpu_dist import comm

@@ -53,10 +53,9 @@ def main():
         help="also sweep bucketed int8 over 1/4/16 MB buckets",
     )
     args = ap.parse_args()
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(args.world)
+    select_platform(args.platform, args.world)
     import jax
     import jax.numpy as jnp
 

@@ -250,14 +250,9 @@ def run(args) -> dict:
 
 def main():
     args = build_args()
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(max(8, args.world))
-    elif args.platform is None:
-        from tpu_dist.utils.platform import pin_cpu_if_backend_dead
-
-        pin_cpu_if_backend_dead(max(8, args.world))
+    select_platform(args.platform, max(8, args.world))
     print(json.dumps(run(args)))
 
 

@@ -35,14 +35,9 @@ def main():
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args()
 
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(8)
-    elif args.platform is None:
-        from tpu_dist.utils.platform import pin_cpu_if_backend_dead
-
-        pin_cpu_if_backend_dead(8)
+    select_platform(args.platform, 8)
 
     import jax
     import jax.numpy as jnp

@@ -71,10 +71,9 @@ def main():
     ap.add_argument("--bucket-mb", type=int, default=4)
     ap.add_argument("--no-persist", action="store_true")
     args = ap.parse_args()
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(args.world)
+    select_platform(args.platform, args.world)
     import jax
     import numpy as np
     from jax.sharding import PartitionSpec as P

@@ -133,10 +133,9 @@ def main():
     ap.add_argument("--max-world", type=int, default=None)
     ap.add_argument("--model", default="mnist", help="mnist | resnet18 | vit")
     args = ap.parse_args()
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu(args.max_world or 8)
+    select_platform(args.platform, args.max_world or 8)
     import jax
 
     n_dev = len(jax.devices(args.platform) if args.platform else jax.devices())

@@ -248,14 +248,9 @@ def main():
                     default=["continuous", "static"],
                     choices=["continuous", "static"])
     args = ap.parse_args()
-    if args.platform == "cpu":
-        from tpu_dist.utils.platform import pin_cpu
+    from tpu_dist.utils.platform import select_platform
 
-        pin_cpu()
-    elif args.platform is None:
-        from tpu_dist.utils.platform import pin_cpu_if_backend_dead
-
-        pin_cpu_if_backend_dead()
+    select_platform(args.platform)
 
     import jax
 

@@ -94,21 +94,29 @@ def test_device_memory_stats_shape():
     assert stats is None or "bytes_in_use" in stats
 
 
-def test_peak_tables_use_longest_prefix_match():
-    """ADVICE r3: 'TPU v5 lite' must win over 'TPU v5' for a v5e part
-    regardless of dict insertion order."""
-    from tpu_dist.train import flops
+def test_peak_table_is_exact_and_unknown_tpu_raises():
+    """One table keyed by the exact device_kind: the v5e's kind is
+    'TPU v5 lite', 'TPU v5' is the v5p, and a TPU kind that is not in the
+    table raises instead of inheriting a prefix's peak (or silently
+    losing its MFU).  Off-TPU there is no peak: None."""
 
     class FakeDev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
-    assert flops.peak_flops(FakeDev("TPU v5 lite")) == 197e12
+    v5e = flops.chip_spec(FakeDev("TPU v5 lite"))
+    assert (v5e.peak_bf16_flops, v5e.hbm_bytes_per_s, v5e.hbm_bytes) == (
+        197e12, 819e9, 16e9,
+    )
+    assert "TPU v5e" in v5e.source
     assert flops.peak_flops(FakeDev("TPU v5")) == 459e12
-    assert flops.hbm_bandwidth(FakeDev("TPU v5 lite")) == 819e9
-    assert flops.hbm_bandwidth(FakeDev("TPU v5p")) == 2765e9
-    # order-independence: a reversed table gives the same answers
-    reversed_table = dict(reversed(list(flops._PEAK_BF16.items())))
-    assert flops._longest_prefix_match(reversed_table, "TPU v5 lite") == 197e12
-    assert flops._longest_prefix_match(reversed_table, "TPU v5") == 459e12
-    assert flops._longest_prefix_match(reversed_table, "Unknown chip") is None
+    assert flops.hbm_bandwidth(FakeDev("TPU v5")) == 2765e9
+    for kind in ("TPU v9", "TPU v5 lite pod", "TPU v5e"):
+        with pytest.raises(KeyError, match="no published peaks"):
+            flops.peak_flops(FakeDev(kind))
+        with pytest.raises(KeyError, match="no published peaks"):
+            flops.hbm_bandwidth(FakeDev(kind))
+    assert flops.peak_flops(FakeDev("cpu", platform="cpu")) is None
+    assert flops.peak_flops(jax.devices("cpu")[0]) is None
+    assert flops.hbm_bandwidth(jax.devices("cpu")[0]) is None

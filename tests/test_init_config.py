@@ -30,35 +30,74 @@ class TestInitConfig:
         assert cfg.num_processes is None
         assert cfg.process_id is None
 
-    def test_compile_cache_env_wires_jax_and_emits_telemetry(
+    def test_compile_cache_env_set_leaves_config_untouched(
         self, monkeypatch, tmp_path
     ):
-        """TPU_DIST_COMPILE_CACHE=<dir> via init(): jax persists compiled
-        programs there, and a second compile of the same program is a
-        cache HIT surfaced as a compile_cache event."""
-        import importlib
+        """JAX_COMPILATION_CACHE_DIR set: the operator owns the location.
+        The helper names it and sets NOTHING in code — no directory, no
+        thresholds."""
+        from tpu_dist.utils import platform as platform_mod
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        monkeypatch.setattr(platform_mod, "_cache_listener_installed", True)
+        keys = (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs",
+        )
+        before = {k: getattr(jax.config, k) for k in keys}
+        assert platform_mod.setup_compile_cache() == str(tmp_path / "c")
+        assert {k: getattr(jax.config, k) for k in keys} == before
+
+    def test_compile_cache_default_is_fixed_path_under_checkout(
+        self, monkeypatch, tmp_path
+    ):
+        """Env unset: the cache is at <checkout>/.jax_cache on every
+        call (the directory is part of the cache key — a path built from
+        a temp name, pid or time never hits), thresholds at JAX's
+        defaults; a second compile of the same program is a cache HIT
+        surfaced as a compile_cache event and a registry counter."""
         import os
+        from pathlib import Path
 
-        init_mod = importlib.import_module("tpu_dist.comm.init")
-        from tpu_dist.observe import events
+        from jax._src import compilation_cache as _cc
 
-        cache_dir = tmp_path / "xla_cache"
+        from tpu_dist.observe import events, registry
+        from tpu_dist.utils import platform as platform_mod
+
+        repo = Path(__file__).resolve().parent.parent
+        assert platform_mod.DEFAULT_COMPILE_CACHE == repo / ".jax_cache"
+        # the test must not fill the checkout's real cache (the chip tool
+        # copies the tree as it stands): same code path, scratch target
+        cache_dir = tmp_path / ".jax_cache"
+        monkeypatch.setattr(platform_mod, "DEFAULT_COMPILE_CACHE", cache_dir)
+        monkeypatch.setattr(platform_mod, "_cache_listener_installed", False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         tdir = tmp_path / "telemetry"
-        monkeypatch.setenv(init_mod.ENV_COMPILE_CACHE, str(cache_dir))
         monkeypatch.setenv(events.ENV_DIR, str(tdir))
         monkeypatch.delenv(events.ENV_RUN_ID, raising=False)
-        monkeypatch.setattr(init_mod, "_compile_cache_dir", None)
-        prev_entry = jax.config.jax_persistent_cache_min_entry_size_bytes
         prev_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+        hits = registry.REGISTRY.counter("tpu_dist_compile_cache_hits_total")
+        hits_before = hits.value()
         try:
-            assert init_mod._setup_compile_cache() == str(cache_dir)
+            assert platform_mod.setup_compile_cache() == str(cache_dir)
+            assert platform_mod.setup_compile_cache() == str(cache_dir)
+            assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+            assert (
+                jax.config.jax_persistent_cache_min_compile_time_secs
+                == prev_secs
+            ), "thresholds stay at JAX's defaults"
+            # the default threshold declines sub-second programs; lower
+            # it HERE (test only) so a tiny program exercises the wiring
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0
+            )
             # two distinct jit objects over the same program: the second
             # compile must be served from the persistent cache
             jax.jit(lambda x: x * 3 + 1)(jnp.ones(8)).block_until_ready()
-            assert any(
-                n.endswith("-cache") for n in os.listdir(cache_dir)
-            ), "no compiled program persisted"
+            assert os.listdir(cache_dir), "no compiled program persisted"
             jax.jit(lambda x: x * 3 + 1)(jnp.ones(8)).block_until_ready()
+            assert hits.value() > hits_before
             recs = events.read_events(str(tdir))
             outcomes = {
                 r["outcome"] for r in recs if r["event"] == "compile_cache"
@@ -67,19 +106,14 @@ class TestInitConfig:
             n, errors = events.validate_dir(str(tdir))
             assert errors == []
         finally:
-            # Full de-pollution: cache off, thresholds restored, the
-            # memoized tmp-dir cache dropped, and the hit/miss listener
+            # Full de-pollution: cache off, threshold restored, the
+            # memoized cache dropped, and the hit/miss listener
             # unregistered so later tests' event files stay clean.
             jax.config.update("jax_compilation_cache_dir", None)
             jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", prev_entry
-            )
-            jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", prev_secs
             )
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()  # drop the memoized tmp-dir cache
+            _cc.reset_cache()
             jax.monitoring.clear_event_listeners()
 
     def test_file_init_rejects_multihost_master_addr(self, monkeypatch, tmp_path):
@@ -98,6 +132,27 @@ class TestInitConfig:
         monkeypatch.setattr(init_mod, "_initialized", False)
         with pytest.raises(ValueError, match="single-host only"):
             comm.init(num_processes=2, process_id=0)
+
+    def test_launchers_refuse_multiprocess_off_cpu(self, monkeypatch, capsys):
+        """`comm.launch` and `python -m tpu_dist.run` put every child on
+        THIS host — the CPU loopback harness.  With world > 1 and any
+        other platform each child would claim every chip and the gang
+        would hang, so both refuse up front and say what to do."""
+        import pytest
+
+        from tpu_dist import run as run_mod
+
+        for platform in ("tpu", None):
+            with pytest.raises(ValueError, match="ONE process per host"):
+                comm.launch(print, 2, platform=platform)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.delenv("TPU_DIST_PLATFORM", raising=False)
+        with pytest.raises(SystemExit) as exc:
+            run_mod.main(["--nproc", "2", "nonexistent.py"])
+        assert exc.value.code == 2
+        assert "CPU loopback harness" in capsys.readouterr().err
+        with pytest.raises(SystemExit):
+            run_mod.main(["--nproc", "2", "--platform", "tpu", "x.py"])
 
     def test_addr_without_port_ignored(self, monkeypatch):
         monkeypatch.setenv("MASTER_ADDR", "10.0.0.1")

@@ -321,8 +321,8 @@ def test_dispatch_bench_smoke():
     spec = importlib.util.spec_from_file_location("_bench_dispatch", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    out = mod.main(["--steps", "4", "--warmup", "1", "--repeats", "1",
-                    "--batch", "32", "--ks", "1,2"])
+    out = mod.main(["--platform", "cpu", "--steps", "4", "--warmup", "1",
+                    "--repeats", "1", "--batch", "32", "--ks", "1,2"])
     assert out["metric"] == "dispatch_pipeline_samples_per_sec"
     assert set(out["rows"]) == {"parity", "latency"}
     for row in out["rows"].values():

@@ -29,11 +29,7 @@ hand-rolled ring allreduce             ``parallel.ring_all_reduce`` (+ chunked)
 =====================================  ========================================
 """
 
-from tpu_dist.utils import compat as _compat
-
-_compat.install()
-
-from tpu_dist import (  # noqa: E402
+from tpu_dist import (
     comm,
     data,
     export,

@@ -21,7 +21,7 @@ import sys
 # hardware is never needed — plans are compile-time artifacts.
 from tpu_dist.utils.platform import pin_cpu  # noqa: E402
 
-pin_cpu(8, opt_out_env="TPU_DIST_ANALYZE_TPU")
+pin_cpu(8)
 
 import argparse  # noqa: E402
 import json  # noqa: E402

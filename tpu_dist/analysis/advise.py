@@ -286,7 +286,7 @@ def main(argv=None) -> int:
     # bootstrap, sized by --chips so `make advise WORLD=16` works).
     from tpu_dist.utils.platform import pin_cpu
 
-    pin_cpu(max(8, args.chips), opt_out_env="TPU_DIST_ANALYZE_TPU")
+    pin_cpu(max(8, args.chips))
     return run_advise(args)
 
 

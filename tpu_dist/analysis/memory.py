@@ -318,7 +318,7 @@ def main(argv=None) -> int:
 
     # Same bootstrap as the collective analyzer: plans are compile-time
     # artifacts, so the 8-device CPU-sim mesh is always enough.
-    pin_cpu(8, opt_out_env="TPU_DIST_ANALYZE_TPU")
+    pin_cpu(8)
 
     from tpu_dist.analysis import programs as prog_mod
     from tpu_dist.observe import events as ev_mod

@@ -259,6 +259,11 @@ _OP_RE = re.compile(
     rf"({'|'.join(COLLECTIVE_OPS)})(?:-start)?\("
 )
 _OPERAND_RE = re.compile(r"([a-z][a-z0-9]*)\[([\d,]*)\]")
+# ``%name = <shape> op(...)`` — XLA (jaxlib 0.9) prints operands by NAME
+# only (``all-reduce(%param.1)``), so operand shapes come from the
+# instruction that defined the name.
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(\([^)]*\)|\S+)\s")
+_NAME_RE = re.compile(r"%[\w.\-]+")
 _GROUPS_RE = re.compile(
     r"replica_groups=(\{\{[\d,{} ]*\}\}|"
     r"\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)"
@@ -347,13 +352,23 @@ def parse_hlo_collectives(
     and the ``-start`` half of async pairs (never the ``-done`` half)."""
     index = _MeshIndex(mesh) if mesh is not None else None
     out = []
+    defs: dict[str, str] = {}  # instruction name -> its shape text
     for line in hlo_text.splitlines():
+        if line.rstrip().endswith("{"):
+            defs = {}  # a new computation: names are scoped to it
+        d = _DEF_RE.match(line)
+        if d is not None:
+            defs[d.group(1)] = d.group(2)
         m = _OP_RE.search(line)
         if m is None:
             continue
         kind = m.group(1)
         operands = line[m.end():]
         operands = operands[: operands.find(")")]
+        if not _OPERAND_RE.search(operands):
+            operands = " ".join(
+                defs.get(name, "") for name in _NAME_RE.findall(operands)
+            )
         parsed = [
             (dt, _parse_shape(dims))
             for dt, dims in _OPERAND_RE.findall(operands)

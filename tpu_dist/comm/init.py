@@ -97,81 +97,6 @@ def _addr_is_remote(addr: str) -> bool:
     return True
 
 
-ENV_COMPILE_CACHE = "TPU_DIST_COMPILE_CACHE"
-
-_compile_cache_dir: str | None = None
-
-
-def _setup_compile_cache() -> str | None:
-    """Wire the persistent XLA compilation cache from the environment.
-
-    ``TPU_DIST_COMPILE_CACHE=<dir>`` points JAX's
-    ``jax_compilation_cache_dir`` at a durable directory, so a restarted
-    job (preemption resume, the gang supervisor's relaunch, a re-run
-    bench) pays compile time once instead of on every boot — at pod
-    scale XLA compilation is minutes of lost goodput per restart.  The
-    entry-size/compile-time thresholds are zeroed because our hottest
-    restart path is the LATENCY-bound parity workload, whose small fast
-    programs the defaults would decline to cache.
-
-    Every cache hit/miss surfaces as telemetry: a ``compile_cache``
-    event (when ``TPU_DIST_TELEMETRY`` is set) and the
-    ``tpu_dist_compile_cache_{hits,misses}_total`` registry counters,
-    via a `jax.monitoring` listener.  Idempotent — the FIRST configured
-    dir wins for the process lifetime (a later env change is not
-    honored; the return value always names the dir actually in effect).
-    Returns None when the env var is unset."""
-    global _compile_cache_dir
-    path = os.environ.get(ENV_COMPILE_CACHE)
-    if not path:
-        return None
-    if _compile_cache_dir is not None:
-        return _compile_cache_dir
-    _compile_cache_dir = path
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        # jax memoizes its is-the-cache-used decision at the FIRST
-        # compile of the process: if anything compiled before init()
-        # (a backend probe, an earlier fit), the new dir would be
-        # silently ignored without this reset.  Private API, so degrade
-        # to "cache from next process" if it moves.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
-
-    from tpu_dist.observe import events as events_mod
-    from tpu_dist.observe import registry
-
-    hits = registry.REGISTRY.counter(
-        "tpu_dist_compile_cache_hits_total",
-        "XLA programs loaded from the persistent compilation cache",
-    )
-    misses = registry.REGISTRY.counter(
-        "tpu_dist_compile_cache_misses_total",
-        "XLA programs compiled and written to the persistent cache",
-    )
-
-    def _listen(event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            hits.inc()
-            events_mod.from_env().emit(
-                "compile_cache", outcome="hit", dir=path
-            )
-        elif event == "/jax/compilation_cache/cache_misses":
-            misses.inc()
-            events_mod.from_env().emit(
-                "compile_cache", outcome="miss", dir=path
-            )
-
-    jax.monitoring.register_event_listener(_listen)
-    return path
-
-
 _initialized = False
 
 
@@ -191,10 +116,6 @@ def init(
     wraps ``jax.distributed.initialize``, the rendezvous of tuto.md:404-419.
     """
     global _initialized
-    # Persistent compile cache rides every init flavor, including the
-    # single-process no-op path (it only touches jax.config, which is
-    # safe before OR after backend initialization).
-    _setup_compile_cache()
     env = InitConfig.from_env()
     cfg = InitConfig(
         coordinator_address=coordinator_address or env.coordinator_address,
@@ -202,6 +123,15 @@ def init(
         process_id=process_id if process_id is not None else env.process_id,
         platform=platform or env.platform,
     )
+    if cfg.platform != "cpu":
+        # Persistent compile cache on every init flavor that may compile
+        # for the chip, including the single-process no-op path (it only
+        # touches jax.config, safe before OR after backend init).  A
+        # restarted job (preemption resume, the gang supervisor's
+        # relaunch) then pays compile time once, not on every boot.
+        from tpu_dist.utils.platform import setup_compile_cache
+
+        setup_compile_cache()
     if _initialized:
         return cfg
     if cfg.platform is not None:

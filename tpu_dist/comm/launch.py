@@ -10,9 +10,14 @@ multi-process path does the native C++ rendezvous (startup barrier + rank
 assignment, `tpu_dist.runtime`) and then ``jax.distributed.initialize`` —
 and finally calls ``fn(rank, world)``.
 
-This is the path that scales to one-process-per-TPU-host pods; the same
-launcher with ``platform='cpu'`` is the loopback development harness (the
-reference's fork-over-loopback strategy, SURVEY.md §4.2).  The external
+`launch` is the LOOPBACK development harness (the reference's
+fork-over-loopback strategy, SURVEY.md §4.2): every child lands on THIS
+host, so ``world > 1`` needs ``platform='cpu'``.  A TPU chip belongs to
+one process at a time and the children are given no per-child device
+visibility — on a TPU host every child would claim every chip and the
+gang would hang — so that combination is refused up front.  On real
+hardware the model is one process per TPU HOST driving all its chips:
+run the script once per host with the env contract set.  The external
 ``mpirun``-style launch (tuto.md:393-398) is covered by setting the env
 vars outside and calling ``init()`` with no arguments (rank -1 lets the
 native rendezvous assign one, mirroring rank-less MPI init,
@@ -139,6 +144,7 @@ def launch(
     from tpu_dist.observe import events as events_mod
     from tpu_dist.resilience.retry import WorkerFailed, logger
 
+    refuse_multiprocess_off_cpu("comm.launch", world, platform)
     # The gang supervisor's own event stream (events_supervisor.jsonl):
     # restarts and final failure become machine-parseable records instead
     # of vanishing into stderr.  NULL logger when telemetry is off.
@@ -196,6 +202,25 @@ def launch(
             )
     assert last_error is not None
     raise last_error
+
+
+def refuse_multiprocess_off_cpu(
+    who: str, world: int, platform: str | None
+) -> None:
+    """`launch` and ``python -m tpu_dist.run`` fork ``world`` processes
+    onto ONE host.  That is sound on the CPU platform only: a TPU chip
+    belongs to one process at a time, the children get no per-child
+    device visibility, and a second child that reaches for the chips
+    fails or hangs.  Refuse, and say what to do instead."""
+    if world > 1 and platform != "cpu":
+        raise ValueError(
+            f"{who}: {world} processes on one host is the CPU loopback "
+            f"harness, but the platform is {platform or 'the default backend'!r}"
+            " — every child would claim every local chip.  Pass "
+            "platform='cpu' (CLI: --platform cpu) for the simulation; on "
+            "TPU hardware run ONE process per host (it drives all the "
+            "host's chips) with MASTER_ADDR/MASTER_PORT/WORLD_SIZE/RANK set"
+        )
 
 
 def _reprobe_world(

@@ -65,12 +65,11 @@ def dot_product_attention(
             and mask is None  # kernel has no arbitrary-mask path
         )
         if eligible:
-            from tpu_dist.ops.flash_attention import flash_attention
+            from tpu_dist import ops
 
-            interp = jax.default_backend() != "tpu"
-            return flash_attention(
-                q, k, v, causal=causal, bq=bq, bk=bk, interpret=interp,
-                window=window,
+            return ops.kernel_for_platform(
+                ops.flash_attention, q, k, v,
+                causal=causal, bq=bq, bk=bk, window=window,
             )
         # fall through to the dense path for shapes the kernel can't take
         # (cross-attention, indivisible block sizes, short sequences)

@@ -45,15 +45,13 @@ class Dense(Module):
         # Optional hand-tuned path: fused pallas matmul (+bias) kernel for
         # 2-D activations (TPU_DIST_PALLAS_DENSE=1); default is XLA's dot,
         # which it tiles onto the MXU itself.
-        from tpu_dist.ops.matmul import use_pallas_dense
+        from tpu_dist import ops
 
-        if self.use_bias and x.ndim == 2 and use_pallas_dense():
-            import jax as _jax
-
-            from tpu_dist.ops.matmul import matmul
-
-            interp = _jax.default_backend() != "tpu"
-            return matmul(x, params["w"], params["b"], interpret=interp), state
+        if self.use_bias and x.ndim == 2 and ops.use_pallas_dense():
+            y = ops.kernel_for_platform(
+                ops.matmul, x, params["w"], params["b"]
+            )
+            return y, state
         y = x @ params["w"]
         if self.use_bias:
             y = y + params["b"]

@@ -10,13 +10,12 @@ inter-chip DMAs (`make_async_remote_copy` over ICI), with neighbor
 barriers and double-buffered communication slots, per the TPU kernel
 playbook (/opt/skills/guides/pallas_guide.md, "Ring Collectives").
 
-COMPILED execution needs ≥2 real TPU chips (those tests carry the
-``tpu`` marker; on other platforms `ring_all_reduce_pallas` falls back
-to the ppermute ring so callers can use one entry point).  The kernel
-itself, though, is exercised EVERYWHERE: Pallas's TPU interpret mode
+COMPILED execution needs ≥2 real TPU chips (``chip_smoke.py`` checks it
+against ``lax.psum`` on all chips of the host).  Off-TPU the kernel runs
+only when ASKED to, with ``interpret=True``: Pallas's TPU interpret mode
 (`pltpu.InterpretParams`) simulates the DMA semaphores and remote copies
-across the CPU-sim mesh, so the un-gated tests run the real kernel body
-— barriers, double buffering, RDMA ordering — and cross-check it against
+across the CPU-sim mesh, so the tests run the real kernel body —
+barriers, double buffering, RDMA ordering — and cross-check it against
 ``lax.psum`` (tests/test_ops.py::TestPallasRing).
 """
 
@@ -30,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpu_dist.comm.mesh import DEFAULT_AXIS
-from tpu_dist.parallel.ring import ring_all_reduce_chunked
 
 
 def _ring_kernel(x_ref, o_ref, comm_buf, send_sem, recv_sem, *, axis_name):
@@ -78,15 +76,6 @@ def _ring_kernel(x_ref, o_ref, comm_buf, send_sem, recv_sem, *, axis_name):
     lax.fori_loop(0, n - 1, step_body, None)
 
 
-def tpu_interpret_supported() -> bool:
-    """Whether this jax ships Pallas's TPU interpret simulator
-    (`pltpu.InterpretParams`, jax >= 0.5) — the mode that simulates DMA
-    semaphores and remote copies on CPU devices.  Older jax only has the
-    generic HLO interpreter, which cannot execute the inter-chip RDMA
-    primitives this kernel is made of."""
-    return hasattr(pltpu, "InterpretParams")
-
-
 def _pallas_ring(
     x: jax.Array, axis_name: str, collective_id: int, *,
     interpret: bool = False,
@@ -94,17 +83,7 @@ def _pallas_ring(
     """``interpret=True`` runs the kernel under Pallas's TPU interpret
     mode (`pltpu.InterpretParams`), which SIMULATES the semaphores and
     inter-chip RDMAs on CPU devices — the same kernel body, exercised
-    without hardware (tests/test_ops.py runs it on the CPU-sim mesh and
-    cross-checks against psum).  Raises `NotImplementedError` on jax
-    builds without the simulator (see `tpu_interpret_supported`) rather
-    than tripping an AttributeError mid-trace."""
-    if interpret and not tpu_interpret_supported():
-        raise NotImplementedError(
-            "Pallas TPU interpret mode (pltpu.InterpretParams) is not "
-            f"available in jax {jax.__version__}; the RDMA ring kernel "
-            "can only be simulated on jax >= 0.5 (compiled execution "
-            "still needs >= 2 real TPU chips)"
-        )
+    without hardware."""
     return pl.pallas_call(
         functools.partial(_ring_kernel, axis_name=axis_name),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -127,33 +106,16 @@ def ring_all_reduce_pallas(
     collective_id: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
-    """Ring all-reduce via explicit RDMA when running on ≥2 TPU chips;
-    falls back to the ppermute ring elsewhere (CPU execution has no real
-    inter-chip DMA).  The fallback WARNS loudly so a benchmark or test
-    can never silently report "RDMA kernel" numbers that ran the
-    ppermute path instead.  Call inside shard_map over ``axis_name``
-    (which must be the mesh's only axis for LOGICAL device ids to equal
-    ring positions).
+    """Ring all-reduce via explicit inter-chip RDMA.  Call inside
+    shard_map over ``axis_name`` (which must be the mesh's only axis for
+    LOGICAL device ids to equal ring positions).
 
-    ``interpret=True`` runs the ACTUAL kernel (semaphores, remote
-    copies) under Pallas's TPU interpret simulator on any platform — no
-    fallback, no warning; how the kernel is exercised without hardware.
+    ``interpret=False`` compiles the kernel for the TPU; lowered for any
+    other platform Pallas raises — there is no silent substitute
+    (`parallel.ring_all_reduce` is the portable ppermute ring, and a
+    caller that wants it calls it).
+    ``interpret=True`` runs the ACTUAL kernel (semaphores, remote copies)
+    under Pallas's TPU interpret simulator on any platform — how the
+    kernel is exercised without hardware.
     """
-    import warnings
-
-    if interpret:
-        return _pallas_ring(x, axis_name, collective_id, interpret=True)
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:  # pragma: no cover
-        platform = "cpu"
-    if platform != "tpu":
-        warnings.warn(
-            f"ring_all_reduce_pallas: not on TPU (platform={platform!r}) — "
-            f"falling back to the ppermute ring; any numbers produced are "
-            f"NOT RDMA-kernel numbers",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return ring_all_reduce_chunked(x, axis_name)
-    return _pallas_ring(x, axis_name, collective_id)
+    return _pallas_ring(x, axis_name, collective_id, interpret=interpret)

@@ -1099,14 +1099,18 @@ def make_partitioned_train_step(
         if model_axes:
             m_ax = model_axes if len(model_axes) > 1 else model_axes[0]
             inner_res_spec = P(None, None, m_ax)
+            # Nested inside the data-manual region: no mesh argument (the
+            # context mesh, already manual over the data axes, is the
+            # one), manual over the model axes it adds.
+            inner_manual = frozenset(model_axes)
 
             def sync(grads, residual):
                 if wrap_ef:
                     return jax.shard_map(
                         sync_local,
-                        mesh=mesh,
                         in_specs=(g_model_specs, inner_res_spec),
                         out_specs=(g_model_specs, inner_res_spec, P()),
+                        axis_names=inner_manual,
                         check_vma=False,
                     )(grads, residual)
                 def stateless(g_):
@@ -1115,9 +1119,9 @@ def make_partitioned_train_step(
 
                 g, e = jax.shard_map(
                     stateless,
-                    mesh=mesh,
                     in_specs=(g_model_specs,),
                     out_specs=(g_model_specs, P()),
+                    axis_names=inner_manual,
                     check_vma=False,
                 )(grads)
                 return g, None, e
@@ -1151,7 +1155,8 @@ def make_partitioned_train_step(
             aux = _pmean_float_leaves(aux, ax)
             return grads, loss, aux, new_res, err
 
-        auto = frozenset(model_axes)
+        # manual over the data axes only; the model axes stay auto
+        manual = frozenset(data_axes)
         if wrap_ef:
             mapped = jax.shard_map(
                 region,
@@ -1159,7 +1164,7 @@ def make_partitioned_train_step(
                 in_specs=(manual_p_specs, rules.batch_spec(), P(), res_manual),
                 out_specs=(P(), P(), P(), res_manual, P()),
                 check_vma=False,
-                auto=auto,
+                axis_names=manual,
             )
         else:
             mapped = jax.shard_map(
@@ -1168,7 +1173,7 @@ def make_partitioned_train_step(
                 in_specs=(manual_p_specs, rules.batch_spec(), P()),
                 out_specs=(P(), P(), P()),
                 check_vma=False,
-                auto=auto,
+                axis_names=manual,
             )
 
         def global_step(params, opt_state, batch, key):

@@ -16,6 +16,12 @@ under mpirun.  ``--rankless`` omits RANK so ranks are assigned
 first-come-first-served by the native rendezvous (the ``mpirun``-style
 rank-less init of allreduce.py:54).
 
+All ``nproc`` children land on THIS host, so ``--nproc`` > 1 is the CPU
+loopback harness: it needs ``--platform cpu`` (exported to the children
+as ``JAX_PLATFORMS``/``TPU_DIST_PLATFORM``) or an environment that
+already says so, and refuses otherwise — on a TPU host every child would
+claim every chip.  On hardware, run one process per host.
+
 Fail-stop semantics (the reference's failure model): the first child
 that exits non-zero causes the launcher to terminate the rest and exit
 with that code.  Child stdout/stderr pass through, line-buffered, with
@@ -56,11 +62,26 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--no-tag", action="store_true",
                     help="don't prefix child output with [rank N]")
+    ap.add_argument(
+        "--platform",
+        default=os.environ.get("JAX_PLATFORMS")
+        or os.environ.get("TPU_DIST_PLATFORM"),
+        help="platform exported to the children; --nproc > 1 requires "
+        "'cpu' (default: JAX_PLATFORMS, then TPU_DIST_PLATFORM)",
+    )
     ap.add_argument("script", help="python script to run per rank")
     ap.add_argument("script_args", nargs=argparse.REMAINDER)
     args = ap.parse_args(argv)
     if args.nproc < 1:
         ap.error("--nproc must be >= 1")
+    from tpu_dist.comm.launch import refuse_multiprocess_off_cpu
+
+    try:
+        refuse_multiprocess_off_cpu(
+            "tpu_dist.run", args.nproc, args.platform
+        )
+    except ValueError as e:
+        ap.error(str(e))
 
     port = args.master_port
     if not port:
@@ -75,6 +96,8 @@ def main(argv: list[str] | None = None) -> int:
         env["MASTER_ADDR"] = args.master_addr
         env["MASTER_PORT"] = str(port)
         env["WORLD_SIZE"] = str(args.nproc)
+        if args.platform:
+            env["JAX_PLATFORMS"] = env["TPU_DIST_PLATFORM"] = args.platform
         if args.rankless:
             env.pop("RANK", None)
         else:

@@ -2,8 +2,10 @@
 
 The reference's native layer is THD's C++ transport/rendezvous
 (tuto.md:404-419); ours is `rendezvous.cc`, loaded via ctypes (no pybind11
-in this image).  The library is built lazily with g++ on first use (or
-``make -C tpu_dist/runtime``) and cached.
+in this image).  The library is built lazily with ``make`` (g++) on first
+use into ``build/`` (git-ignored): every load goes through ``make``, so a
+``.so`` older than its ``.cc`` is rebuilt and a stale one is never trusted
+merely because it exists.
 
 API:
   - `rendezvous(addr, port, world, rank=-1, payload="", timeout_ms=...)`
@@ -28,14 +30,15 @@ _lock = threading.Lock()
 _lib = None
 
 
-def _build() -> Path:
+def _build() -> None:
+    """Bring ``build/*.so`` up to date with the tracked ``.cc`` sources
+    (a no-op when they already are — ``make`` compares the mtimes)."""
     subprocess.run(
         ["make", "-s", "-C", str(_HERE)],
         check=True,
         capture_output=True,
         text=True,
     )
-    return _LIB_PATH
 
 
 def _load():
@@ -43,8 +46,7 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if not _LIB_PATH.exists():
-            _build()
+        _build()
         lib = ctypes.CDLL(str(_LIB_PATH))
         lib.td_rendezvous.restype = ctypes.c_int
         lib.td_rendezvous.argtypes = [
@@ -71,10 +73,8 @@ def _load_idx():
     with _lock:
         if _idx_lib is not None:
             return _idx_lib
-        path = _HERE / "build" / "libidxreader.so"
-        if not path.exists():
-            _build()
-        lib = ctypes.CDLL(str(path))
+        _build()
+        lib = ctypes.CDLL(str(_HERE / "build" / "libidxreader.so"))
         lib.td_idx_open.restype = ctypes.c_void_p
         lib.td_idx_open.argtypes = [
             ctypes.c_char_p,

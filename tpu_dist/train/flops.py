@@ -14,78 +14,80 @@ achieved-FLOP/s as a fraction of the chip's peak (MFU).  Two counters:
    the XLA number — which includes optimizer/allreduce arithmetic — is a
    slight overestimate of the conventional numerator; both are exposed).
 
-Peak numbers are the public per-chip bf16 (dense) specs.
+Peak numbers are the public per-chip specs, one table keyed by the exact
+``device_kind`` string (`CHIPS`).
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Callable
 
-# Public per-chip dense peak, FLOP/s.  bf16 is the MXU's native matmul
-# dtype (fp32 inputs are handled via bf16x3 passes — far below this peak,
-# so fp32 runs will legitimately show low MFU vs the bf16 figure).
-_PEAK_BF16: dict[str, float] = {
-    "TPU v2": 45e12,
-    "TPU v3": 123e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,  # v5e
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,  # v5p
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,  # v6e / Trillium
-    "TPU v6e": 918e12,
+
+@dataclass(frozen=True)
+class ChipSpec:
+    """Published per-chip peaks.  bf16 is the MXU's native matmul dtype
+    (fp32 inputs run as bf16x3 passes — far below this peak, so fp32 runs
+    legitimately show low MFU against it); HBM bandwidth is the
+    decode-side roofline (autoregressive decode re-reads weights + KV
+    cache every step, so tok/s is bounded by bandwidth long before the
+    MXU matters)."""
+
+    peak_bf16_flops: float
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+    source: str
+
+
+# Keyed by the EXACT ``jax.Device.device_kind`` (the strings libtpu
+# 0.0.34 reports for each generation's topology; the v5e one is also what
+# chip_smoke.py printed on the chip).  No prefix matching: "TPU v5" is the
+# v5p, and a future "TPU v5 <something>" must not inherit its peak.
+_CLOUD = "Google Cloud TPU documentation, "
+CHIPS: dict[str, ChipSpec] = {
+    "TPU v2": ChipSpec(45e12, 700e9, 16e9, _CLOUD + '"TPU v2"'),
+    "TPU v3": ChipSpec(123e12, 900e9, 32e9, _CLOUD + '"TPU v3"'),
+    "TPU v4": ChipSpec(275e12, 1228e9, 32e9, _CLOUD + '"TPU v4"'),
+    "TPU v5 lite": ChipSpec(197e12, 819e9, 16e9, _CLOUD + '"TPU v5e"'),
+    "TPU v5": ChipSpec(459e12, 2765e9, 95e9, _CLOUD + '"TPU v5p"'),
+    "TPU v6 lite": ChipSpec(918e12, 1640e9, 32e9, _CLOUD + '"TPU v6e"'),
 }
 
 
-# Public per-chip HBM bandwidth, bytes/s — the decode-side roofline
-# (autoregressive decode re-reads weights + KV cache every step, so
-# tok/s is bounded by bandwidth long before the MXU matters).
-_HBM_BW: dict[str, float] = {
-    "TPU v2": 700e9,
-    "TPU v3": 900e9,
-    "TPU v4": 1228e9,
-    "TPU v5 lite": 819e9,  # v5e
-    "TPU v5e": 819e9,
-    "TPU v5": 2765e9,  # v5p
-    "TPU v5p": 2765e9,
-    "TPU v6 lite": 1640e9,  # v6e / Trillium
-    "TPU v6e": 1640e9,
-}
+def chip_spec(device: Any | None = None) -> ChipSpec | None:
+    """The `CHIPS` entry for ``device`` (default: first device).
 
-
-def _longest_prefix_match(table: dict[str, float], kind: str) -> float | None:
-    """Most-specific (longest) prefix match: 'TPU v5 lite' must win over
-    'TPU v5' for a v5e regardless of dict insertion order."""
-    best: float | None = None
-    best_len = -1
-    for name, value in table.items():
-        if kind.lower().startswith(name.lower()) and len(name) > best_len:
-            best, best_len = value, len(name)
-    return best
-
-
-def hbm_bandwidth(device: Any | None = None) -> float | None:
-    """Per-chip HBM bandwidth (bytes/s); None when unknown (CPU-sim)."""
-    import jax
-
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "") or ""
-    return _longest_prefix_match(_HBM_BW, kind)
-
-
-def peak_flops(device: Any | None = None) -> float | None:
-    """Per-chip bf16 peak FLOP/s for ``device`` (default: first device).
-
-    Returns None for platforms without a known peak (CPU-sim) so callers
-    report MFU only when it is meaningful.
+    ``None`` off-TPU (the CPU has no published peak, so callers report
+    MFU only where it means something).  On platform ``tpu`` a
+    ``device_kind`` that is not in the table RAISES: a device without a
+    peak is an error to fix in the table, not a default to inherit and
+    not a silently missing MFU.
     """
     import jax
 
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "") or ""
-    return _longest_prefix_match(_PEAK_BF16, kind)
+    spec = CHIPS.get(kind)
+    if spec is None and getattr(device, "platform", None) == "tpu":
+        raise KeyError(
+            f"no published peaks for TPU device_kind {kind!r} — add it to "
+            f"tpu_dist.train.flops.CHIPS with its source (known: "
+            f"{sorted(CHIPS)})"
+        )
+    return spec
+
+
+def hbm_bandwidth(device: Any | None = None) -> float | None:
+    """Per-chip HBM bandwidth (bytes/s); see `chip_spec` for None/raise."""
+    spec = chip_spec(device)
+    return spec.hbm_bytes_per_s if spec else None
+
+
+def peak_flops(device: Any | None = None) -> float | None:
+    """Per-chip bf16 peak FLOP/s; see `chip_spec` for None/raise."""
+    spec = chip_spec(device)
+    return spec.peak_bf16_flops if spec else None
 
 
 def xla_flops(fn: Callable, *args: Any) -> float | None:
