@@ -279,3 +279,114 @@ class TestGoldenGate:
         )
         diffs = analysis.compare_to_golden(_prog(name).plan, golden)
         assert diffs == [], "\n".join(diffs)
+
+
+class TestProgramNamesAndScopes:
+    """The names the device trace carries: every hot program's module
+    name (the trace's `XLA Modules` line) and the `jax.named_scope`
+    vocabulary of PERF.md §3, read from the lowering's debug text (the
+    `op_name` XLA keeps for each instruction).  Metadata only: the
+    token-identity and determinism tests hold the values."""
+
+    SERVE_SCOPES = ["embed", "ln", "attn/qkv", "attn/kv_scatter",
+                    "attn/kv_gather", "attn/scores", "attn/out", "mlp",
+                    "lm_head", "sample"]
+    TRAIN_SCOPES = ["cast", "embed", "block/attn", "block/mlp", "lm_head",
+                    "loss", "grad_accum", "optimizer"]
+
+    @staticmethod
+    def _text(fn, args):
+        return fn.lower(*args).as_text(debug_info=True)
+
+    @staticmethod
+    def _scoped(text, scope):
+        # a scope is one or more whole components of an op_name path
+        import re
+
+        return re.search(rf'[/("]{re.escape(scope)}[/)]', text) is not None
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from tpu_dist import serve
+
+        lm = models.TransformerLM(vocab=64, dim=32, depth=2, heads=4, max_seq=48)
+        params, _ = lm.init(jax.random.key(7))
+        return serve.ServeEngine(lm, params, serve.ServeConfig(
+            max_batch=4, block_size=8, num_blocks=16, max_seq=32, prefill_chunk=8))
+
+    @pytest.mark.parametrize("key,module,extra", [
+        ("serve_decode", "jit_serve_decode_sampled", ["state_update"]),
+        ("serve_prefill", "jit_serve_prefill", []),
+    ])
+    def test_serving_programs_are_named_and_scoped(self, engine, key, module, extra):
+        programs = engine.analysis_programs()
+        assert set(programs) == {"serve_decode", "serve_prefill"}  # the keys stay
+        text = self._text(*programs[key])
+        assert f"module @{module} " in text and "jit_fn" not in text
+        for scope in self.SERVE_SCOPES + extra:
+            assert self._scoped(text, scope), scope
+
+    def test_the_greedy_decode_has_a_name_of_its_own(self, engine):
+        _, args = engine.analysis_programs()["serve_decode"]
+        text = self._text(engine._decode_fn_greedy, args)
+        assert "module @jit_serve_decode_greedy " in text
+        assert self._scoped(text, "sample") and self._scoped(text, "state_update")
+
+    @pytest.mark.parametrize("mesh_axes", [None, "fsdp=2"])
+    def test_the_trainers_step_is_named_and_scoped(self, mesh_axes):
+        lm = models.TransformerLM(vocab=64, dim=32, depth=2, heads=4,
+                                  max_seq=16, remat=True)
+        mesh = (parallel.build_mesh(mesh_axes, mesh_devices=jax.devices()[:2])
+                if mesh_axes else
+                comm.make_mesh(1, ("data",), mesh_devices=jax.devices()[:1]))
+        tr = train.LMTrainer(lm, mesh, train.LMTrainConfig(
+            global_batch=4, accum_steps=2, mesh_axes=mesh_axes,
+            compute_dtype="bfloat16", log=lambda m: None))
+        batch = (jnp.zeros((4, 16), jnp.int32),)
+        text = self._text(tr._partition.step,
+                          (tr.params, tr.opt_state, batch, jax.random.key(0)))
+        assert "module @jit_train_step " in text
+        for scope in self.TRAIN_SCOPES:
+            assert self._scoped(text, scope), scope
+        # backward and rematerialised work carry the forward's scope inside
+        # JAX's own wrappers: what `chipbench.scopes` splits the passes on
+        assert "transpose(jvp(" in text and "rematted_computation/block/attn" in text
+
+    @pytest.mark.parametrize("builder", ["spmd", "auto"])
+    def test_the_hand_written_steps_are_named_and_scoped(self, builder):
+        mesh = comm.make_mesh(2, ("data",), mesh_devices=jax.devices()[:2])
+        from tpu_dist.train import optim
+
+        opt = optim.sgd(0.1)
+        params = {"w": jnp.ones((4, 4))}
+
+        def loss_fn(p, state, batch, key):
+            (x,) = batch
+            return jnp.mean((x @ p["w"]) ** 2), (state, {})
+
+        if builder == "spmd":
+            step = parallel.make_spmd_train_step(loss_fn, opt, mesh, accum_steps=2)
+            want = ["grad_accum", "grad_sync", "optimizer"]
+        else:
+            step = parallel.make_train_step_auto(loss_fn, opt, mesh)
+            want = ["optimizer"]
+        text = self._text(step, (params, {}, opt.init(params),
+                                 (jnp.ones((8, 4)),), jax.random.key(0)))
+        assert "module @jit_train_step " in text
+        for scope in want:
+            assert self._scoped(text, scope), scope
+
+    def test_the_kernels_have_names(self):
+        """`name=` on every `pl.pallas_call`: the trace's one
+        `flash_attention` row becomes forward, dK/dV and dQ."""
+        from tpu_dist.ops.flash_attention import flash_attention
+        from tpu_dist.ops.matmul import matmul
+
+        q = jnp.ones((1, 2, 128, 64), jnp.float32)
+        flash = str(jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True, interpret=True).sum()))(q))
+        for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+            assert f"name={name}\n" in flash
+        x = jnp.ones((128, 128), jnp.float32)
+        assert "name=matmul_fused\n" in str(jax.make_jaxpr(
+            lambda x: matmul(x, x, interpret=True))(x))

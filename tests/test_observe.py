@@ -242,7 +242,198 @@ def test_spans_from_env_null_when_off(monkeypatch):
     rec = spans.from_env()
     with rec.span("x"):
         pass
-    assert rec.save() is None and len(rec) == 0
+    assert rec.save() is None and not rec.enabled
+
+
+def test_span_ids_and_parents_nest():
+    t0 = time.perf_counter()
+    with spans.span("engine.step", step=3) as outer:
+        with spans.span("engine.admit") as first:
+            pass
+        with spans.span("engine.decode_wait") as second:
+            with spans.span("inner") as leaf:
+                pass
+    lone = spans.record("request.queued", t0, t0 + 0.5, request_id=9)
+    t1 = time.perf_counter()
+    assert outer.parent is None and lone.parent is None
+    assert first.parent == second.parent == outer.id and leaf.parent == second.id
+    ids = [outer.id, first.id, second.id, leaf.id, lone.id]
+    assert len(set(ids)) == 5 and all(isinstance(i, int) for i in ids)
+    # a span is kept when it closes: children before their parent
+    got = [s for s in spans.recent(since=t0) if s.id in ids]
+    assert [s.id for s in got] == [first.id, leaf.id, second.id, outer.id, lone.id]
+    assert outer.attrs == {"step": 3} and lone.attrs == {"request_id": 9}
+    # one clock: every reading is time.perf_counter's
+    for s in got[:4]:
+        assert t0 <= s.start <= s.end <= t1
+    assert outer.start <= first.start and second.end <= outer.end
+    assert lone.ms == pytest.approx(500.0)
+
+
+def test_a_span_that_raises_is_still_recorded_and_unwinds_the_stack():
+    with pytest.raises(KeyError):
+        with spans.span("boom") as sp:
+            raise KeyError("x")
+    assert spans.recent()[-1] is sp and sp.end >= sp.start
+    with spans.span("after") as nxt:
+        pass
+    assert nxt.parent is None
+
+
+def test_spans_of_another_thread_have_their_own_parents():
+    import threading
+
+    seen = {}
+
+    def work():
+        with spans.span("other") as sp:
+            seen["other"] = sp
+
+    with spans.span("main") as main:
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert seen["other"].parent is None and seen["other"].id != main.id
+
+
+def test_span_ring_is_bounded_and_drops_the_oldest(monkeypatch):
+    import collections
+
+    monkeypatch.setattr(spans, "_ring", collections.deque(maxlen=4))
+    for i in range(7):
+        with spans.span("s", step=i):
+            pass
+    assert [s.attrs["step"] for s in spans.recent()] == [3, 4, 5, 6]
+    assert spans._ring.maxlen == 4
+    cut = spans.recent()[2].start
+    assert [s.attrs["step"] for s in spans.recent(since=cut)] == [5, 6]
+
+
+def test_chrome_export_is_on_the_spans_clock(tmp_path):
+    """`ts` is `start` moved by the one process-wide offset; `dur` is
+    `end - start`: no second clock is read when a span is made."""
+    rec = spans.SpanRecorder(str(tmp_path / "t.trace.json"), rank=1)
+    with rec.span("dispatch", step=4) as sp:
+        time.sleep(0.002)
+    rec.instant("preempt", step=4)
+    with rec.span("still_open"):
+        doc = json.load(open(rec.save()))  # an open span waits for the next save
+    ev = {e["name"]: e for e in doc["traceEvents"]}
+    assert set(ev) == {"dispatch", "preempt"}
+    assert ev["dispatch"]["ts"] == pytest.approx((sp.start + spans.WALL_OFFSET) * 1e6)
+    assert ev["dispatch"]["dur"] == pytest.approx((sp.end - sp.start) * 1e6)
+    assert ev["dispatch"]["args"] == {"step": 4, "id": sp.id, "parent": None}
+    assert abs(ev["dispatch"]["ts"] / 1e6 - time.time()) < 60  # the wall clock
+    assert spans.recent()[-1].name == "still_open"  # the ring has them all
+    assert len(json.load(open(rec.save()))["traceEvents"]) == 3
+
+
+def test_merge_traces_aligns_ranks_on_the_wall_clock(tmp_path):
+    paths, order = [], []
+    for r in (1, 0, 1, 0):
+        rec = spans.SpanRecorder(str(tmp_path / f"r{r}_{len(paths)}.json"), rank=r)
+        with rec.span("step", step=len(paths)):
+            time.sleep(0.001)
+        order.append(r)
+        paths.append(rec.save())
+    merged = spans.merge_traces(paths)
+    xs = [e for e in merged["traceEvents"] if e.get("ph") == "X"]
+    assert [e["pid"] for e in xs] == order  # in time order, each in its lane
+    assert [e["ts"] for e in xs] == sorted(e["ts"] for e in xs)
+
+
+def test_the_null_recorder_still_records_to_the_ring(monkeypatch):
+    monkeypatch.delenv(events.ENV_DIR, raising=False)
+    rec = spans.from_env()
+    with rec.span("readback", step=12) as sp:
+        pass
+    rec.instant("preempt", step=12)
+    last = spans.recent()[-2:]
+    assert last[0] is sp and sp.attrs == {"step": 12}
+    assert last[1].name == "preempt" and last[1].start == last[1].end
+    assert rec.save() is None and not rec.enabled
+
+
+def test_the_export_is_the_ring_whoever_opened_the_span(tmp_path, monkeypatch):
+    """One recorder: a span opened with the module's `span` or `record`
+    (the serving engine's way) is in the file like one opened through the
+    recorder (the trainers'), from the moment the recorder was made."""
+    import collections
+    import threading
+
+    with spans.span("before_the_recorder"):
+        pass
+    rec = spans.SpanRecorder(str(tmp_path / "t.trace.json"), rank=3)
+    with spans.span("engine.step", step=1) as step:
+        with spans.span("engine.admit") as admit:
+            pass
+    import numpy as np
+
+    with rec.span("dispatch", step=1, tokens=np.int32(7)) as disp:  # a numpy scalar is written
+        pass
+    queued = spans.record("request.queued", step.start, step.end, request_id=41)
+    decode = spans.record("request.decode", step.end, step.end + 1.0, request_id=41, emitted=5)
+    other = spans.record("request.queued", step.start, step.end, request_id=42)
+    doc = json.load(open(rec.save()))
+    ev = {e["args"]["id"]: e for e in doc["traceEvents"]}
+    assert set(ev) == {step.id, admit.id, disp.id, queued.id, decode.id, other.id}
+    assert doc["otherData"] == {"producer": "tpu_dist.observe.spans", "rank": 3, "complete": True}
+    # the thread's spans share its lane and nest there; a request has a lane of its own
+    me = threading.get_ident() & 0xFFFFFF
+    assert ev[step.id]["tid"] == ev[admit.id]["tid"] == ev[disp.id]["tid"] == me == step.tid
+    assert ev[queued.id]["tid"] == ev[decode.id]["tid"] == 41 and ev[other.id]["tid"] == 42
+    assert ev[admit.id]["args"]["parent"] == step.id and ev[decode.id]["args"]["emitted"] == 5
+    assert ev[disp.id]["args"]["tokens"] == 7
+    assert all(e["pid"] == 3 for e in ev.values())
+    # once the ring has wrapped past the recorder's start the file says so
+    assert spans.complete_since(rec.since)
+    monkeypatch.setattr(spans, "_ring", collections.deque(maxlen=2))
+    for i in range(3):
+        with spans.span("s", step=i):
+            pass
+    assert not spans.complete_since(rec.since)
+    doc = json.load(open(rec.save()))
+    assert [e["args"]["step"] for e in doc["traceEvents"]] == [1, 2]
+    assert doc["otherData"]["complete"] is False
+    assert spans.merge_traces([rec.path])["otherData"]["complete"] is False
+    assert spans.complete_since(time.perf_counter())  # nothing since now was dropped
+
+
+def test_a_span_under_the_profiler_is_in_the_traces_host_plane(tmp_path):
+    """With a profiler session open a span is a `TraceAnnotation` named
+    `tpu_dist/<name>`: it sits in the `.xplane.pb` beside the device's
+    operations, on their clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with spans.span("before_the_session"):
+        pass
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with spans.span("engine.step", step=1) as outer:
+            with spans.span("engine.decode_wait"):
+                jax.block_until_ready(jax.numpy.ones((8, 8)) @ jax.numpy.ones((8, 8)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(spans.PREFIX):
+                        found[e.name] = e
+    assert set(found) == {"tpu_dist/engine.step", "tpu_dist/engine.decode_wait"}
+    step, wait = found["tpu_dist/engine.step"], found["tpu_dist/engine.decode_wait"]
+    assert step.start_ns <= wait.start_ns
+    assert wait.start_ns + wait.duration_ns <= step.start_ns + step.duration_ns
+    assert step.duration_ns / 1e6 == pytest.approx(outer.ms, rel=0.2, abs=0.2)
 
 
 # --------------------------------------------------------------- heartbeat

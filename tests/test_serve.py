@@ -510,3 +510,181 @@ class TestRuntimeSampling:
         prompt = models.synthetic_tokens(1, 40, 64, seed=0)
         with pytest.raises(ValueError, match="exceeds cache length"):
             serve.generate_runtime(lm, lm_params, prompt, 20)
+
+
+PHASES = {
+    "engine.admit", "engine.decode_dispatch", "engine.prefill_dispatch",
+    "engine.decode_wait", "engine.decode_apply", "engine.prefill_wait",
+    "engine.prefill_apply", "engine.publish",
+}
+
+
+def _serve_traced(lm, lm_params, lengths, *, max_new=5, **cfg):
+    """Drain an engine over prompts of ``lengths``; returns (engine,
+    request ids, the spans it left in `observe.spans`' ring)."""
+    import time
+
+    from tpu_dist.observe import spans
+
+    t0 = time.perf_counter()
+    eng = serve.ServeEngine(lm, lm_params, _cfg(**cfg))
+    rids = [
+        eng.submit(models.synthetic_tokens(1, n, 64, seed=i)[0], max_new)
+        for i, n in enumerate(lengths)
+    ]
+    eng.run_until_drained()
+    return eng, rids, spans.recent(since=t0)
+
+
+class TestEngineSpans:
+    """The engine names its own work: one `engine.step` span a call with
+    its phases as children, three spans a request, counters on both."""
+
+    def test_every_phase_span_lies_inside_its_engine_step(self, lm, lm_params):
+        eng, _, got = _serve_traced(lm, lm_params, [3, 11, 20, 6, 9])
+        steps = {s.id: s for s in got if s.name == "engine.step"}
+        assert len(steps) == eng.step_count
+        assert [s.attrs["step"] for s in steps.values()] == list(range(eng.step_count))
+        phases = [s for s in got if s.name.startswith("engine.") and s.name != "engine.step"]
+        assert {s.name for s in phases} == PHASES
+        for s in phases:
+            step = steps[s.parent]
+            assert step.start <= s.start <= s.end <= step.end
+        for step in steps.values():
+            assert step.parent is None
+            assert set(step.attrs) == {"step", "occupancy", "queued", "admitted", "blocked"}
+            mine = [s.name for s in phases if s.parent == step.id]
+            assert mine[0] == "engine.admit" and mine[-1] == "engine.publish"
+            assert len(mine) == len(set(mine))  # each phase once a step at most
+            # only the readbacks wait for the device, each after its dispatch
+            if "engine.decode_wait" in mine:
+                assert mine.index("engine.decode_dispatch") < mine.index("engine.decode_wait")
+                assert mine.index("engine.decode_wait") + 1 == mine.index("engine.decode_apply")
+            if "engine.prefill_wait" in mine:
+                assert mine.index("engine.prefill_wait") + 1 == mine.index("engine.prefill_apply")
+        assert sum(s.attrs["admitted"] for s in steps.values()) == 5
+
+    def test_each_finished_request_has_three_spans_and_one_id(self, lm, lm_params):
+        eng, rids, got = _serve_traced(lm, lm_params, [3, 11, 20, 6, 9, 4])
+        for rid in rids:
+            mine = {s.name: s for s in got if s.attrs.get("request_id") == rid}
+            assert set(mine) == {"request.queued", "request.prefill", "request.decode"}
+            q, p, d = (mine[f"request.{k}"] for k in ("queued", "prefill", "decode"))
+            res = eng.results[rid]
+            assert q.start == res.arrival_time and q.end == p.start == res.admit_time
+            assert p.end == d.start == res.first_token_time and d.end == res.finish_time
+            assert d.attrs["emitted"] == res.emitted == 5
+            assert d.attrs["finish_reason"] == res.finish_reason == "length"
+            assert len({q.id, p.id, d.id}) == 3 and q.parent is None
+        assert sum(s.name == "request.queued" for s in got) == len(rids)
+
+    @pytest.mark.parametrize("chunk", [4, 8, 32])
+    def test_prefill_rounds_count_the_prompts_tokens(self, lm, lm_params, chunk):
+        lengths = [3, 11, 20, 6, 9]
+        eng, _, got = _serve_traced(lm, lm_params, lengths, prefill_chunk=chunk)
+        rounds = [s for s in got if s.name == "engine.prefill_dispatch"]
+        assert sum(s.attrs["real_tokens"] for s in rounds) == sum(lengths)
+        assert all(s.attrs["chunk"] == chunk for s in rounds)
+        assert all(1 <= s.attrs["rows"] <= eng.cfg.prefill_batch for s in rounds)
+        want_rows = sum(-(-n // chunk) for n in lengths)  # a row a chunk a request
+        assert sum(s.attrs["rows"] for s in rounds) == want_rows
+        assert len(rounds) == eng.steps_with_prefill
+
+    @pytest.mark.parametrize("cause,cfg", [
+        # each request needs ceil((6+10)/8) = 2 blocks
+        ("blocks", dict(max_batch=4, num_blocks=2)),
+        ("slots", dict(max_batch=1, num_blocks=64)),
+    ])
+    def test_blocked_names_what_the_queues_head_waits_for(self, lm, lm_params, cause, cfg):
+        eng, rids, got = _serve_traced(lm, lm_params, [6, 6], max_new=10, **cfg)
+        steps = [s for s in got if s.name == "engine.step"]
+        assert steps[0].attrs["admitted"] == 1 and steps[0].attrs["queued"] == 1
+        said = [s.attrs["blocked"] for s in steps]
+        assert said[0] == cause and set(said) == {cause, ""}
+        # blocked for as long as the second request stayed queued, not after
+        waiting = [s for s in steps if s.attrs["queued"] == 1]
+        assert [s.attrs["blocked"] for s in waiting] == [cause] * len(waiting)
+        assert said[len(waiting):] == [""] * (len(steps) - len(waiting))
+        q = [s for s in got if s.name == "request.queued"]
+        assert q[1].ms > q[0].ms and eng.results[rids[1]].emitted == 10
+
+    def test_repacks_and_decode_steps_are_counted(self, lm, lm_params):
+        from tpu_dist.observe.registry import REGISTRY
+
+        names = ["admitted", "prefill_rows", "prefill_real_tokens",
+                 "prefill_padded_tokens", "state_repacks", "decode_steps"]
+        counter = lambda n: REGISTRY.counter(f"tpu_dist_serve_{n}_total")  # noqa: E731
+        before = {n: counter(n).value() for n in names}
+        blocked0 = counter("blocked_steps").value(cause="slots")
+        eng, _, got = _serve_traced(lm, lm_params, [5, 7, 9], max_batch=2)
+        delta = {n: counter(n).value() - before[n] for n in names}
+        decodes = [s for s in got if s.name == "engine.decode_dispatch"]
+        rounds = [s for s in got if s.name == "engine.prefill_dispatch"]
+        assert delta["admitted"] == 3 and delta["prefill_real_tokens"] == 21
+        assert delta["decode_steps"] == len(decodes) == eng.steps_with_decode
+        assert delta["state_repacks"] == sum(s.attrs["repacked"] for s in decodes) >= 2
+        assert delta["prefill_rows"] == sum(s.attrs["rows"] for s in rounds)
+        assert delta["prefill_padded_tokens"] == delta["prefill_rows"] * 8
+        blocked = sum(s.attrs["blocked"] == "slots" for s in got if s.name == "engine.step")
+        assert counter("blocked_steps").value(cause="slots") - blocked0 == blocked > 0
+        assert "tpu_dist_serve_blocked_steps_total" in REGISTRY.render()
+
+    def test_warmup_leaves_no_count_and_a_cancel_in_the_queue_no_span(self, lm, lm_params):
+        import time
+
+        from tpu_dist.observe import spans
+        from tpu_dist.observe.registry import REGISTRY
+
+        admitted = REGISTRY.counter("tpu_dist_serve_admitted_total")
+        before = admitted.value()
+        eng = serve.ServeEngine(lm, lm_params, _cfg())
+        eng.warmup()
+        assert admitted.value() == before and not eng.audit
+        t0 = time.perf_counter()
+        rid = eng.submit(models.synthetic_tokens(1, 4, 64, seed=0)[0], 3)
+        assert eng.cancel(rid) and eng.results[rid].admit_time is None
+        assert not [s for s in spans.recent(since=t0) if s.name.startswith("request.")]
+
+    def test_the_telemetry_file_holds_the_servers_spans(self, lm, lm_params, tmp_path,
+                                                        monkeypatch):
+        """Under ``TPU_DIST_TELEMETRY`` a server's Chrome-trace file (the
+        documented export, `merge_traces`' input) holds the engine's phases
+        on the engine's thread and each request's life on a lane of its own,
+        all stamped by the spans' clock through the real front-end."""
+        import json
+
+        from tpu_dist.observe import events, spans
+
+        monkeypatch.setenv(events.ENV_DIR, str(tmp_path))
+        server = serve.LMServer(lm, lm_params, _cfg())
+        assert server.engine._now is spans.time.perf_counter
+        rids = [server.submit(models.synthetic_tokens(1, n, 64, seed=n)[0], 4) for n in (5, 9, 14)]
+        server.run_until_drained()
+        rec = spans.from_env()
+        assert not (tmp_path / "spans_rank0.trace.json").exists()
+        spans.flush_all()  # what interpreter exit and the crash paths call
+        doc = json.load(open(rec.path))
+        evs = doc["traceEvents"]
+        steps = [e for e in evs if e["name"] == "engine.step"]
+        assert len(steps) == server.engine.step_count and doc["otherData"]["complete"]
+        assert PHASES <= {e["name"] for e in evs}
+        assert len({e["tid"] for e in evs if e["name"].startswith("engine.")}) == 1
+        for rid in rids:
+            lane = sorted((e for e in evs if e["tid"] == rid), key=lambda e: e["ts"])
+            assert [e["name"] for e in lane] == ["request.queued", "request.prefill",
+                                                 "request.decode"]
+            res = server.result(rid)
+            # the lane reads as the request's TTFT and its decode time
+            assert lane[1]["ts"] + lane[1]["dur"] == pytest.approx(lane[2]["ts"])
+            assert (lane[2]["ts"] - lane[0]["ts"]) / 1e6 == pytest.approx(res.ttft, abs=1e-6)
+            assert lane[2]["args"]["emitted"] == 4
+            # a request's prefill lies under the steps that ran its rounds
+            inside = [e for e in steps if e["ts"] < lane[1]["ts"] + lane[1]["dur"]
+                      and e["ts"] + e["dur"] > lane[1]["ts"]]
+            assert inside
+        merged = spans.merge_traces([rec.path])
+        assert {"request.decode", "engine.decode_wait"} <= {e["name"] for e in merged["traceEvents"]}
+
+    def test_the_audit_is_bounded(self, lm, lm_params):
+        eng = serve.ServeEngine(lm, lm_params, _cfg())
+        assert eng.audit.maxlen >= 2 * 10_000  # two tuples a request

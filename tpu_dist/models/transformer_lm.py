@@ -200,11 +200,12 @@ class TransformerLM(Module):
 
     def _trunk(self, params, tokens, *, pos_offset=0):
         b, s = tokens.shape
-        h = params["embed"]["table"][tokens]
-        if self.pos_embedding == "learned":
-            h = h + jax.lax.dynamic_slice_in_dim(
-                params["pos"], pos_offset, s, axis=1
-            )
+        with jax.named_scope("embed"):
+            h = params["embed"]["table"][tokens]
+            if self.pos_embedding == "learned":
+                h = h + jax.lax.dynamic_slice_in_dim(
+                    params["pos"], pos_offset, s, axis=1
+                )
         # rope: positions enter inside attention (q/k rotation), not here
         return h
 
@@ -223,18 +224,23 @@ class TransformerLM(Module):
                 if not self.moe_experts:
                     return blk.apply(pb_, {}, h_, train=train,
                                      mask=attn_mask)[0]
-                x1, _ = blk.ln1.apply(pb_["ln1"], {}, h_)
-                o, _ = blk.attn.apply(pb_["attn"], {}, x1, mask=attn_mask)
-                h_ = h_ + o
-                x2, _ = blk.ln2.apply(pb_["ln2"], {}, h_)
-                return h_ + self._mlp_or_moe(blk, pb_, x2)
+                with jax.named_scope("block/attn"):
+                    x1, _ = blk.ln1.apply(pb_["ln1"], {}, h_)
+                    o, _ = blk.attn.apply(
+                        pb_["attn"], {}, x1, mask=attn_mask
+                    )
+                    h_ = h_ + o
+                with jax.named_scope("block/mlp"):
+                    x2, _ = blk.ln2.apply(pb_["ln2"], {}, h_)
+                    return h_ + self._mlp_or_moe(blk, pb_, x2)
 
             if self.remat:
                 h = jax.checkpoint(block_fn)(pb, h)
             else:
                 h = block_fn(pb, h)
-        h, _ = self.ln.apply(params["ln"], {}, h)
-        logits = h @ params["embed"]["table"].T
+        with jax.named_scope("lm_head"):
+            h, _ = self.ln.apply(params["ln"], {}, h)
+            logits = h @ params["embed"]["table"].T
         return logits, state
 
     # ---- autoregressive inference (KV cache) ----------------------------
@@ -1167,14 +1173,17 @@ def lm_loss(
     mean is over counted positions — pair with ``apply(attn_mask=...)``
     so padded batches train identically to trimmed ones (tested)."""
     b, s, V = logits.shape
-    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
-    picked = jnp.take_along_axis(
-        logp, tokens[:, 1:, None], axis=-1
-    )[..., 0]
-    if mask is None:
-        return -picked.mean()
-    w = mask[:, 1:].astype(jnp.float32)
-    return -(picked * w).sum() / jnp.maximum(w.sum(), 1.0)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(
+            logits[:, :-1].astype(jnp.float32), axis=-1
+        )
+        picked = jnp.take_along_axis(
+            logp, tokens[:, 1:, None], axis=-1
+        )[..., 0]
+        if mask is None:
+            return -picked.mean()
+        w = mask[:, 1:].astype(jnp.float32)
+        return -(picked * w).sum() / jnp.maximum(w.sum(), 1.0)
 
 
 def lm_loss_seq_parallel(

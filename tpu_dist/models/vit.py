@@ -58,12 +58,14 @@ class EncoderBlock(Module):
         return {"ln1": pl1, "attn": pa, "ln2": pl2, "mlp": pm}, {}
 
     def apply(self, params, state, x, *, train=False, key=None, mask=None):
-        h, _ = self.ln1.apply(params["ln1"], {}, x)
-        h, _ = self.attn.apply(params["attn"], {}, h, mask=mask)
-        x = x + h
-        h, _ = self.ln2.apply(params["ln2"], {}, x)
-        h, _ = self.mlp.apply(params["mlp"], {}, h)
-        return x + h, state
+        with jax.named_scope("block/attn"):
+            h, _ = self.ln1.apply(params["ln1"], {}, x)
+            h, _ = self.attn.apply(params["attn"], {}, h, mask=mask)
+            x = x + h
+        with jax.named_scope("block/mlp"):
+            h, _ = self.ln2.apply(params["ln2"], {}, x)
+            h, _ = self.mlp.apply(params["mlp"], {}, h)
+            return x + h, state
 
 
 class ViT(Module):

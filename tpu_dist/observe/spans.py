@@ -1,149 +1,214 @@
-"""Host-side span tracing — Chrome-trace/perfetto JSON.
+"""Host-side spans: one recorder, on the profiler's clock.
 
-`jax.profiler` traces show the DEVICE timeline; what it cannot show is
-where the HOST spent its time between dispatches — data loading, batch
-sharding, loss readback, checkpoint writes, rendezvous.  `SpanRecorder`
-captures those as Chrome-trace "complete" events (``ph: "X"``) that
-load in ``chrome://tracing`` / https://ui.perfetto.dev next to the
-device trace.
+A span is ``(name, start, end, id, parent, attrs)``.  ``start`` / ``end``
+are `time.perf_counter()` readings and nothing else (the clock the
+serving engine's ``now`` and the benchmark's harness use); ``parent`` is
+the id of the span that was open on the same thread when this one
+started; request-scoped spans carry ``request_id`` in ``attrs`` and
+step-scoped ones ``step``.
 
-Correlation contract: every span carries ``args.step`` (the global step
-id) when the caller provides one, and the trainers run `jax.profiler`
-device traces with the SAME step ids (`jax.profiler.StepTraceAnnotation`
-naming convention) — load both files in perfetto and match on step.
+Every span lands in ONE process-global bounded ring (a
+``deque(maxlen=RING_SIZE)`` like `flightrec`'s: no lock, no I/O), read
+with `recent`.  It is always on.  Opening a span also enters
+``jax.profiler.TraceAnnotation("tpu_dist/<name>")``: a flag test while no
+profiler session is open, and while one is the span sits in the
+``.xplane.pb`` host plane on the device trace's clock, so idle gaps on
+the chip can be named after what the host was doing
+(`python -m chipbench.scopes`).  jax is imported at the first span, not
+with this module, which stays importable before a backend exists.
 
-Opt-in via ``TPU_DIST_TELEMETRY=<dir>``: `from_env` records to
-``<dir>/spans_rank<r>.trace.json`` (saved on `save`, which the trainers
-call at fit-exit).  Stdlib-only.
+The Chrome-trace export is an operator's view of the same ring
+(docs/observability.md): under ``TPU_DIST_TELEMETRY=<dir>`` `from_env`
+returns a `SpanRecorder` whose `save` writes the ring's spans since the
+recorder was made, whoever opened them (a trainer's ``dispatch``, the
+serving engine's phases and its requests' lifecycles), to
+``<dir>/spans_rank<r>.trace.json`` (fit-exit, interpreter exit, the flight
+recorder's crash paths).  ``ts`` there is the same ``start`` moved onto
+the wall clock by one process-wide offset, so the ranks of a gang line up
+in `merge_traces`; a span that carries a ``request_id`` is drawn on that
+request's own lane.
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
+import itertools
 import json
 import os
 import threading
 import time
+from collections import deque
 
 from tpu_dist.observe import events as _events
 
+RING_SIZE = 65536
+PREFIX = "tpu_dist/"
+
+# wall clock minus perf_counter, read once: the export's only other clock
+WALL_OFFSET = time.time() - time.perf_counter()
+
+_ring: deque = deque(maxlen=RING_SIZE)
+_ids = itertools.count(1)
+_local = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, resolved at the first span
+
+
+def _open_ids() -> list:
+    try:
+        return _local.open
+    except AttributeError:
+        _local.open = []
+        return _local.open
+
+
+class Span:
+    """One span; also its own context manager (`span` hands it out, so a
+    caller can add to ``attrs`` what it learns inside)."""
+
+    __slots__ = ("name", "start", "end", "id", "parent", "attrs", "tid", "_ann")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name, self.attrs = name, attrs
+        self.start = self.end = 0.0
+        self.id = self.parent = None
+        self.tid = threading.get_ident() & 0xFFFFFF  # the export's lane
+        self._ann = None
+
+    @property
+    def ms(self) -> float:
+        return (self.end - self.start) * 1e3
+
+    def __enter__(self) -> "Span":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        opened = _open_ids()
+        self.parent = opened[-1] if opened else None
+        self.id = next(_ids)
+        opened.append(self.id)
+        self._ann = _annotation(PREFIX + self.name)
+        self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._ann = None
+        _open_ids().pop()
+        _ring.append(self)
+        return False
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, {self.start:.6f}..{self.end:.6f}, "
+                f"id={self.id}, parent={self.parent}, {self.attrs})")
+
+
+def span(name: str, **attrs) -> Span:
+    """``with span("engine.step", step=3) as sp:`` times the block."""
+    return Span(name, attrs)
+
+
+def record(name: str, start: float, end: float, **attrs) -> Span:
+    """A span whose two ends the caller read itself (`time.perf_counter`),
+    for a stretch no ``with`` block covers: a request's wait in the queue.
+    It has no parent and, lying in the past, no profiler annotation."""
+    sp = Span(name, attrs)
+    sp.start, sp.end, sp.id = start, end, next(_ids)
+    _ring.append(sp)
+    return sp
+
+
+def recent(since: float = float("-inf")) -> list[Span]:
+    """The ring's spans that started at or after ``since``, in the order
+    they ended (a span is kept when it closes)."""
+    return [s for s in list(_ring) if s.start >= since]
+
+
+def complete_since(since: float) -> bool:
+    """Whether `recent(since)` is all there was: False once the ring has
+    wrapped past ``since`` (it is full, and its oldest span ended after
+    ``since``, so a span that started later may already have been dropped)."""
+    ring = _ring
+    return len(ring) < ring.maxlen or ring[0].end <= since
+
+
+def _with_step(step: int | None, attrs: dict) -> dict:
+    if step is not None:
+        attrs["step"] = int(step)
+    return attrs
+
 
 class SpanRecorder:
-    """Collects Chrome-trace events in memory; `save` writes the JSON
-    object format (``{"traceEvents": [...]}``).  Thread-safe."""
+    """The Chrome-trace file of the ring: `span` and `instant` are the
+    module's own with ``step`` named, and `save` writes every span the ring
+    holds that started since this recorder was made (the ring bounds a
+    multi-day run's file to its newest `RING_SIZE` spans)."""
 
     enabled = True
 
-    # Memory bound for multi-day runs: ~3 spans/step accumulate in
-    # memory until save(); past this cap new spans are counted, not
-    # stored (the count lands in the saved file's otherData).
-    MAX_EVENTS = 200_000
-
-    def __init__(self, path: str | None = None, rank: int = 0,
-                 max_events: int | None = None):
+    def __init__(self, path: str | None = None, rank: int = 0):
         self.path = path
         self.rank = int(rank)
-        self.max_events = self.MAX_EVENTS if max_events is None else max_events
-        self.dropped = 0
-        self._lock = threading.Lock()
-        self._trace_events: list[dict] = []
+        self.since = time.perf_counter()
 
-    @contextlib.contextmanager
-    def span(self, name: str, step: int | None = None, **args):
-        """Time a host-side region.  ``step`` is the device-trace
-        correlation key; extra kwargs land in the event's ``args``."""
-        wall0 = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            self._append(
-                {
-                    "name": name,
-                    "ph": "X",
-                    "ts": wall0 * 1e6,  # microseconds, trace convention
-                    "dur": dur * 1e6,
-                    "pid": self.rank,
-                    "tid": threading.get_ident() & 0xFFFFFF,
-                    "args": self._args(step, args),
-                }
-            )
+    def span(self, name: str, step: int | None = None, **args) -> Span:
+        """Time a host-side region.  ``step`` is the global step id;
+        extra kwargs land in the span's ``attrs``."""
+        return Span(name, _with_step(step, args))
 
     def instant(self, name: str, step: int | None = None, **args) -> None:
         """A zero-duration marker (preemption signal, chaos injection)."""
-        self._append(
-            {
-                "name": name,
-                "ph": "i",
-                "s": "p",  # process-scoped instant
-                "ts": time.time() * 1e6,
-                "pid": self.rank,
-                "tid": threading.get_ident() & 0xFFFFFF,
-                "args": self._args(step, args),
-            }
-        )
+        now = time.perf_counter()
+        record(name, now, now, **_with_step(step, args))
 
-    @staticmethod
-    def _args(step, args) -> dict:
-        out = dict(args)
-        if step is not None:
-            out["step"] = int(step)
-        return out
-
-    def _append(self, ev: dict) -> None:
-        with self._lock:
-            if len(self._trace_events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._trace_events.append(ev)
-
-    def __len__(self) -> int:
-        return len(self._trace_events)
+    def _event(self, sp: Span) -> dict:
+        ev = {
+            "name": sp.name,
+            "ts": (sp.start + WALL_OFFSET) * 1e6,  # microseconds
+            "pid": self.rank,
+            # a request's spans overlap other requests': a lane for each
+            "tid": sp.attrs.get("request_id", sp.tid),
+            "args": dict(sp.attrs, id=sp.id, parent=sp.parent),
+        }
+        if sp.end > sp.start:
+            ev.update(ph="X", dur=(sp.end - sp.start) * 1e6)
+        else:
+            ev.update(ph="i", s="p")  # process-scoped instant
+        return ev
 
     def save(self, path: str | None = None) -> str | None:
         """Write the Chrome-trace JSON; returns the path (None if this
         recorder has nowhere to write).  Idempotent — call at every
-        fit-exit; later spans simply extend the file on the next save."""
+        fit-exit; later spans simply extend the file on the next save.
+        A span still open is not in the ring yet: the next save has it."""
         path = path or self.path
         if path is None:
             return None
-        with self._lock:
-            doc = {
-                "traceEvents": list(self._trace_events),
-                "displayTimeUnit": "ms",
-                "otherData": {
-                    "producer": "tpu_dist.observe.spans",
-                    "rank": self.rank,
-                    "dropped_events": self.dropped,
-                },
-            }
+        doc = {
+            "traceEvents": [self._event(sp) for sp in recent(self.since)],
+            "displayTimeUnit": "ms",
+            "otherData": {
+                "producer": "tpu_dist.observe.spans",
+                "rank": self.rank,
+                "complete": complete_since(self.since),
+            },
+        }
         tmp = f"{path}.tmp"
         with open(tmp, "w", encoding="utf-8") as fh:
-            json.dump(doc, fh)
+            # attrs are any caller's: a numpy scalar among them must not
+            # cost the crash paths the whole file
+            json.dump(doc, fh, default=lambda o: o.item() if hasattr(o, "item") else repr(o))
         os.replace(tmp, path)
         return path
 
 
-class NullRecorder:
-    """Telemetry-off stand-in (same surface, zero cost)."""
+class NullRecorder(SpanRecorder):
+    """No file is written: the spans go to the ring and nowhere else."""
 
     enabled = False
-    path = None
-
-    @contextlib.contextmanager
-    def span(self, name, step=None, **args):
-        yield
-
-    def instant(self, name, step=None, **args):
-        pass
-
-    def save(self, path=None):
-        return None
-
-    def __len__(self):
-        return 0
 
 
 NULL = NullRecorder()
@@ -191,8 +256,9 @@ def _install_flush_hooks() -> None:
 
 
 def from_env(rank: int | None = None):
-    """This process's recorder under ``TPU_DIST_TELEMETRY`` (cached per
-    dir+rank), or the NULL recorder when telemetry is off."""
+    """Whether a file is written: this process's `SpanRecorder` under
+    ``TPU_DIST_TELEMETRY`` (cached per dir+rank), else `NULL`.  Either
+    way the spans reach the ring."""
     dirpath = os.environ.get(_events.ENV_DIR)
     if not dirpath:
         return NULL
@@ -219,7 +285,7 @@ def merge_traces(paths, out_path: str | None = None) -> dict:
     merge CLI; returns the merged trace document (written to
     ``out_path`` when given)."""
     events: list[dict] = []
-    dropped = 0
+    complete = True
     for i, path in enumerate(paths):
         try:
             with open(path, encoding="utf-8") as fh:
@@ -228,7 +294,7 @@ def merge_traces(paths, out_path: str | None = None) -> dict:
             continue
         other = doc.get("otherData", {}) or {}
         rank = other.get("rank", i)
-        dropped += int(other.get("dropped_events", 0) or 0)
+        complete = complete and bool(other.get("complete", True))
         events.append({
             "name": "process_name", "ph": "M", "pid": rank, "tid": 0,
             "ts": 0, "args": {"name": f"rank {rank}"},
@@ -244,7 +310,7 @@ def merge_traces(paths, out_path: str | None = None) -> dict:
         "otherData": {
             "producer": "tpu_dist.observe.spans.merge_traces",
             "sources": len(paths),
-            "dropped_events": dropped,
+            "complete": complete,
         },
     }
     if out_path is not None:
@@ -253,3 +319,37 @@ def merge_traces(paths, out_path: str | None = None) -> dict:
             json.dump(merged, fh)
         os.replace(tmp, out_path)
     return merged
+
+
+def _cost_ns(n: int = 100_000) -> float:
+    t0 = time.perf_counter()
+    for i in range(n):
+        with span("cost", step=i):
+            pass
+    return (time.perf_counter() - t0) / n * 1e9
+
+
+if __name__ == "__main__":
+    # what the always-on ring costs: ns a span, off and on a profiler session
+    import tempfile
+
+    import jax
+
+    _cost_ns(10_000)
+    off = [_cost_ns() for _ in range(3)]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp, profiler_options=opts)
+        try:
+            on = [_cost_ns(20_000) for _ in range(3)]
+        finally:
+            jax.profiler.stop_trace()
+    t0 = time.perf_counter()
+    for i in range(100_000):
+        record("cost", 0.0, 1.0, request_id=i)
+    rec_ns = (time.perf_counter() - t0) / 100_000 * 1e9
+    print(f"{jax.devices()[0].platform}: span() {min(off):.0f} ns with no profiler session "
+          f"(three runs: {', '.join(f'{x:.0f}' for x in off)}), {min(on):.0f} ns inside one "
+          f"({', '.join(f'{x:.0f}' for x in on)}); record() {rec_ns:.0f} ns")

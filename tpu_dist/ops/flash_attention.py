@@ -107,6 +107,7 @@ def _flash_forward(q3, k3, v3, causal, bq, bk, interpret, window=None):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, S // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
@@ -291,6 +292,7 @@ def _flash_bwd(causal, bq, bk, interpret, window, res, g):
     )
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, causal=causal, window=window),
+        name="flash_bwd_dkv",
         grid=(bh, S // bk),
         in_specs=[full, pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
                   pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
@@ -308,6 +310,7 @@ def _flash_bwd(causal, bq, bk, interpret, window, res, g):
     )(q3, k3, v3, go, lse, D)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bk=bk, causal=causal, window=window),
+        name="flash_bwd_dq",
         grid=(bh, S // bq),
         in_specs=[pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
                   full, full,

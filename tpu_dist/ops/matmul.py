@@ -210,6 +210,7 @@ def _matmul_impl(x, w, b, epilogue, bm, bn, bk, interpret):
     kernel = functools.partial(_matmul_kernel, epilogue=epilogue, nk=nk)
     return pl.pallas_call(
         kernel,
+        name="matmul_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),

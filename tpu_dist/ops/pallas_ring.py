@@ -86,6 +86,7 @@ def _pallas_ring(
     without hardware."""
     return pl.pallas_call(
         functools.partial(_ring_kernel, axis_name=axis_name),
+        name="ring_all_reduce",
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -174,10 +174,11 @@ def accumulate_microbatches(
         )
         return (state, jax.tree.map(jnp.add, gacc, g), lacc + loss), aux
 
-    (new_state, gsum, lsum), auxs = lax.scan(
-        body, (model_state, g0, 0.0), (micro, jnp.arange(accum_steps))
-    )
-    grads = jax.tree.map(lambda g: g / accum_steps, gsum)
+    with jax.named_scope("grad_accum"):
+        (new_state, gsum, lsum), auxs = lax.scan(
+            body, (model_state, g0, 0.0), (micro, jnp.arange(accum_steps))
+        )
+        grads = jax.tree.map(lambda g: g / accum_steps, gsum)
     aux = jax.tree.map(
         lambda a: a.mean(0)
         if jnp.issubdtype(a.dtype, jnp.floating)
@@ -268,7 +269,7 @@ def make_spmd_train_step(
             loss = loss * inv
         return grads, loss, new_state, aux
 
-    def spmd_step(params, model_state, opt_state, batch, key):
+    def train_step(params, model_state, opt_state, batch, key):
         # fold over the DATA axis only: model-axis ranks run the same
         # replicated computation and must share keys (dropout identity)
         key = jax.random.fold_in(key, lax.axis_index(axis_name))
@@ -287,25 +288,29 @@ def make_spmd_train_step(
             # BEFORE the reduce, so the exact psum propagates the NaN to
             # every rank and the guard skips the step.
             grads = _poison(grads, ~jnp.isfinite(loss))
-        grads = average_gradients(grads, axis_name, backend=grad_reduce)
-        loss = lax.pmean(loss, axis_name)
-        for ax in extra_grad_axes:
-            grads = jax.tree.map(lambda g: lax.pmean(g, ax), grads)
-            loss = lax.pmean(loss, ax)
-            new_state = _pmean_float_leaves(new_state, ax)
-            aux = _pmean_float_leaves(aux, ax)
-        for ax in grad_psum_axes:
-            grads = jax.tree.map(lambda g: lax.psum(g, ax), grads)
-            loss = lax.pmean(loss, ax)  # replicated loss: mean, not sum
-            new_state = _pmean_float_leaves(new_state, ax)
-            aux = _pmean_float_leaves(aux, ax)
-        new_state = _pmean_float_leaves(new_state, axis_name)
-        aux = _pmean_float_leaves(aux, axis_name)
-        params, new_opt = optimizer.update(params, grads, opt_state)
+        with jax.named_scope("grad_sync"):
+            grads = average_gradients(grads, axis_name, backend=grad_reduce)
+            loss = lax.pmean(loss, axis_name)
+            for ax in extra_grad_axes:
+                grads = jax.tree.map(lambda g: lax.pmean(g, ax), grads)
+                loss = lax.pmean(loss, ax)
+                new_state = _pmean_float_leaves(new_state, ax)
+                aux = _pmean_float_leaves(aux, ax)
+            for ax in grad_psum_axes:
+                grads = jax.tree.map(lambda g: lax.psum(g, ax), grads)
+                loss = lax.pmean(loss, ax)  # replicated loss: mean, not sum
+                new_state = _pmean_float_leaves(new_state, ax)
+                aux = _pmean_float_leaves(aux, ax)
+            new_state = _pmean_float_leaves(new_state, axis_name)
+            aux = _pmean_float_leaves(aux, axis_name)
+        with jax.named_scope("optimizer"):
+            params, new_opt = optimizer.update(params, grads, opt_state)
         return params, new_state, new_opt, loss, aux
 
+    # jit names the program after the function it is handed: `train_step`
+    # on the trace's `XLA Modules` line
     mapped = jax.shard_map(
-        spmd_step,
+        train_step,
         mesh=mesh,
         in_specs=(
             P(), P(), P(),
@@ -344,15 +349,16 @@ def make_train_step_auto(
     repl = NamedSharding(mesh, P())
     sharded = NamedSharding(mesh, P(axis_name))
 
-    def global_step(params, model_state, opt_state, batch, key):
+    def train_step(params, model_state, opt_state, batch, key):
         (loss, (new_state, aux)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(params, model_state, batch, key)
-        params, opt_state = optimizer.update(params, grads, opt_state)
+        with jax.named_scope("optimizer"):
+            params, opt_state = optimizer.update(params, grads, opt_state)
         return params, new_state, opt_state, loss, aux
 
     return jax.jit(
-        global_step,
+        train_step,
         in_shardings=(repl, repl, repl, sharded, repl),
         out_shardings=(repl, repl, repl, repl, repl),
         donate_argnums=(0, 1, 2) if donate else (),

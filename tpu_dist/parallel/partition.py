@@ -987,10 +987,11 @@ def make_partitioned_train_step(
             (loss, aux), g = vg(params, mb, jax.random.fold_in(key, i))
             return (jax.tree.map(jnp.add, gacc, g), lacc + loss), aux
 
-        (gsum, lsum), auxs = jax.lax.scan(
-            body, (g0, 0.0), (micro, jnp.arange(accum_steps))
-        )
-        grads = jax.tree.map(lambda g: g / accum_steps, gsum)
+        with jax.named_scope("grad_accum"):
+            (gsum, lsum), auxs = jax.lax.scan(
+                body, (g0, 0.0), (micro, jnp.arange(accum_steps))
+            )
+            grads = jax.tree.map(lambda g: g / accum_steps, gsum)
         aux = jax.tree.map(
             lambda a: a.mean(0)
             if jnp.issubdtype(a.dtype, jnp.floating)
@@ -1002,7 +1003,7 @@ def make_partitioned_train_step(
     flat_plan = None
     if ccfg is None:
 
-        def global_step(params, opt_state, batch, key):
+        def train_step(params, opt_state, batch, key):
             if accum_steps == 1:
                 (loss, aux), grads = vg(params, batch, key)
             else:
@@ -1013,8 +1014,11 @@ def make_partitioned_train_step(
             # partitions with it instead of replicating (arxiv
             # 2004.13336's transformation, expressed as a sharding
             # constraint instead of a rewrite).
-            grads = jax.lax.with_sharding_constraint(grads, u_sh)
-            new_params, new_opt = optimizer.update(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                grads = jax.lax.with_sharding_constraint(grads, u_sh)
+                new_params, new_opt = optimizer.update(
+                    params, grads, opt_state
+                )
             return new_params, new_opt, loss, aux
 
         o_sh_step = o_sh
@@ -1148,11 +1152,12 @@ def make_partitioned_train_step(
                 from tpu_dist.resilience.guards import _poison
 
                 grads = _poison(grads, ~jnp.isfinite(loss))
-            grads, new_res, err = sync(grads, residual)
             from tpu_dist.parallel.data_parallel import _pmean_float_leaves
 
-            loss = jax.lax.pmean(loss, ax)
-            aux = _pmean_float_leaves(aux, ax)
+            with jax.named_scope("grad_sync"):
+                grads, new_res, err = sync(grads, residual)
+                loss = jax.lax.pmean(loss, ax)
+                aux = _pmean_float_leaves(aux, ax)
             return grads, loss, aux, new_res, err
 
         # manual over the data axes only; the model axes stay auto
@@ -1176,7 +1181,7 @@ def make_partitioned_train_step(
                 axis_names=manual,
             )
 
-        def global_step(params, opt_state, batch, key):
+        def train_step(params, opt_state, batch, key):
             inner_opt = opt_state["opt"] if wrap_ef else opt_state
             if wrap_ef:
                 grads, loss, aux, new_res, err = mapped(
@@ -1184,8 +1189,11 @@ def make_partitioned_train_step(
                 )
             else:
                 grads, loss, aux = mapped(params, batch, key)
-            grads = jax.lax.with_sharding_constraint(grads, u_sh)
-            new_params, new_opt = optimizer.update(params, grads, inner_opt)
+            with jax.named_scope("optimizer"):
+                grads = jax.lax.with_sharding_constraint(grads, u_sh)
+                new_params, new_opt = optimizer.update(
+                    params, grads, inner_opt
+                )
             if wrap_ef:
                 new_opt = {
                     "opt": new_opt,
@@ -1206,8 +1214,10 @@ def make_partitioned_train_step(
         else:
             o_sh_step = o_sh
 
+    # jit names the program after this function: `train_step` on the
+    # trace's `XLA Modules` line
     step = jax.jit(
-        global_step,
+        train_step,
         in_shardings=(p_sh, o_sh_step, b_sh, None),
         out_shardings=(p_sh, o_sh_step, None, None),
         donate_argnums=(0, 1) if donate else (),

@@ -20,8 +20,15 @@ of the time).  This engine is the standard fix:
   request mix), per-request PRNG streams keyed by (seed, token index);
 - request-lifecycle telemetry: ``request_admit`` / ``prefill`` /
   ``decode_step`` / ``request_finish`` events (`observe.events`
-  schema), occupancy / queue-depth / KV-pool gauges and TTFT / TPOT
-  histograms in `observe.registry.REGISTRY`.
+  schema), occupancy / queue-depth / KV-pool gauges, cumulative
+  counters and TTFT / TPOT histograms in `observe.registry.REGISTRY`;
+- spans in `observe.spans`' ring: one ``engine.step`` per call with its
+  phases as children (``engine.admit``, ``.decode_dispatch``,
+  ``.prefill_dispatch``, ``.decode_wait``, ``.decode_apply``,
+  ``.prefill_wait``, ``.prefill_apply``, ``.publish``; a phase that had
+  nothing to do leaves no span), and per request ``request.queued`` /
+  ``request.prefill`` / ``request.decode`` sharing ``request_id``
+  (docs/observability.md lists the attrs).
 
 Greedy decode through the engine is token-identical to the dense
 `generate` (tested across block sizes) — continuous batching changes
@@ -41,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist.observe import events as ev_mod
+from tpu_dist.observe import spans
 from tpu_dist.observe.registry import REGISTRY
 from tpu_dist.serve.paged_kv import (
     BlockAllocator,
@@ -102,6 +110,7 @@ class Request:
     prefill_pos: int = 0
     tokens: list = field(default_factory=list)
     arrival_time: float = 0.0
+    admit_time: float | None = None
     first_token_time: float | None = None
     finish_time: float | None = None
     token_times: list = field(default_factory=list)
@@ -121,6 +130,7 @@ class RequestResult:
     first_token_time: float | None
     finish_time: float
     token_times: list
+    admit_time: float | None = None  # None: cancelled while queued
 
     @property
     def emitted(self) -> int:
@@ -145,13 +155,14 @@ class ServeEngine:
     """The continuous-batching step loop over one model + paged pool.
 
     ``now``: injectable clock (tests pass a fake for deterministic
-    latency fields; benches pass ``time.perf_counter``).  The engine is
-    single-threaded by design — callers drive `step()` (or
+    latency fields).  The default is `observe.spans`' clock, so the
+    ``request.*`` spans, stamped with it, lie on the phase spans' axis.
+    The engine is single-threaded by design — callers drive `step()` (or
     `run_until_drained()`); thread-safety belongs to the front-end.
     """
 
     def __init__(self, lm, params, config: ServeConfig | None = None, *,
-                 now=time.monotonic, events=None):
+                 now=time.perf_counter, events=None):
         cfg = config or ServeConfig()
         if cfg.max_seq > lm.max_seq:
             raise ValueError(
@@ -169,6 +180,9 @@ class ServeEngine:
         self.lm, self.params, self.cfg = lm, params, cfg
         self._now = now
         self.events = events if events is not None else ev_mod.from_env()
+        # under TPU_DIST_TELEMETRY: the Chrome-trace file of the ring, this
+        # engine's spans in it, written at exit and on the crash paths
+        spans.from_env()
         from tpu_dist.observe import flightrec as _flightrec_mod
         from tpu_dist.observe import memory as _memory_mod
 
@@ -204,8 +218,9 @@ class ServeEngine:
         self.steps_with_prefill = 0
         self._next_id = 0
         # (kind, ...) tuples, appended in processing order — the
-        # determinism tests' observable
-        self.audit: list[tuple] = []
+        # determinism tests' observable.  Two a request: bounded, so a
+        # server's memory does not grow with the requests it has served.
+        self.audit: deque[tuple] = deque(maxlen=65536)
 
         self._decode_fn = self._build_decode_fn(greedy=False)
         self._decode_fn_greedy = self._build_decode_fn(greedy=True)
@@ -241,6 +256,29 @@ class ServeEngine:
         self._h_tpot = REGISTRY.histogram(
             "tpu_dist_serve_tpot_seconds", "per-token decode latency"
         )
+        counter = lambda name, what: REGISTRY.counter(  # noqa: E731
+            f"tpu_dist_serve_{name}_total", what
+        )
+        self._c_admitted = counter("admitted", "requests admitted to a slot")
+        self._c_blocked = counter(
+            "blocked_steps",
+            "engine steps that left the queue's head waiting, by cause",
+        )
+        self._c_prefill_rows = counter(
+            "prefill_rows", "request chunks run by prefill rounds"
+        )
+        self._c_prefill_real = counter(
+            "prefill_real_tokens", "prompt tokens run by prefill rounds"
+        )
+        self._c_prefill_padded = counter(
+            "prefill_padded_tokens",
+            "token places of prefill rounds (rows x chunk), padding included",
+        )
+        self._c_repacks = counter(
+            "state_repacks",
+            "decode dispatches that rebuilt the packed slot state",
+        )
+        self._c_decode_steps = counter("decode_steps", "decode steps dispatched")
         # Memory breakdown: what this engine keeps resident — weights
         # vs KV pool (allocated in full at init; blocks are GRANTS of
         # that pool) vs whatever headroom the device has left for
@@ -299,26 +337,32 @@ class ServeEngine:
                 lm, params, last_tok[:, None], cache, block_tables,
                 index[:, None], active[:, None], bs,
             )
-            if greedy:
-                toks = jnp.argmax(logits[:, 0], axis=-1).astype(
-                    last_tok.dtype
+            with jax.named_scope("sample"):
+                if greedy:
+                    toks = jnp.argmax(logits[:, 0], axis=-1).astype(
+                        last_tok.dtype
+                    )
+                else:
+                    keys = slot_keys(
+                        ints[:, MB + self._SEED],
+                        ints[:, MB + self._COUNTER],
+                    )
+                    toks = sample_slots(
+                        logits[:, 0], keys, flt[:, 0],
+                        ints[:, MB + self._TOPK], flt[:, 1],
+                        last_tok.dtype,
+                    )
+            with jax.named_scope("state_update"):
+                inc = active.astype(jnp.int32)
+                ints = ints.at[:, MB + self._LASTTOK].set(
+                    jnp.where(active, toks, last_tok)
                 )
-            else:
-                keys = slot_keys(
-                    ints[:, MB + self._SEED], ints[:, MB + self._COUNTER]
-                )
-                toks = sample_slots(
-                    logits[:, 0], keys, flt[:, 0],
-                    ints[:, MB + self._TOPK], flt[:, 1], last_tok.dtype,
-                )
-            inc = active.astype(jnp.int32)
-            ints = ints.at[:, MB + self._LASTTOK].set(
-                jnp.where(active, toks, last_tok)
-            )
-            ints = ints.at[:, MB + self._INDEX].add(inc)
-            ints = ints.at[:, MB + self._COUNTER].add(inc)
+                ints = ints.at[:, MB + self._INDEX].add(inc)
+                ints = ints.at[:, MB + self._COUNTER].add(inc)
             return toks, ints, cache
 
+        # the program's name on the trace's `XLA Modules` line
+        fn.__name__ = "serve_decode_greedy" if greedy else "serve_decode_sampled"
         return jax.jit(fn, donate_argnums=(1, 2))
 
     def _build_prefill_fn(self):
@@ -331,7 +375,7 @@ class ServeEngine:
         lm, bs, C = self.lm, self.cfg.block_size, self.cfg.prefill_chunk
         MB = self.blocks_per_seq
 
-        def fn(params, cache, ints, flt):
+        def serve_prefill(params, cache, ints, flt):
             # ints columns: [tokens(C) | block_table(MB) | start |
             #                real_len | top_k | seed]
             tokens = ints[:, :C]
@@ -344,21 +388,22 @@ class ServeEngine:
                 lm, params, tokens, cache, block_tables, positions,
                 write_mask, bs,
             )
-            last = jnp.take_along_axis(
-                logits,
-                jnp.maximum(real_len, 1)[:, None, None] - 1,
-                axis=1,
-            )[:, 0]
-            keys = slot_keys(
-                ints[:, C + MB + 3], jnp.zeros_like(real_len)
-            )
-            toks = sample_slots(
-                last, keys, flt[:, 0], ints[:, C + MB + 2], flt[:, 1],
-                tokens.dtype,
-            )
+            with jax.named_scope("sample"):
+                last = jnp.take_along_axis(
+                    logits,
+                    jnp.maximum(real_len, 1)[:, None, None] - 1,
+                    axis=1,
+                )[:, 0]
+                keys = slot_keys(
+                    ints[:, C + MB + 3], jnp.zeros_like(real_len)
+                )
+                toks = sample_slots(
+                    last, keys, flt[:, 0], ints[:, C + MB + 2], flt[:, 1],
+                    tokens.dtype,
+                )
             return toks, cache
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(serve_prefill, donate_argnums=(1,))
 
     # ------------------------------------------------------ static analysis
 
@@ -560,48 +605,58 @@ class ServeEngine:
         filling slots fast raises the occupancy every later decode step
         amortizes over, at the bounded cost of delaying at most half a
         batch by one prefill round."""
-        self._process_cancels()
-        self._admit()
-        prefer_prefill = (
-            len(self._prefillq) > self.cfg.prefill_batch
-            and self.occupancy() <= self.cfg.max_batch // 2
-        )
-        try:
-            decode_toks = None if prefer_prefill else self._decode_dispatch()
-        except Exception as e:
-            self._oom(e, "decode")
-            raise
-        try:
-            prefill_ctx = self._prefill_dispatch()
-        except Exception as e:
-            self._oom(e, "prefill")
-            raise
-        try:
-            did_decode = self._decode_complete(decode_toks)
-        except Exception as e:
-            self._oom(e, "decode")
-            raise
-        try:
-            did_prefill = self._prefill_complete(prefill_ctx)
-        except Exception as e:
-            self._oom(e, "prefill")
-            raise
-        if self.events.enabled and not self._warming:
-            if did_decode:
-                self._memory.sample("decode")
-            if did_prefill:
-                self._memory.sample("prefill")
-        self.steps_with_prefill += bool(did_prefill)
-        self.steps_with_decode += bool(did_decode)
-        if did_prefill or did_decode:
-            # Flight ring (observe.flightrec): one deque append per
-            # working step, so a wedged decode gang's post-mortem dump
-            # shows the serving loop's last completed steps too.
-            self._flight.record(
-                "step", step=self.step_count, phase="readback",
-                occupancy=self.occupancy(),
+        with spans.span("engine.step", step=self.step_count) as sp:
+            with spans.span("engine.admit"):
+                self._process_cancels()
+                admitted, blocked = self._admit()
+            prefer_prefill = (
+                len(self._prefillq) > self.cfg.prefill_batch
+                and self.occupancy() <= self.cfg.max_batch // 2
             )
-        self._publish(did_prefill or did_decode)
+            try:
+                decode_toks = (
+                    None if prefer_prefill else self._decode_dispatch()
+                )
+            except Exception as e:
+                self._oom(e, "decode")
+                raise
+            try:
+                prefill_ctx = self._prefill_dispatch()
+            except Exception as e:
+                self._oom(e, "prefill")
+                raise
+            try:
+                did_decode = self._decode_complete(decode_toks)
+            except Exception as e:
+                self._oom(e, "decode")
+                raise
+            try:
+                did_prefill = self._prefill_complete(prefill_ctx)
+            except Exception as e:
+                self._oom(e, "prefill")
+                raise
+            with spans.span("engine.publish"):
+                if self.events.enabled and not self._warming:
+                    if did_decode:
+                        self._memory.sample("decode")
+                    if did_prefill:
+                        self._memory.sample("prefill")
+                self.steps_with_prefill += bool(did_prefill)
+                self.steps_with_decode += bool(did_decode)
+                if did_prefill or did_decode:
+                    # Flight ring (observe.flightrec): one deque append
+                    # per working step, so a wedged decode gang's
+                    # post-mortem dump shows the serving loop's last
+                    # completed steps too.
+                    self._flight.record(
+                        "step", step=self.step_count, phase="readback",
+                        occupancy=self.occupancy(),
+                    )
+                occ = self._publish(did_prefill or did_decode)
+            sp.attrs.update(
+                occupancy=occ, queued=len(self.queue),
+                admitted=admitted, blocked=blocked,
+            )
         self.step_count += 1
 
     def _process_cancels(self) -> None:
@@ -616,10 +671,21 @@ class ServeEngine:
                 self._evict(s, "cancelled", tnow)
         self._cancelled.clear()  # ids that were already finished
 
-    def _admit(self) -> None:
+    def _count(self, counter, amount: int = 1, **labels) -> None:
+        """The registry's totals leave warm-up's throwaway requests out,
+        as its histograms do."""
+        if not self._warming:
+            counter.inc(amount, **labels)
+
+    def _admit(self) -> tuple[int, str]:
+        """Admit from the queue's head while a slot and its blocks are
+        free.  Returns how many were admitted and why the head stayed:
+        ``""`` (the queue emptied), ``"slots"`` or ``"blocks"``."""
+        admitted, blocked = 0, ""
         while self.queue:
             free = [s for s, r in enumerate(self.slots) if r is None]
             if not free:
+                blocked = "slots"
                 break
             req = self.queue[0]
             need = math.ceil(
@@ -627,9 +693,17 @@ class ServeEngine:
             )
             blocks = self.allocator.alloc(need)
             if blocks is None:
-                break  # head-of-line blocks; FIFO stays deterministic
+                # head-of-line blocks; FIFO stays deterministic
+                blocked = "blocks"
+                break
             self._check_block_grant(req, need)
             self.queue.popleft()
+            admitted += 1
+            req.admit_time = self._now()
+            spans.record(
+                "request.queued", req.arrival_time, req.admit_time,
+                request_id=req.request_id,
+            )
             s = free[0]
             req.slot, req.blocks, req.state = s, blocks, "prefill"
             self.slots[s] = req
@@ -658,6 +732,11 @@ class ServeEngine:
                 max_new_tokens=int(req.max_new_tokens),
                 queue_depth=len(self.queue),
             )
+        if admitted:
+            self._count(self._c_admitted, admitted)
+        if blocked:
+            self._count(self._c_blocked, cause=blocked)
+        return admitted, blocked
 
     def _prefill_dispatch(self):
         """Assemble + dispatch one chunk for each of (up to
@@ -670,25 +749,31 @@ class ServeEngine:
         C, MB = self.cfg.prefill_chunk, self.blocks_per_seq
         take = list(self._prefillq)[: self.cfg.prefill_batch]
         P = len(take)
-        ints = np.zeros((P, C + MB + 4), np.int32)
-        flt = np.zeros((P, 2), np.float32)
-        chunks = []
-        for r, s in enumerate(take):
-            req = self.slots[s]
-            start = req.prefill_pos
-            chunk = req.prompt[start : start + C]
-            chunks.append((s, req, start, chunk.size))
-            ints[r, : chunk.size] = chunk
-            ints[r, C : C + MB] = self.block_tables[s]
-            ints[r, C + MB] = start
-            ints[r, C + MB + 1] = chunk.size
-            ints[r, C + MB + 2] = self.top_k[s]
-            ints[r, C + MB + 3] = self.seeds[s]
-            flt[r, 0] = self.temperature[s]
-            flt[r, 1] = self.top_p[s]
-        first_toks, self.cache = self._prefill_fn(
-            self.params, self.cache, ints, flt
-        )
+        with spans.span("engine.prefill_dispatch", rows=P, chunk=C) as sp:
+            ints = np.zeros((P, C + MB + 4), np.int32)
+            flt = np.zeros((P, 2), np.float32)
+            chunks = []
+            for r, s in enumerate(take):
+                req = self.slots[s]
+                start = req.prefill_pos
+                chunk = req.prompt[start : start + C]
+                chunks.append((s, req, start, chunk.size))
+                ints[r, : chunk.size] = chunk
+                ints[r, C : C + MB] = self.block_tables[s]
+                ints[r, C + MB] = start
+                ints[r, C + MB + 1] = chunk.size
+                ints[r, C + MB + 2] = self.top_k[s]
+                ints[r, C + MB + 3] = self.seeds[s]
+                flt[r, 0] = self.temperature[s]
+                flt[r, 1] = self.top_p[s]
+            first_toks, self.cache = self._prefill_fn(
+                self.params, self.cache, ints, flt
+            )
+            real = sum(size for _, _, _, size in chunks)
+            sp.attrs["real_tokens"] = real
+        self._count(self._c_prefill_rows, P)
+        self._count(self._c_prefill_real, real)
+        self._count(self._c_prefill_padded, P * C)
         return chunks, first_toks
 
     def _prefill_complete(self, ctx) -> bool:
@@ -704,34 +789,43 @@ class ServeEngine:
             r for r, (s, req, start, size) in enumerate(chunks)
             if start + size >= req.prompt.size
         ]
-        toks_np = np.asarray(first_toks) if finishing else None
+        toks_np = None
+        if finishing:
+            # the one place the host waits for the prefill round
+            with spans.span("engine.prefill_wait"):
+                toks_np = np.asarray(first_toks)
         tnow = self._now()
-        for r, (s, req, start, size) in enumerate(chunks):
-            req.prefill_pos += size
-            self.events.emit(
-                "prefill",
-                request_id=req.request_id,
-                chunk=start // self.cfg.prefill_chunk,
-                tokens=size,
-                done=req.prefill_pos >= req.prompt.size,
-            )
-            if req.prefill_pos < req.prompt.size:
-                continue
-            self._prefillq.remove(s)
-            tok = int(toks_np[r])
-            req.tokens.append(tok)
-            req.token_times.append(tnow)
-            req.first_token_time = tnow
-            if not self._warming:
-                self._h_ttft.observe(tnow - req.arrival_time)
-            self.counters[s] += 1
-            self.last_tok[s] = tok
-            self.index[s] = req.prompt.size
-            req.state = "decode"
-            self.active[s] = True
-            self._dirty = True
-            if self._finished_by(req, tok):
-                self._evict(s, self._finish_reason(req, tok), tnow)
+        with spans.span("engine.prefill_apply"):
+            for r, (s, req, start, size) in enumerate(chunks):
+                req.prefill_pos += size
+                self.events.emit(
+                    "prefill",
+                    request_id=req.request_id,
+                    chunk=start // self.cfg.prefill_chunk,
+                    tokens=size,
+                    done=req.prefill_pos >= req.prompt.size,
+                )
+                if req.prefill_pos < req.prompt.size:
+                    continue
+                self._prefillq.remove(s)
+                tok = int(toks_np[r])
+                req.tokens.append(tok)
+                req.token_times.append(tnow)
+                req.first_token_time = tnow
+                spans.record(
+                    "request.prefill", req.admit_time, tnow,
+                    request_id=req.request_id,
+                )
+                if not self._warming:
+                    self._h_ttft.observe(tnow - req.arrival_time)
+                self.counters[s] += 1
+                self.last_tok[s] = tok
+                self.index[s] = req.prompt.size
+                req.state = "decode"
+                self.active[s] = True
+                self._dirty = True
+                if self._finished_by(req, tok):
+                    self._evict(s, self._finish_reason(req, tok), tnow)
         return True
 
     def _decode_dispatch(self):
@@ -739,17 +833,22 @@ class ServeEngine:
         readback yet).  Returns the tokens' device handle, or None."""
         if not self.active.any():
             return None
-        if self._dirty:
-            self._dint, self._dflt = self._pack_state()
-            self._dirty = False
-        fn = (
-            self._decode_fn_greedy
-            if not self.temperature[self.active].any()
-            else self._decode_fn
-        )
-        toks, self._dint, self.cache = fn(
-            self.params, self.cache, self._dint, self._dflt
-        )
+        repacked = self._dirty
+        with spans.span("engine.decode_dispatch", repacked=repacked):
+            if repacked:
+                self._dint, self._dflt = self._pack_state()
+                self._dirty = False
+            fn = (
+                self._decode_fn_greedy
+                if not self.temperature[self.active].any()
+                else self._decode_fn
+            )
+            toks, self._dint, self.cache = fn(
+                self.params, self.cache, self._dint, self._dflt
+            )
+        self._count(self._c_decode_steps)
+        if repacked:
+            self._count(self._c_repacks)
         return toks
 
     def _decode_complete(self, toks) -> bool:
@@ -758,21 +857,23 @@ class ServeEngine:
         compute half."""
         if toks is None:
             return False
-        toks_np = np.asarray(toks)  # host sync: the step boundary
+        with spans.span("engine.decode_wait"):
+            toks_np = np.asarray(toks)  # host sync: the step boundary
         tnow = self._now()
-        active = np.nonzero(self.active)[0]
-        self.last_tok[active] = toks_np[active]
-        self.index[active] += 1
-        self.counters[active] += 1
-        for s in active:
-            req = self.slots[s]
-            tok = int(toks_np[s])
-            if req.token_times and not self._warming:
-                self._h_tpot.observe(tnow - req.token_times[-1])
-            req.tokens.append(tok)
-            req.token_times.append(tnow)
-            if self._finished_by(req, tok):
-                self._evict(s, self._finish_reason(req, tok), tnow)
+        with spans.span("engine.decode_apply"):
+            active = np.nonzero(self.active)[0]
+            self.last_tok[active] = toks_np[active]
+            self.index[active] += 1
+            self.counters[active] += 1
+            for s in active:
+                req = self.slots[s]
+                tok = int(toks_np[s])
+                if req.token_times and not self._warming:
+                    self._h_tpot.observe(tnow - req.token_times[-1])
+                req.tokens.append(tok)
+                req.token_times.append(tnow)
+                if self._finished_by(req, tok):
+                    self._evict(s, self._finish_reason(req, tok), tnow)
         return True
 
     @staticmethod
@@ -811,8 +912,15 @@ class ServeEngine:
             first_token_time=req.first_token_time,
             finish_time=tnow,
             token_times=list(req.token_times),
+            admit_time=req.admit_time,
         )
         self.results[req.request_id] = result
+        if req.first_token_time is not None:
+            spans.record(
+                "request.decode", req.first_token_time, tnow,
+                request_id=req.request_id, emitted=len(req.tokens),
+                finish_reason=reason,
+            )
         self.audit.append(
             ("finish", req.request_id, reason, len(req.tokens),
              self.step_count)
@@ -826,8 +934,10 @@ class ServeEngine:
             tpot_mean=result.tpot_mean,
         )
 
-    def _publish(self, worked: bool) -> None:
-        occ = int(self.active.sum())
+    def _publish(self, worked: bool) -> int:
+        """Gauges and the sampled ``decode_step`` event; returns the
+        occupancy it published."""
+        occ = self.occupancy()
         self._g_occ.set(occ)
         self._g_queue.set(len(self.queue))
         self._g_blocks.set(self.allocator.used)
@@ -841,6 +951,7 @@ class ServeEngine:
                 kv_blocks_used=self.allocator.used,
                 kv_block_utilization=self.allocator.utilization(),
             )
+        return occ
 
     # ----------------------------------------------------------- accessors
 

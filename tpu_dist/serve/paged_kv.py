@@ -132,48 +132,53 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
     `MultiHeadAttention.apply_cached`, with the contiguous cache
     replaced by the (scatter, gather) pair."""
     S, s, _ = x.shape
-    q, k, v = attn._project(params, x)
-    if attn.use_rope:
-        q, k = _rope_slots(q, positions), _rope_slots(k, positions)
+    with jax.named_scope("attn/qkv"):
+        q, k, v = attn._project(params, x)
+        if attn.use_rope:
+            q, k = _rope_slots(q, positions), _rope_slots(k, positions)
 
-    scratch = k_pool.shape[0] - 1
-    blk = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
-    blk = jnp.where(write_mask, blk, scratch).reshape(-1)
-    off = (positions % block_size).reshape(-1)
-    k_w = jnp.moveaxis(k.astype(k_pool.dtype), 1, 2).reshape(
-        S * s, attn.kv_heads, attn.head_dim
-    )
-    v_w = jnp.moveaxis(v.astype(v_pool.dtype), 1, 2).reshape(
-        S * s, attn.kv_heads, attn.head_dim
-    )
-    k_pool = k_pool.at[blk, :, off].set(k_w)
-    v_pool = v_pool.at[blk, :, off].set(v_w)
+    with jax.named_scope("attn/kv_scatter"):
+        scratch = k_pool.shape[0] - 1
+        blk = jnp.take_along_axis(
+            block_tables, positions // block_size, axis=1
+        )
+        blk = jnp.where(write_mask, blk, scratch).reshape(-1)
+        off = (positions % block_size).reshape(-1)
+        k_w = jnp.moveaxis(k.astype(k_pool.dtype), 1, 2).reshape(
+            S * s, attn.kv_heads, attn.head_dim
+        )
+        v_w = jnp.moveaxis(v.astype(v_pool.dtype), 1, 2).reshape(
+            S * s, attn.kv_heads, attn.head_dim
+        )
+        k_pool = k_pool.at[blk, :, off].set(k_w)
+        v_pool = v_pool.at[blk, :, off].set(v_w)
 
     # gather the per-slot tables back into the contiguous dense-cache
     # layout; from here on the math is exactly apply_cached's
     L = block_tables.shape[1] * block_size
-    k_full = jnp.moveaxis(k_pool[block_tables], 2, 1).reshape(
-        S, attn.kv_heads, L, attn.head_dim
-    )
-    v_full = jnp.moveaxis(v_pool[block_tables], 2, 1).reshape(
-        S, attn.kv_heads, L, attn.head_dim
-    )
-    scale = attn.head_dim**-0.5
-    logits = jnp.einsum(
-        "bhqd,bhkd->bhqk", q * scale, attn._expand_kv(k_full).astype(q.dtype)
-    )
-    pos_k = jnp.arange(L)[None, None, :]
-    qpos = positions[:, :, None]
-    visible = pos_k <= qpos  # (S, s, L), per-slot positions
-    if attn.sliding_window is not None:
-        visible = visible & (pos_k > qpos - attn.sliding_window)
-    logits = jnp.where(visible[:, None], logits, -1e30)
-    weights = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum(
-        "bhqk,bhkd->bhqd", weights, attn._expand_kv(v_full).astype(q.dtype)
-    )
-    o = jnp.moveaxis(o, 1, 2).reshape(S, s, attn.dim)
-    y, _ = attn._out.apply(params["out"], {}, o)
+    with jax.named_scope("attn/kv_gather"):
+        k_full = jnp.moveaxis(k_pool[block_tables], 2, 1).reshape(
+            S, attn.kv_heads, L, attn.head_dim
+        )
+        v_full = jnp.moveaxis(v_pool[block_tables], 2, 1).reshape(
+            S, attn.kv_heads, L, attn.head_dim
+        )
+        k_full = attn._expand_kv(k_full).astype(q.dtype)
+        v_full = attn._expand_kv(v_full).astype(q.dtype)
+    with jax.named_scope("attn/scores"):
+        scale = attn.head_dim**-0.5
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k_full)
+        pos_k = jnp.arange(L)[None, None, :]
+        qpos = positions[:, :, None]
+        visible = pos_k <= qpos  # (S, s, L), per-slot positions
+        if attn.sliding_window is not None:
+            visible = visible & (pos_k > qpos - attn.sliding_window)
+        logits = jnp.where(visible[:, None], logits, -1e30)
+        weights = jax.nn.softmax(logits, axis=-1)
+        o = jnp.einsum("bhqk,bhkd->bhqd", weights, v_full)
+    with jax.named_scope("attn/out"):
+        o = jnp.moveaxis(o, 1, 2).reshape(S, s, attn.dim)
+        y, _ = attn._out.apply(params["out"], {}, o)
     return y, k_pool, v_pool
 
 
@@ -193,21 +198,26 @@ def paged_apply_cached(lm, params, tokens, cache, block_tables, positions,
     pool view equals the dense contiguous cache for every visible
     position, and every op after the gather is the dense op."""
     L = block_tables.shape[1] * block_size
-    positions = jnp.clip(positions, 0, min(lm.max_seq, L) - 1)
-    h = params["embed"]["table"][tokens]
-    if lm.pos_embedding == "learned":
-        h = h + params["pos"][0][positions]
+    with jax.named_scope("embed"):
+        positions = jnp.clip(positions, 0, min(lm.max_seq, L) - 1)
+        h = params["embed"]["table"][tokens]
+        if lm.pos_embedding == "learned":
+            h = h + params["pos"][0][positions]
     new_cache = []
     for blk, pb, c in zip(lm.blocks, params["blocks"], cache):
-        x1, _ = blk.ln1.apply(pb["ln1"], {}, h)
+        with jax.named_scope("ln"):
+            x1, _ = blk.ln1.apply(pb["ln1"], {}, h)
         o, ck, cv = _paged_attention(
             blk.attn, pb["attn"], x1, c["k"], c["v"], block_tables,
             positions, write_mask, block_size,
         )
         h = h + o
-        x2, _ = blk.ln2.apply(pb["ln2"], {}, h)
-        h = h + lm._mlp_or_moe(blk, pb, x2)
+        with jax.named_scope("ln"):
+            x2, _ = blk.ln2.apply(pb["ln2"], {}, h)
+        with jax.named_scope("mlp"):
+            h = h + lm._mlp_or_moe(blk, pb, x2)
         new_cache.append({"k": ck, "v": cv})
-    h, _ = lm.ln.apply(params["ln"], {}, h)
-    logits = h @ params["embed"]["table"].T
+    with jax.named_scope("lm_head"):
+        h, _ = lm.ln.apply(params["ln"], {}, h)
+        logits = h @ params["embed"]["table"].T
     return logits, new_cache

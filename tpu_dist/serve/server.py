@@ -28,7 +28,7 @@ class LMServer:
     """One model, one paged KV pool, one admission queue."""
 
     def __init__(self, lm, params, config: ServeConfig | None = None, *,
-                 now=time.monotonic, events=None):
+                 now=time.perf_counter, events=None):
         self.lm = lm
         self.engine = ServeEngine(
             lm, params, config, now=now, events=events

@@ -429,12 +429,13 @@ class LMTrainer:
         def cast(p):
             if compute is None:
                 return p
-            return jax.tree.map(
-                lambda a: a.astype(compute)
-                if jnp.issubdtype(a.dtype, jnp.floating)
-                else a,
-                p,
-            )
+            with jax.named_scope("cast"):
+                return jax.tree.map(
+                    lambda a: a.astype(compute)
+                    if jnp.issubdtype(a.dtype, jnp.floating)
+                    else a,
+                    p,
+                )
 
         def mode_loss(p, tokens):
             """The per-rank loss for the active model-sharding mode."""
@@ -482,7 +483,8 @@ class LMTrainer:
                     cast(p), tokens, parallel.DATA_AXIS
                 )
             logits, _ = self.lm.apply(cast(p), {}, tokens)
-            return lm_loss(logits.astype(jnp.float32), tokens)
+            with jax.named_scope("loss"):  # the f32 logits are the loss's
+                return lm_loss(logits.astype(jnp.float32), tokens)
 
         def loss_fn(p, s, batch, key):
             (tokens,) = batch
@@ -510,7 +512,8 @@ class LMTrainer:
             def engine_loss(p, batch, key):
                 (tokens,) = batch
                 logits, _ = self.lm.apply(cast(p), {}, tokens)
-                return lm_loss(logits.astype(jnp.float32), tokens), {}
+                with jax.named_scope("loss"):  # the f32 logits are the loss's
+                    return lm_loss(logits.astype(jnp.float32), tokens), {}
 
             built = parallel.make_partitioned_train_step(
                 engine_loss, self.optimizer, mesh, params, self._ruleset,
