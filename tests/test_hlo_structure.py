@@ -390,3 +390,92 @@ class TestProgramNamesAndScopes:
         x = jnp.ones((128, 128), jnp.float32)
         assert "name=matmul_fused\n" in str(jax.make_jaxpr(
             lambda x: matmul(x, x, interpret=True))(x))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip: libtpu compiles for it here.
+    Made inside a fixture, never at import: only the worker that runs
+    this file may load the TPU's library."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+class TestServingPoolIsNotRelayoutOnTheV5e:
+    """The serving programs, compiled for the v5e, update the donated KV
+    pool in place.  With a 64-wide last dimension the device's own layout
+    of a pool array puts another dimension minor-most, and every program
+    then copied every layer's whole pool on entry and again on exit (two
+    thirds of the serving cells' device time, ledger PR 25); with heads
+    and head_dim folded into one minor dimension (serve/paged_kv.py) the
+    argument, the scatter and the gather agree."""
+
+    # head_dim 64, block_size 16: the two minor dimensions decide.  Where
+    # the row (kv_heads * head_dim) is no multiple of 128 the device
+    # still picks the dimension whose padding to a 128-lane tile wastes
+    # least: 192 -> 256 against 257 -> 384 blocks keeps the row minor
+    # (docs/serving.md has the rule and its limits).
+    SHAPES = {
+        "mha_row128": dict(dim=128, heads=2, num_blocks=255),
+        "gqa_row192": dict(dim=384, heads=6, kv_heads=3, num_blocks=256),
+    }
+
+    @pytest.fixture(scope="class", params=list(SHAPES))
+    def engine(self, request):
+        from tpu_dist import serve
+
+        shape = dict(self.SHAPES[request.param])
+        num_blocks = shape.pop("num_blocks")
+        lm = models.TransformerLM(vocab=128, depth=2, max_seq=64, **shape)
+        params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                              lm.init(jax.random.key(0))[0])
+        return serve.ServeEngine(lm, params, serve.ServeConfig(
+            max_batch=4, block_size=16, num_blocks=num_blocks, max_seq=64,
+            prefill_chunk=16, prefill_batch=2))
+
+    @pytest.mark.parametrize("program,rows", [
+        ("serve_decode_greedy", None), ("serve_decode_sampled", None),
+        ("serve_prefill", 1), ("serve_prefill", 2)])
+    def test_no_pool_sized_copy_and_the_pool_is_aliased(
+            self, engine, v5e_chip, program, rows):
+        import math
+        import re
+
+        from tpu_dist.analysis.lints import donated_buffer_count
+
+        programs = engine.analysis_programs()
+        if rows:  # the prefill program is retraced for each row count
+            fn, (params, cache, ints, flt) = programs["serve_prefill"]
+            ints, flt = (jax.ShapeDtypeStruct((rows,) + a.shape[1:], a.dtype)
+                         for a in (ints, flt))
+        else:
+            _, (params, cache, ints, flt) = programs["serve_decode"]
+            fn = (engine._decode_fn_greedy if program.endswith("greedy")
+                  else engine._decode_fn)
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            (params, cache, ints, flt))
+        text = fn.lower(*args).compile().as_text()
+
+        pool = math.prod(cache[0]["k"].shape)
+        # "pool-sized" is unambiguous: nothing else in the program is as large
+        assert pool > max(math.prod(a.shape) for a in jax.tree.leaves(params))
+        copy_of = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(")
+        copies = [
+            line.strip() for line in text.splitlines()
+            if (m := copy_of.search(line))
+            and math.prod(int(d) for d in m.group(1).split(",") if d) == pool]
+        assert not copies, (
+            f"{program} copies a whole pool array "
+            f"{cache[0]['k'].shape}:\n" + "\n".join(copies[:4]))
+        # every pool array is donated AND aliased to its output
+        assert donated_buffer_count(text) >= len(jax.tree.leaves(cache))

@@ -158,6 +158,51 @@ class TestPagedParity:
         np.testing.assert_array_equal(got, dense)
 
 
+class TestPoolLayout:
+    """The pool is ``(num_blocks + 1, block_size, kv_heads * head_dim)``:
+    a token's k/v is ONE contiguous row at ``[blk, off]``, heads major
+    within it (docs/serving.md: the shape the device's own layout agrees
+    with, so no program relayouts the pool)."""
+
+    @pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+    def test_rows_land_at_blk_off_and_masked_writes_in_scratch(self, kv_heads):
+        from tpu_dist.parallel import per_device_bytes
+
+        bs, nblk, depth, hd = 4, 6, 2, 8
+        lm = models.TransformerLM(vocab=64, dim=32, depth=depth, heads=4,
+                                  max_seq=16, kv_heads=kv_heads)
+        params, _ = lm.init(jax.random.key(3))
+        cache = serve.init_paged_cache(lm, nblk, bs)
+        assert cache[0]["k"].shape == (nblk + 1, bs, kv_heads * hd)
+        assert per_device_bytes(cache) == (
+            2 * depth * (nblk + 1) * bs * kv_heads * hd * 4)
+
+        # slot 0 writes positions 2..5 through blocks (5, 1); slot 1 is
+        # masked from its third token on (a padded prefill row)
+        tokens = jnp.asarray([[3, 9, 27, 17], [5, 25, 61, 49]], jnp.int32)
+        positions = jnp.asarray([[2, 3, 4, 5], [0, 1, 2, 3]], jnp.int32)
+        tables = jnp.asarray([[5, 1, nblk], [2, nblk, nblk]], jnp.int32)
+        mask = jnp.asarray([[True] * 4, [True, True, False, False]])
+        _, new = serve.paged_apply_cached(
+            lm, params, tokens, cache, tables, positions, mask, bs)
+
+        # the first block's k/v, computed apart from the paged path
+        h = params["embed"]["table"][tokens] + params["pos"][0][positions]
+        blk0, pb0 = lm.blocks[0], params["blocks"][0]
+        x1, _ = blk0.ln1.apply(pb0["ln1"], {}, h)
+        _, k, v = blk0.attn._project(pb0["attn"], x1)  # (S, kv_heads, s, hd)
+        for side, t in (("k", np.asarray(k)), ("v", np.asarray(v))):
+            want = np.zeros((nblk, bs, kv_heads, hd), np.float32)
+            for slot, tok in np.argwhere(np.asarray(mask)):
+                pos = int(positions[slot, tok])
+                want[int(tables[slot, pos // bs]), pos % bs] = t[slot, :, tok]
+            got = np.asarray(new[0][side]).reshape(nblk + 1, bs, kv_heads, hd)
+            np.testing.assert_allclose(got[:nblk], want, rtol=1e-6, atol=1e-6)
+            # the two masked tokens (positions 2, 3) went to scratch alone
+            assert np.abs(got[nblk, 2:]).sum() > 0
+            assert not got[nblk, :2].any()
+
+
 class TestEngineScheduling:
     def test_deterministic_under_seeded_trace(self, lm, lm_params):
         """Same trace, same engine config -> identical admission /

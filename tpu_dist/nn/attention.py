@@ -200,12 +200,13 @@ class MultiHeadAttention(Module):
         k, v = (jnp.moveaxis(kv[:, :, i], 1, 2) for i in range(2))
         return q, k, v
 
-    def _expand_kv(self, t):
-        """Repeat each kv head across its query-head group (XLA folds the
-        broadcast into the batched matmul; nothing materializes in HBM)."""
+    def _expand_kv(self, t, axis: int = 1):
+        """Repeat each kv head (on ``axis``) across its query-head group
+        (XLA folds the broadcast into the batched matmul; nothing
+        materializes in HBM)."""
         if self.group == 1:
             return t
-        return jnp.repeat(t, self.group, axis=1)
+        return jnp.repeat(t, self.group, axis=axis)
 
     def apply(self, params, state, x, *, train=False, key=None, mask=None):
         """``mask``: optional boolean, either a key-padding mask

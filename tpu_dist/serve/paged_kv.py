@@ -16,14 +16,24 @@ The device side is `paged_apply_cached`: the SAME math as
 `TransformerLM.apply_cached` (tests assert greedy decode through it is
 token-identical to the dense `generate`) with two differences:
 
-- **write**: a token's k/v rows scatter into
-  ``pool[table[pos // block_size], :, pos % block_size]`` instead of a
+- **write**: a token's k/v scatters as ONE contiguous row of
+  ``kv_heads * head_dim`` (heads major) into
+  ``pool[table[pos // block_size], pos % block_size]`` instead of a
   ``dynamic_update_slice`` into a contiguous cache (masked-off tokens —
   pads, inactive slots — write to a reserved scratch block);
 - **read**: the per-slot tables gather the pool back into a contiguous
-  ``(slots, kv_heads, L, head_dim)`` view, after which the attention
-  (scale, position mask, -1e30 fill, softmax) is exactly the dense
-  incremental attention, per-slot positions included.
+  ``(slots, L, kv_heads, head_dim)`` view by reshape alone, contracted
+  where it lies; the attention (scale, position mask, -1e30 fill,
+  softmax) is exactly the dense incremental attention, per-slot
+  positions included.
+
+Heads and ``head_dim`` share the pool's minor dimension because of the
+TPU's layouts: with a 64-wide last dimension the device stores the
+array with another dimension minor-most, the scatter/gather body wants
+``head_dim`` minor, and every program then relayouts every layer's
+whole pool on entry and again on exit (docs/serving.md has the table).
+Folded, the argument's layout IS the body's and the donated pool is
+updated in place.
 
 Everything is static-shape: one compiled program serves every decode
 step and every prefill chunk regardless of which requests occupy which
@@ -87,15 +97,15 @@ class BlockAllocator:
 
 def init_paged_cache(lm, num_blocks: int, block_size: int, dtype=None):
     """The device pool: per transformer block one ``{"k", "v"}`` pair of
-    ``(num_blocks + 1, kv_heads, block_size, head_dim)`` arrays.  Index
-    ``num_blocks`` is the SCRATCH block — masked writes (pad tokens,
-    inactive slots) land there and nothing ever reads it through a real
-    block table."""
+    ``(num_blocks + 1, block_size, kv_heads * head_dim)`` arrays — a
+    token's k/v is one row, heads major within it.  Index ``num_blocks``
+    is the SCRATCH block — masked writes (pad tokens, inactive slots)
+    land there and nothing ever reads it through a real block table."""
     if block_size < 1:
         raise ValueError(f"block_size must be >= 1, got {block_size}")
     hd = lm.dim // lm.heads
     dt = dtype or jnp.float32
-    shape = (num_blocks + 1, lm.kv_heads, block_size, hd)
+    shape = (num_blocks + 1, block_size, lm.kv_heads * hd)
     # distinct buffers per block/side: the engine donates the whole
     # cache pytree into its jitted steps, and donation rejects aliased
     # buffers
@@ -144,30 +154,28 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
         )
         blk = jnp.where(write_mask, blk, scratch).reshape(-1)
         off = (positions % block_size).reshape(-1)
-        k_w = jnp.moveaxis(k.astype(k_pool.dtype), 1, 2).reshape(
-            S * s, attn.kv_heads, attn.head_dim
-        )
-        v_w = jnp.moveaxis(v.astype(v_pool.dtype), 1, 2).reshape(
-            S * s, attn.kv_heads, attn.head_dim
-        )
-        k_pool = k_pool.at[blk, :, off].set(k_w)
-        v_pool = v_pool.at[blk, :, off].set(v_w)
+        # (S, kv_heads, s, hd) -> one row of kv_heads * hd per token
+        k_w = jnp.moveaxis(k.astype(k_pool.dtype), 1, 2).reshape(S * s, -1)
+        v_w = jnp.moveaxis(v.astype(v_pool.dtype), 1, 2).reshape(S * s, -1)
+        k_pool = k_pool.at[blk, off].set(k_w)
+        v_pool = v_pool.at[blk, off].set(v_w)
 
-    # gather the per-slot tables back into the contiguous dense-cache
-    # layout; from here on the math is exactly apply_cached's
+    # gather the per-slot tables back into a contiguous per-slot view,
+    # token-major (S, L, kv_heads, hd) by reshape alone; from here on the
+    # math is exactly apply_cached's, contracted where the view lies
     L = block_tables.shape[1] * block_size
     with jax.named_scope("attn/kv_gather"):
-        k_full = jnp.moveaxis(k_pool[block_tables], 2, 1).reshape(
-            S, attn.kv_heads, L, attn.head_dim
+        k_full = k_pool[block_tables].reshape(
+            S, L, attn.kv_heads, attn.head_dim
         )
-        v_full = jnp.moveaxis(v_pool[block_tables], 2, 1).reshape(
-            S, attn.kv_heads, L, attn.head_dim
+        v_full = v_pool[block_tables].reshape(
+            S, L, attn.kv_heads, attn.head_dim
         )
-        k_full = attn._expand_kv(k_full).astype(q.dtype)
-        v_full = attn._expand_kv(v_full).astype(q.dtype)
+        k_full = attn._expand_kv(k_full, axis=2).astype(q.dtype)
+        v_full = attn._expand_kv(v_full, axis=2).astype(q.dtype)
     with jax.named_scope("attn/scores"):
         scale = attn.head_dim**-0.5
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k_full)
+        logits = jnp.einsum("bhqd,bkhd->bhqk", q * scale, k_full)
         pos_k = jnp.arange(L)[None, None, :]
         qpos = positions[:, :, None]
         visible = pos_k <= qpos  # (S, s, L), per-slot positions
@@ -175,7 +183,7 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
             visible = visible & (pos_k > qpos - attn.sliding_window)
         logits = jnp.where(visible[:, None], logits, -1e30)
         weights = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", weights, v_full)
+        o = jnp.einsum("bhqk,bkhd->bhqd", weights, v_full)
     with jax.named_scope("attn/out"):
         o = jnp.moveaxis(o, 1, 2).reshape(S, s, attn.dim)
         y, _ = attn._out.apply(params["out"], {}, o)
@@ -195,8 +203,8 @@ def paged_apply_cached(lm, params, tokens, cache, block_tables, positions,
     ``(logits (S, s, vocab), new_cache)``.
 
     Token-identical to the dense path by construction: the gathered
-    pool view equals the dense contiguous cache for every visible
-    position, and every op after the gather is the dense op."""
+    pool view holds the dense contiguous cache's values for every
+    visible position, and every op after the gather is the dense op."""
     L = block_tables.shape[1] * block_size
     with jax.named_scope("embed"):
         positions = jnp.clip(positions, 0, min(lm.max_seq, L) - 1)
