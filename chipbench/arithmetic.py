@@ -2,7 +2,8 @@
 
 Everything here is computed from readings the harness took and from the
 counts a configuration's family gives (`chipbench/families/<name>.py`:
-parameters, operations per token, cache bytes per token); no function
+parameters, operations per token, cache bytes per token, state bytes per
+slot); no function
 imports the program.  Counts are what the algorithm requires: recomputed
 operations (rematerialisation, the flash kernels' second pass over the
 scores) are not credited.
@@ -38,11 +39,14 @@ def mfu_pct(tokens_per_s: float, flops_per_token: float, chips: int,
     return 100.0 * tokens_per_s * flops_per_token / (chips * peak_flops_per_s)
 
 
-def decode_step_bytes(weight_bytes: float, held_tokens: float,
-                      kv_bytes_per_token: float) -> float:
-    """Bytes one decode step has to move: every weight once, and the keys
-    and values of the tokens the active slots really hold."""
-    return weight_bytes + held_tokens * kv_bytes_per_token
+def decode_step_bytes(weight_bytes: float, held_tokens: float, kv_bytes_per_token: float,
+                      busy_slots: float = 0.0, state_bytes_per_slot: float = 0.0) -> float:
+    """Bytes one decode step has to move: every weight once, the keys and
+    values of the tokens the active slots really hold, and, where the
+    architecture keeps a recurrent state per slot, each busy slot's state
+    read once and written once."""
+    return (weight_bytes + held_tokens * kv_bytes_per_token
+            + 2.0 * busy_slots * state_bytes_per_slot)
 
 
 def hbm_roofline_pct(step_bytes: float, step_seconds: float,

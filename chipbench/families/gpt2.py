@@ -12,7 +12,20 @@ What a family file offers (``cfg`` is the configuration file's object):
 - ``vocab_size(cfg)``; ``param_count(cfg)``;
   ``forward_flops_per_token(cfg, seq_len)`` and
   ``kv_bytes_per_token(cfg, bytes_per_value)``: the operations and bytes
-  the algorithm requires, for ``mfu`` and roofline shares;
+  the algorithm requires, for ``mfu`` and roofline shares; beside them the
+  count a kernel's own roofline metric needs (here
+  ``attention_flops_per_token``, for ``flash_roofline_pct.train``);
+- ``tiny(cfg)``: the overrides, by this family's own key names, that take
+  a configuration to the size the CPU rehearsal runs (under 5 M
+  parameters); a ``serve`` / ``train`` / ``limits`` entry in it overrides
+  the rehearsal's common sizes of that section.  A family without it
+  fails the rehearsal at once: nothing is rehearsed at published widths;
+- optional: ``SCOPES`` (the `jax.named_scope` names of its layers, which
+  join `chipbench.scopes.SCOPES`), ``KERNELS`` (its Pallas kernels'
+  `pl.pallas_call(name=)`), ``state_bytes_per_slot(cfg)`` (a recurrent
+  state a decode step reads and writes for every busy slot).  This
+  family's scopes and kernels are the program's base vocabulary already
+  and it keeps no such state, so it offers none of the three;
 - ``make_lm(cfg, seeded_key, dtype, remat=)``: the program's model at the
   configuration's sizes, whose ``init`` returns the seeded weights;
 - ``make_init(cfg, dtype, layout=)``: a jitted ``key -> weights`` in the
@@ -47,16 +60,29 @@ def param_count(cfg: dict) -> int:
     return V * D + S * D + L * per_block + 2 * D
 
 
+def tiny(cfg: dict) -> dict:
+    """The rehearsal's size of any configuration of this family."""
+    del cfg
+    return {"n_embd": 64, "n_layer": 2, "n_head": 4, "n_positions": 128, "n_ctx": 128,
+            "vocab_size": 512,
+            # wide enough that two blocks of width 64 outweigh the embedding:
+            # at the published 0.02 the tied head just echoes the last token
+            "initializer_range": 0.15}
+
+
+def attention_flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Operations the attention of all layers requires for one token's
+    forward pass at ``seq_len``: QK^T and PV are 4*D operations per visible
+    key, and a causal query sees (seq_len + 1) / 2 keys on average."""
+    return cfg["n_layer"] * 4 * cfg["n_embd"] * (seq_len + 1) / 2
+
+
 def forward_flops_per_token(cfg: dict, seq_len: int) -> float:
     """Matrix-product operations one token's forward pass requires at
     ``seq_len``: the four block products, causal attention over the
     realisable scores, and the tied head."""
     L, D, V = cfg["n_layer"], cfg["n_embd"], cfg["vocab_size"]
-    block = 2 * 12 * D * D
-    # QK^T and PV: 4*D operations per visible key; a causal query sees
-    # (seq_len + 1) / 2 keys on average
-    attention = 4 * D * (seq_len + 1) / 2
-    return L * (block + attention) + 2 * V * D
+    return L * 2 * 12 * D * D + attention_flops_per_token(cfg, seq_len) + 2 * V * D
 
 
 def kv_bytes_per_token(cfg: dict, bytes_per_value: int) -> int:

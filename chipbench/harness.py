@@ -11,7 +11,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 from chipbench import device as device_mod
-from chipbench import xplane
+from chipbench import scopes, xplane
 from chipbench.manifest import Cell, Manifest
 from chipbench.peaks import peaks_for
 from chipbench.spans import CompileCounter, Recorder
@@ -58,11 +58,21 @@ class TraceSlice:
             jax.profiler.stop_trace()
             self.stopped = now
 
-    def reduce(self) -> dict | None:
+    def reduce(self, families=()) -> tuple[dict | None, dict | None]:
+        """(`xplane.reduce`'s summary, `scopes.table`'s table by the
+        program's names), both made before the trace is deleted and both
+        None where no slice was taken or no operation ran on a device."""
         if self.stopped is None:
-            return None
+            return None, None
         try:
-            return xplane.reduce(*xplane.load(xplane.find_trace(str(self.dir))))
+            path = xplane.find_trace(str(self.dir))
+            t0 = time.perf_counter()
+            reduced = xplane.reduce(*xplane.load(path))
+            t1 = time.perf_counter()
+            table = scopes.table(path, families=families) if reduced is not None else None
+            log(f"trace reduced in {t1 - t0:.1f} s, by-scope table in "
+                f"{time.perf_counter() - t1:.1f} s")
+            return reduced, table
         finally:
             if not os.environ.get("CHIPBENCH_KEEP_TRACE"):
                 shutil.rmtree(self.dir, ignore_errors=True)
@@ -97,7 +107,8 @@ class RunView:
     """What a per-layer metric's reader is handed."""
     cell: Cell
     facts: dict
-    trace: dict | None
+    trace: dict | None             # `xplane.reduce`: by XLA's instruction names
+    scopes: dict | None            # `scopes.table`: by program, scope, pass and kernel
     rec: Recorder
     peaks: object | None
 
@@ -127,9 +138,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
     metrics = {}
     device_extra = {}
     if trace:
-        reduced = ctx.tracer.reduce()
+        reduced, table = ctx.tracer.reduce(families=[cell.family])
         view = RunView(
-            cell=cell, facts=outcome.facts, trace=reduced, rec=ctx.rec,
+            cell=cell, facts=outcome.facts, trace=reduced, scopes=table, rec=ctx.rec,
             peaks=_peaks(devices[0]),
         )
         for m in wanted:
@@ -153,8 +164,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
             devices, outcome.facts["hbm_peak_bytes"], **device_extra),
     }
     if trace and reduced is not None:
+        # idle gaps by the table's rule: split among the program's own spans
         result["breakdown"] = {
-            "device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"],
+            "device_ops": reduced["device_ops"],
+            "idle_gaps": [list(gap) for gap in table["idle_gaps"][:10]],
         }
     return result
 

@@ -57,6 +57,7 @@ class Driver:
         """One engine step inside a span; returns the requests it finished."""
         e = self.engine
         before = (e.steps_with_prefill, e.steps_with_decode)
+        decoding = int(e.active.sum())
         held = int(e.index[e.active].sum())
         in_pool = int(sum(e.index[s] for s, r in enumerate(e.slots) if r is not None))
         with self.rec.span("engine_step") as sp:
@@ -64,7 +65,8 @@ class Driver:
         sp.attrs.update(
             prefill=e.steps_with_prefill > before[0],
             decode=e.steps_with_decode > before[1],
-            occupancy=e.occupancy(), held_tokens=held, pool_tokens=in_pool,
+            occupancy=e.occupancy(), decoding_slots=decoding, held_tokens=held,
+            pool_tokens=in_pool,
         )
         finished, still = [], []
         for tr in self.live:
@@ -86,24 +88,31 @@ class Driver:
         return finished
 
 
-def _build_engine(ctx: RunContext):
+# the device programs these kinds run, by the start of their names
+PROGRAMS = ("serve_decode_greedy", "serve_prefill")
+
+
+def engine_config(sc: dict):
+    """The engine's sizing from a configuration's ``serve`` section: every
+    key of it that is a field of `ServeConfig`, and the cache in ``dtype``."""
+    import dataclasses
+
     import jax.numpy as jnp
 
-    from tpu_dist.serve import ServeConfig, ServeEngine
+    from tpu_dist.serve import ServeConfig
+
+    fields = {f.name for f in dataclasses.fields(ServeConfig)}
+    return ServeConfig(**{k: v for k, v in sc.items() if k in fields},
+                       cache_dtype=jnp.dtype(sc["dtype"]))
+
+
+def _build_engine(ctx: RunContext):
+    from tpu_dist.serve import ServeEngine
 
     sc, model = ctx.cell.config["serve"], ctx.cell.config
     lm = ctx.cell.family.make_lm(model, seed_key(ctx.seed), sc["dtype"])
     params, _ = lm.init()
-    engine = ServeEngine(
-        lm, params,
-        ServeConfig(
-            max_batch=sc["max_batch"], block_size=sc["block_size"],
-            num_blocks=sc["num_blocks"], max_seq=sc["max_seq"],
-            prefill_chunk=sc["prefill_chunk"], prefill_batch=sc["prefill_batch"],
-            cache_dtype=jnp.dtype(sc["dtype"]),
-        ),
-        now=time.perf_counter,
-    )
+    engine = ServeEngine(lm, params, engine_config(sc), now=time.perf_counter)
     # Warm the cell's own programs and no others: a prefill round of each
     # row count up to prefill_batch, and the greedy decode step.  (The
     # traffic is greedy, so `ServeEngine.warmup()`'s sampled-decode
@@ -183,6 +192,10 @@ def _step_facts(ctx: RunContext, start: float) -> dict:
         "decode_step_ms": [s.ms for s in steps if s.attrs["decode"] and not s.attrs["prefill"]],
         "decode_held_tokens": [
             s.attrs["held_tokens"] for s in steps
+            if s.attrs["decode"] and not s.attrs["prefill"]
+        ],
+        "decode_busy_slots": [
+            s.attrs["decoding_slots"] for s in steps
             if s.attrs["decode"] and not s.attrs["prefill"]
         ],
         "slots_busy_share": [s.attrs["occupancy"] / max_batch for s in steps],

@@ -18,6 +18,10 @@ from chipbench import correct, device as device_mod, schedule
 from chipbench.harness import Outcome, RunContext, log, seed_key
 
 
+# the device program this kind runs
+PROGRAMS = ("train_step",)
+
+
 def run(ctx: RunContext) -> Outcome:
     import jax
     import jax.numpy as jnp

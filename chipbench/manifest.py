@@ -63,7 +63,7 @@ class Manifest:
         return Cell(
             name=workload, chips=int(entry["chips"]), config_name=cfg["name"],
             config=config, traffic_name=entry["traffic"], traffic=traffic,
-            family=self._module("families", config["family"]),
+            family=self.family(config["family"]),
             end_to_end=[m for m in self.doc["end_to_end"] if _applies(m, workload)],
             per_layer=[m for m in self.doc["per_layer"] if _applies(m, workload)],
         )
@@ -78,6 +78,16 @@ class Manifest:
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         return module
+
+    def family(self, name: str):
+        """The family file ``<path>/families/<name>.py``, as a module."""
+        return self._module("families", name)
+
+    def families(self) -> list:
+        """Every family file under any of ``paths``."""
+        found = {p.stem for path in self.doc["paths"]
+                 for p in (self.root / path / "families").glob("*.py")}
+        return [self.family(name) for name in sorted(found - {"__init__"})]
 
     def reader(self, metric_name: str):
         """The ``read(run)`` function of a per-layer metric's own file."""

@@ -4,8 +4,9 @@ profiler trace:
     CHIPBENCH_KEEP_TRACE=1 python3 -m chipbench.run --workload <cell> ... --trace 1
     python3 -m chipbench.scopes .chipbench_trace/<cell>        # or a .xplane.pb
 
-Run by hand: the harness deletes the trace before its readers run, and
-handing this table to them is a later `benchmark` PR's (`PERF.md` §7).
+The harness makes the same table of every traced run, before it deletes the
+trace, and hands it to the per-layer readers as ``run.scopes``
+(`chipbench/device_reads.py` has the few reads they share).
 
 How an event finds its program and its scope.  `jax.profiler.ProfileData`
 does not surface an event's metadata, so the file is read as what it is, an
@@ -15,7 +16,8 @@ line points at an ``XEventMetadata`` whose stats hold ``program_id`` (the
 ``XLA Modules`` line's events are named ``<module>(<program_id>)``) and
 ``tf_op``: the ``op_name`` XLA kept for the instruction, which is JAX's
 name stack, ``jit(train_step)/transpose(jvp(block/attn))/dot_general``.
-The scope is the innermost entry of `SCOPES` on that path; the pass is
+The scope is the innermost entry of the vocabulary (`SCOPES`, plus what the
+configuration's family file lists, `vocabulary`) on that path; the pass is
 ``remat`` under ``rematted_computation``, else ``bwd`` under
 ``transpose(``, else ``fwd``.  A FUSION IS CHARGED TO ITS ROOT'S SCOPE:
 XLA gives a fusion the metadata of its root instruction, so what it fused
@@ -63,11 +65,14 @@ import json
 import re
 import struct
 import sys
+from bisect import bisect_left, bisect_right
 from dataclasses import dataclass, field
+from pathlib import Path
 
 from chipbench import xplane
 
-# the program's scope vocabulary (`PERF.md` §3)
+# the program's scope vocabulary (`PERF.md` §3); a family file adds its
+# architecture's own under the same two names (`vocabulary`)
 SCOPES = (
     # serving (`serve/paged_kv.py`, `serve/engine.py`)
     "embed", "ln", "attn/qkv", "attn/kv_scatter", "attn/kv_gather",
@@ -75,6 +80,8 @@ SCOPES = (
     # training (`models/transformer_lm.py`, the train step)
     "cast", "block/attn", "block/mlp", "loss", "grad_accum", "optimizer", "grad_sync",
 )
+# the program's Pallas kernels, by their `pl.pallas_call(name=)`
+KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "matmul_fused", "ring_all_reduce")
 UNSCOPED = "(unscoped)"
 PROGRAM_SPANS, HARNESS_SPANS = "tpu_dist/", "chipbench/"
 MODULES_LINE = "XLA Modules"
@@ -234,10 +241,20 @@ def _line(buf: bytes) -> Line:
     return line
 
 
-def read_xspace(path: str) -> list[Plane]:
+def _plane_name(buf: bytes) -> str:
+    return next((v.decode() for num, _, v in _fields(buf) if num == 2), "")
+
+
+def raw_planes(path: str) -> list[tuple[str, bytes]]:
+    """(name, bytes) of each ``XPlane``, unparsed: parsing is the cost, and
+    of four chips' planes `table` reads one."""
     with open(path, "rb") as fh:
         buf = fh.read()
-    return [_plane(v) for num, _, v in _fields(buf) if num == 1]
+    return [(_plane_name(v), v) for num, _, v in _fields(buf) if num == 1]
+
+
+def read_xspace(path: str) -> list[Plane]:
+    return [_plane(buf) for _, buf in raw_planes(path)]
 
 
 # ---------------------------------------------------------------- reduction
@@ -247,13 +264,22 @@ _WRAPPERS = re.compile(r"(?:jvp|transpose|vmap)\(|\)")
 _ARGUMENT = re.compile(r"([A-Za-z_]\w*)\[")  # a leaf of an argument's tree
 
 
-def scope_of(op_name: str) -> str | None:
-    """The innermost entry of `SCOPES` on an ``op_name`` path;
+def vocabulary(*families) -> tuple[tuple[str, ...], tuple[str, ...]]:
+    """(scopes, kernels): the program's own (`SCOPES`, `KERNELS`) and what
+    each family file offers under the same two names (both optional)."""
+    def with_theirs(base: tuple, name: str) -> tuple:
+        return tuple(dict.fromkeys((*base, *(x for fam in families for x in getattr(fam, name, ())))))
+
+    return with_theirs(SCOPES, "SCOPES"), with_theirs(KERNELS, "KERNELS")
+
+
+def scope_of(op_name: str, scopes: tuple[str, ...] = SCOPES) -> str | None:
+    """The innermost entry of ``scopes`` on an ``op_name`` path;
     ``arg:<name>`` for an argument's own path; else None."""
     op_name = op_name.split(";", 1)[0]
     path = "/" + _WRAPPERS.sub("", op_name) + "/"
     best, where = None, (-1, 0)
-    for scope in SCOPES:
+    for scope in scopes:
         at = path.rfind("/" + scope + "/")
         if at >= 0 and (at + len(scope), len(scope)) > where:
             best, where = scope, (at + len(scope), len(scope))
@@ -268,6 +294,17 @@ def pass_of(op_name: str) -> str:
     if "rematted_computation" in op_name:
         return "remat"
     return "bwd" if "transpose(" in op_name else "fwd"
+
+
+def kernel_of(meta: Meta, kernels: tuple[str, ...] = KERNELS) -> str | None:
+    """The name of the Pallas kernel an instruction is, or None: the entry
+    before ``pallas_call`` on its ``tf_op`` path (`pl.pallas_call(name=)`
+    is a scope of its own), else its base name where the vocabulary lists it."""
+    path = (meta.stats.get("tf_op") or "").rstrip(":").split(";", 1)[0].split("/")
+    if len(path) > 1 and path[-1] == "pallas_call":
+        return path[-2]
+    base = xplane.op_name(meta.name)
+    return base if base in kernels else None
 
 
 def program_name(module_event: str) -> str:
@@ -296,27 +333,34 @@ def _self_times(events: list) -> list:
     return out
 
 
-def table(path: str, device_ahead_ms: float | None = None) -> dict:
-    """The first chip's device time by program, by (program, scope, pass),
-    its collectives by scope, and its idle gaps by host span.  Without a
-    ``device_ahead_ms`` the clock offset is the middle of `clock_bounds`
-    (0 where the file bounds nothing)."""
-    planes = read_xspace(path)
-    chips = sorted((p for p in planes if p.name.startswith(xplane.DEVICE_PLANE)),
-                   key=lambda p: p.name)
-    chips = [p for p in chips
-             if any(ln.name.startswith(xplane.OPS_LINE) and ln.events for ln in p.lines)]
-    if not chips:
+def table(path: str, device_ahead_ms: float | None = None, *, families=()) -> dict:
+    """The first chip's device time by program (each run's duration too), by
+    (program, scope, pass) and by named kernel, its collectives by scope,
+    and its idle gaps by host span.  ``families``: the family modules whose
+    scopes and kernels join the vocabulary.  Without a ``device_ahead_ms``
+    the clock offset is the middle of `clock_bounds` (0 where the file
+    bounds nothing)."""
+    vocab, kernel_names = vocabulary(*families)
+    raw = raw_planes(path)
+    planes = [_plane(buf) for name, buf in raw if name == xplane.HOST_PLANE]
+    chip = None
+    for name, buf in sorted(raw):
+        if name.startswith(xplane.DEVICE_PLANE):
+            chip = _plane(buf)
+            if any(ln.name.startswith(xplane.OPS_LINE) and ln.events for ln in chip.lines):
+                break
+            chip = None
+    if chip is None:
         raise ValueError(f"no device plane with operations in {path}")
-    chip = chips[0]
     ops = [e for ln in chip.lines if ln.name.startswith(xplane.OPS_LINE) for e in ln.events]
-    modules = [e for ln in chip.lines if ln.name == MODULES_LINE for e in ln.events]
+    modules = sorted((e for ln in chip.lines if ln.name == MODULES_LINE for e in ln.events),
+                     key=lambda e: e[1])
 
-    programs: dict[str, float] = {}
+    program_runs: dict[str, list[float]] = {}   # program -> each run's device seconds, in order
     by_id: dict[int, str] = {}
     for mid, _, dur in modules:
         name = chip.metas[mid].name
-        programs[program_name(name)] = programs.get(program_name(name), 0.0) + dur / 1e9
+        program_runs.setdefault(program_name(name), []).append(dur / 1e9)
         m = re.search(r"\((\d+)\)\s*$", name)
         if m:
             by_id[int(m.group(1))] = program_name(name)
@@ -347,8 +391,8 @@ def table(path: str, device_ahead_ms: float | None = None) -> dict:
         if id(meta) in memo:
             return memo[id(meta)]
         op = (meta.stats.get("tf_op") or "").rstrip(":")
-        scope = scope_of(op) if op else None
-        found = (scope, pass_of(op), OWN if scope in SCOPES else FALLBACK) if scope else None
+        scope = scope_of(op, vocab) if op else None
+        found = (scope, pass_of(op), OWN if scope in vocab else FALLBACK) if scope else None
         if found is None and not op and hops:
             body = meta.name.split(" = ", 1)[-1]
             read = re.search(r"%[\w.\-]+", body.split("(", 1)[-1])
@@ -373,6 +417,8 @@ def table(path: str, device_ahead_ms: float | None = None) -> dict:
     by_op_scope: dict[tuple, float] = {}
     by_how = {OWN: 0.0, FALLBACK: 0.0, UNSCOPED: 0.0}
     collectives: dict[str, float] = {}
+    kernels: dict[str, list] = {}   # kernel -> [self seconds, calls]
+    kernel_by_meta: dict[int, str | None] = {}
     for ev, self_ns, _ in selfs:
         scope, which, how = scoped(ev)
         key = (program_of(ev), scope, which)
@@ -382,6 +428,13 @@ def table(path: str, device_ahead_ms: float | None = None) -> dict:
         by_op_scope[op, scope, how] = by_op_scope.get((op, scope, how), 0.0) + self_ns / 1e9
         if xplane.is_collective(op):
             collectives[scope] = collectives.get(scope, 0.0) + self_ns / 1e9
+        if ev[0] not in kernel_by_meta:
+            kernel_by_meta[ev[0]] = kernel_of(chip.metas[ev[0]], kernel_names)
+        kernel = kernel_by_meta[ev[0]]
+        if kernel:
+            seen = kernels.setdefault(kernel, [0.0, 0])
+            seen[0] += self_ns / 1e9
+            seen[1] += 1
     total = sum(by_scope.values())
     share = lambda s: 100.0 * s / total if total else 0.0  # noqa: E731
 
@@ -389,18 +442,22 @@ def table(path: str, device_ahead_ms: float | None = None) -> dict:
     bounds = clock_bounds(host, [(program_name(chip.metas[m].name), s, d) for m, s, d in modules])
     if device_ahead_ms is None:
         device_ahead_ms = sum(bounds) / 2 if bounds else 0.0
-    dev = [xplane.Event(chip.metas[m].name, s, d) for m, s, d in ops]
-    gaps, window_s = _idle_gaps(host, dev, device_ahead_ms)
-    ends = [_idle_gaps(host, dev, b)[0] for b in bounds or ()]
+    busy = xplane.busy_intervals([xplane.Event("", s, d) for _, s, d in ops])
+    timeline = _timeline(host)
+    gaps, window_s = _idle_gaps(host, timeline, busy, device_ahead_ms)
+    ends = [_idle_gaps(host, timeline, busy, b)[0] for b in bounds or ()]
     ordered = lambda d: sorted(d.items(), key=lambda kv: -kv[1])  # noqa: E731
     return {
         "chip": chip.name,
-        "programs": ordered(programs),
+        "programs": ordered({name: sum(runs) for name, runs in program_runs.items()}),
+        "program_runs": program_runs,
         "by_scope": [[*k, s] for k, s in ordered(by_scope)],
         "total_self_s": total,
         "scoped_share_pct": share(by_how[OWN]),
         "fallback_share_pct": share(by_how[FALLBACK]),
-        "by_op_scope": [[*k, s] for k, s in ordered(by_op_scope)[:16]],
+        "by_op_scope": [[*k, s] for k, s in ordered(by_op_scope)],
+        "kernels": [[name, s, calls] for name, (s, calls) in
+                    sorted(kernels.items(), key=lambda kv: -kv[1][0])],
         "collectives_by_scope": ordered(collectives),
         "idle_gaps": ordered(gaps),
         "idle_gaps_at_bounds": [[name, *(e.get(name, 0.0) for e in ends)]
@@ -455,30 +512,42 @@ def clock_bounds(host: dict, runs: list, dispatches: dict = DISPATCHES,
     return max(lows), min(highs)
 
 
-def _idle_gaps(host: dict, dev: list, device_ahead_ms: float):
-    """-> ({host span name: idle seconds of the chip under it}, window s),
-    the device's times moved onto the host's clock."""
+def _timeline(host: dict) -> tuple[list[float], list[str]]:
+    """The host's time cut at every span's edge: (the edges, with an
+    infinite one at either end; the name of each piece between two).  A
+    piece is named after the innermost (shortest) program span over it,
+    else the innermost harness span, else ``unannotated``."""
+    edges = sorted({t for group in host.values() for s in group for t in (s.start_ns, s.end_ns)})
+    edges = [float("-inf"), *edges, float("inf")]
+    names = ["unannotated"] * (len(edges) - 1)
+    for prefix in (HARNESS_SPANS, PROGRAM_SPANS):   # the program's are written last: they win
+        shortest: dict[int, float] = {}
+        for s in host[prefix]:
+            for k in range(bisect_left(edges, s.start_ns), bisect_left(edges, s.end_ns)):
+                if s.dur_ns < shortest.get(k, float("inf")):
+                    shortest[k], names[k] = s.dur_ns, s.name
+    return edges, names
+
+
+def _idle_gaps(host: dict, timeline: tuple, busy: list, device_ahead_ms: float):
+    """-> ({host span name: idle seconds of the chip under it}, window s).
+    ``busy`` is the device's `xplane.busy_intervals`, moved here onto the
+    host's clock; the window runs from the first to the last harness span
+    (else program span, else device operation)."""
     shift = device_ahead_ms * 1e6
-    dev = [xplane.Event(e.name, e.start_ns - shift, e.dur_ns) for e in dev]
-    marks = host[HARNESS_SPANS] or host[PROGRAM_SPANS] or dev
-    lo, hi = min(e.start_ns for e in marks), max(e.end_ns for e in marks)
+    busy = [(a - shift, b - shift) for a, b in busy]
+    marks = host[HARNESS_SPANS] or host[PROGRAM_SPANS]
+    lo = min(s.start_ns for s in marks) if marks else busy[0][0]
+    hi = max(s.end_ns for s in marks) if marks else busy[-1][1]
+    edges, names = timeline
     gaps: dict[str, float] = {}
-    edges = [(lo, lo)] + xplane.busy_intervals(dev) + [(hi, hi)]
-    for (_, end), (start, _) in zip(edges, edges[1:]):
+    for (_, end), (start, _) in zip([(lo, lo), *busy], [*busy, (hi, hi)]):
         a, b = max(end, lo), min(start, hi)
-        if b <= a:
-            continue
-        over = {p: [s for s in host[p] if s.start_ns < b and s.end_ns > a] for p in host}
-        cuts = sorted({a, b, *(t for ss in over.values() for s in ss
-                               for t in (s.start_ns, s.end_ns) if a < t < b)})
-        for x, y in zip(cuts, cuts[1:]):
-            mid_ns, name = (x + y) / 2, "unannotated"
-            for prefix in (PROGRAM_SPANS, HARNESS_SPANS):
-                cover = [s for s in over[prefix] if s.start_ns <= mid_ns <= s.end_ns]
-                if cover:
-                    name = min(cover, key=lambda s: s.dur_ns).name
-                    break
-            gaps[name] = gaps.get(name, 0.0) + (y - x) / 1e9
+        k = bisect_right(edges, a) - 1
+        while a < b:   # the gap's part in each piece of the timeline it crosses
+            upto = min(b, edges[k + 1])
+            gaps[names[k]] = gaps.get(names[k], 0.0) + (upto - a) / 1e9
+            a, k = upto, k + 1
     return gaps, (hi - lo) / 1e9
 
 
@@ -495,7 +564,10 @@ def render(t: dict) -> str:
             for prog, scope, which, s in t["by_scope"]]
     out += ["", "the largest (operation, scope) pairs (*: the scope is not the operation's own):"]
     out += [f"  {s:10.4f}  {100 * s / total:5.1f} %  {op} / {scope}{' *' if how == FALLBACK else ''}"
-            for op, scope, how, s in t["by_op_scope"]]
+            for op, scope, how, s in t["by_op_scope"][:16]]
+    if t["kernels"]:
+        out += ["", "named kernels (pl.pallas_call(name=)): self seconds, calls"]
+        out += [f"  {s:10.4f}  {calls:6d}  {name}" for name, s, calls in t["kernels"]]
     if t["collectives_by_scope"]:
         out += ["", "collectives by scope:"]
         out += [f"  {s:10.4f}  {name}" for name, s in t["collectives_by_scope"]]
@@ -519,7 +591,11 @@ def main(argv=None) -> int:
     ap.add_argument("--json", help="also write the table here")
     args = ap.parse_args(argv)
     path = args.trace if args.trace.endswith(".pb") else xplane.find_trace(args.trace)
-    t = table(path, args.device_ahead_ms)
+    from chipbench.manifest import Manifest
+
+    # by hand no cell is named: every family file's scopes and kernels count
+    t = table(path, args.device_ahead_ms,
+              families=Manifest(Path(__file__).resolve().parents[1]).families())
     print(render(t))
     if args.json:
         with open(args.json, "w", encoding="utf-8") as fh:

@@ -9,6 +9,7 @@ and `fixture_train` (`block/attn`, `block/mlp`, forward and backward) under a
 """
 
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -52,6 +53,38 @@ def test_every_scope_is_found_and_forward_is_split_from_backward(table):
     assert not any(which == "remat" for _, _, which in rows)
     # every scope here is the operation's own: nothing was charged by a fallback rule
     assert 75.0 < table["scoped_share_pct"] <= 100.0 and table["fallback_share_pct"] == 0.0
+
+
+def test_every_run_of_a_program_keeps_its_own_duration(table):
+    runs = table["program_runs"]
+    assert set(runs) == {"fixture_train", "fixture_serve"}
+    assert all(len(r) == 3 and min(r) > 0 for r in runs.values())   # three rounds
+    assert {n: pytest.approx(sum(r)) for n, r in runs.items()} == dict(table["programs"])
+    assert table["kernels"] == []   # plain XLA programs: no Pallas kernel in them
+
+
+def test_a_familys_scope_joins_the_vocabulary_and_counts_as_own(table):
+    """A vocabulary entry brought from outside (a family file's `SCOPES`):
+    the time under it is the operation's own scope, not `(unscoped)` and
+    not a fallback's, and nothing else of the table moves."""
+    family = SimpleNamespace(SCOPES=("attn/scores/div", "attn/scores"), KERNELS=("fusion",))
+    vocab, kernels = scopes.vocabulary(family, SimpleNamespace())
+    assert vocab == (*scopes.SCOPES, "attn/scores/div") and kernels == (*scopes.KERNELS, "fusion")
+    assert scopes.vocabulary() == (scopes.SCOPES, scopes.KERNELS)
+    assert scopes.scope_of("jit(f)/attn/scores/div/mul") == "attn/scores"
+    assert scopes.scope_of("jit(f)/attn/scores/div/mul", vocab) == "attn/scores/div"
+
+    t = scopes.table(FIXTURE, families=[family])
+    rows = {(prog, scope): s for prog, scope, _, s in t["by_scope"]}
+    base = {(prog, scope): s for prog, scope, _, s in table["by_scope"]}
+    assert rows["fixture_serve", "attn/scores/div"] > 0
+    assert rows["fixture_serve", "attn/scores/div"] + rows["fixture_serve", "attn/scores"] == (
+        pytest.approx(base["fixture_serve", "attn/scores"]))
+    assert {how for _, scope, how, _ in t["by_op_scope"] if scope == "attn/scores/div"} == {scopes.OWN}
+    assert t["scoped_share_pct"] == pytest.approx(table["scoped_share_pct"])
+    assert t["fallback_share_pct"] == 0.0 and t["total_self_s"] == table["total_self_s"]
+    # a kernel the family lists by its instruction's base name is found by it
+    assert [name for name, *_ in t["kernels"]] == ["fusion"]
 
 
 def test_idle_gaps_are_named_after_the_programs_spans(table):
@@ -204,6 +237,33 @@ def test_time_charged_by_a_fallback_rule_is_counted_apart(tmp_path):
     assert t["device_ahead_bounds_ms"] == pytest.approx([-1.1, -0.5])
     assert t["device_ahead_ms"] == pytest.approx(-0.8)
     assert "charged by a fallback rule" in scopes.render(t) and "copy / arg:cache *" in scopes.render(t)
+
+
+def test_named_kernels_are_counted_by_their_pallas_call_name(tmp_path):
+    """Two calls of a kernel named by `pl.pallas_call(name=)` (the entry
+    before ``pallas_call`` on its ``tf_op`` path), whatever the vocabulary
+    lists, beside a fusion; on a host with four chips one plane is read."""
+    kernel = [f"%flash_fwd.{i} = bf16[8]{{0}} custom-call(%p.0), custom_call_target=\"tpu_custom_call\""
+              for i in (1, 2)]
+    fusion = "%fusion.3 = bf16[8]{0} fusion(%p.1), kind=kLoop"
+    path_of = "jit(train_step)/jvp(block/attn)/jit(flash_attention)/flash_fwd/pallas_call"
+    device = _plane("/device:TPU:0", {
+        "XLA Modules": [("jit_train_step(7)", 1000, 100), ("jit_train_step(7)", 1200, 60)],
+        "XLA Ops": [(kernel[0], 1000, 40), (fusion, 1040, 60), (kernel[1], 1200, 60)],
+    }, tf_ops={kernel[0]: path_of, kernel[1]: path_of, fusion: "jit(train_step)/jvp(block/mlp)/mul"})
+    other = _plane("/device:TPU:1", {"XLA Ops": [(fusion, 1000, 500)]})
+    path = tmp_path / "kernels.xplane.pb"
+    path.write_bytes(_msg((1, other), (1, device)))
+    assert [name for name, _ in scopes.raw_planes(str(path))] == ["/device:TPU:1", "/device:TPU:0"]
+    t = scopes.table(str(path))
+    assert t["chip"] == "/device:TPU:0"
+    assert t["kernels"] == [["flash_fwd", pytest.approx(100e-6), 2]]
+    assert t["program_runs"] == {"train_step": [pytest.approx(100e-6), pytest.approx(60e-6)]}
+    assert {(p, sc): s for p, sc, _, s in t["by_scope"]} == {
+        ("train_step", "block/attn"): pytest.approx(100e-6),
+        ("train_step", "block/mlp"): pytest.approx(60e-6)}
+    assert "named kernels" in scopes.render(t) and "flash_fwd" in scopes.render(t)
+    assert scopes.kernel_of(scopes.Meta("%copy.1 = bf16[8] copy(%p)", {"tf_op": "a/b"})) is None
 
 
 def test_the_wire_format_reader_agrees_with_profile_data():

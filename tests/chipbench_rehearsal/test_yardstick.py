@@ -6,7 +6,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from chipbench import arithmetic, schedule, xplane
+from chipbench import arithmetic, device_reads, harness, schedule, scopes, xplane
 from chipbench.families import gpt2
 from chipbench.harness import KINDS
 from chipbench.manifest import NAME, UNIT, Manifest
@@ -138,6 +138,106 @@ def test_decode_bytes_and_roofline():
     assert b == 2 * cfg["parameters"] + 10_000 * 307200
     assert arithmetic.hbm_roofline_pct(b, b / 819e9, 819e9) == pytest.approx(100.0)
     assert arithmetic.hbm_roofline_pct(b, 0.24, 819e9) < 5.0
+
+
+@pytest.mark.parametrize("busy,state,more", [
+    (0, 0, 0),                       # an architecture with no per-slot state: as before
+    (32, 0, 0), (0, 37_748_736, 0),  # neither alone adds a byte
+    (64, 37_748_736, 2 * 64 * 37_748_736),   # read once and written once a step
+])
+def test_decode_bytes_know_a_per_slot_state(busy, state, more):
+    plain = arithmetic.decode_step_bytes(1e9, 10_000, 4096)
+    assert plain == 1e9 + 10_000 * 4096
+    assert arithmetic.decode_step_bytes(1e9, 10_000, 4096, busy, state) == plain + more
+
+
+def test_attention_flops_are_a_part_of_the_forward_count():
+    cfg = CONFIGS["gpt2-medium"]
+    attn = gpt2.attention_flops_per_token(cfg, 1024)
+    assert attn == 24 * 4 * 1024 * 1025 / 2
+    assert gpt2.forward_flops_per_token(cfg, 1024) - attn == 24 * 24 * 1024**2 + 2 * 50257 * 1024
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_the_engine_is_sized_by_the_serve_sections_own_keys(name):
+    """Every key of ``serve`` that is a field of `ServeConfig` is passed;
+    for the committed configurations those are the six that were named."""
+    import jax.numpy as jnp
+
+    from chipbench.kinds import serve
+    from tpu_dist.serve import ServeConfig
+
+    sc = CONFIGS[name]["serve"]
+    assert serve.engine_config(sc) == ServeConfig(
+        max_batch=sc["max_batch"], block_size=sc["block_size"], num_blocks=sc["num_blocks"],
+        max_seq=sc["max_seq"], prefill_chunk=sc["prefill_chunk"],
+        prefill_batch=sc["prefill_batch"], cache_dtype=jnp.dtype(sc["dtype"]))
+    more = serve.engine_config(dict(sc, decode_event_every=2, deployment="ignored: no field"))
+    assert more.decode_event_every == 2 and more.max_batch == sc["max_batch"]
+
+
+@pytest.mark.parametrize("family", Manifest(REPO).families(), ids=lambda f: Path(f.__file__).stem)
+def test_every_family_file_offers_a_rehearsal_size(family):
+    for cfg in CONFIGS.values():
+        if Path(family.__file__).stem == cfg["family"]:
+            small = dict(cfg, **{k: v for k, v in family.tiny(cfg).items()
+                                 if k not in ("serve", "train", "limits")})
+            assert family.param_count(small) < 5_000_000 < family.param_count(cfg)
+    assert isinstance(scopes.vocabulary(family)[0], tuple)
+
+
+# ------------------------------------------------ the device by the program's names
+
+
+def _view(table):
+    return harness.RunView(cell=None, facts={}, trace=None, scopes=table, rec=None, peaks=None)
+
+
+def test_a_reader_says_nothing_off_the_chip_and_zero_for_a_scope_that_is_gone():
+    table = {
+        "program_runs": {"serve_decode_greedy": [0.07, 0.09], "serve_decode_sampled": [0.08]},
+        "by_scope": [["serve_decode_greedy", "attn/kv_gather", "fwd", 0.09],
+                     ["serve_decode_sampled", "attn/kv_gather", "fwd", 0.03],
+                     ["serve_decode_greedy", "mlp", "fwd", 0.12]],
+        "kernels": [["flash_fwd", 0.2, 8], ["flash_bwd_dq", 0.1, 8], ["matmul_fused", 0.4, 2]],
+    }
+    run = _view(table)
+    assert device_reads.runs_ms(run, "serve_decode") == pytest.approx([70.0, 90.0, 80.0])
+    assert device_reads.median_run_ms(run, "serve_decode") == pytest.approx(80.0)
+    assert device_reads.median_run_ms(run, "serve_prefill") is None
+    assert device_reads.scope_ms_per_run(run, "serve_decode", "attn/kv_gather") == pytest.approx(40.0)
+    assert device_reads.scope_share_pct(run, "serve_decode", "mlp") == pytest.approx(50.0)
+    assert device_reads.kernel_seconds(run, "flash_") == pytest.approx(0.3)
+    # the program ran, the scope holds no time: 0.0, so the line is still printed
+    assert device_reads.scope_ms_per_run(run, "serve_decode", "attn/scores") == 0.0
+    assert device_reads.scope_share_pct(run, "serve_decode", "attn/scores") == 0.0
+    # the program did not run in the slice, or there is no trace: nothing
+    for nothing in (run, _view(None)):
+        program = "serve_prefill" if nothing is run else "serve_decode"
+        assert device_reads.runs_ms(nothing, program) is None
+        assert device_reads.scope_ms_per_run(nothing, program, "attn/kv_gather") is None
+        assert device_reads.scope_share_pct(nothing, program, "mlp") is None
+    assert device_reads.kernel_seconds(_view(None), "flash_") is None
+
+
+def test_the_traced_slice_hands_back_both_reductions_and_deletes_the_trace(tmp_path):
+    """`TraceSlice.reduce` on a trace recorded on the chip: the summary by
+    XLA's names and the table by the program's, made before the file goes."""
+    import shutil
+
+    where = tmp_path / "trace" / "plugins" / "profile" / "2026_01_01"
+    where.mkdir(parents=True)
+    shutil.copy(REPO / "chipbench/fixtures/scoped_tpu.xplane.pb", where / "host.xplane.pb")
+    tracer = harness.TraceSlice(tmp_path / "trace", harness.Recorder(), seconds=1.0)
+    assert tracer.reduce() == (None, None)           # no slice was taken
+    tracer.started, tracer.stopped = 0.0, 1.0
+    family = type("family", (), {"SCOPES": ("attn/scores/div",)})
+    reduced, table = tracer.reduce(families=[family])
+    assert reduced["busy_s"] > 0 and reduced["chips"] == 1
+    assert table["program_runs"].keys() == {"fixture_train", "fixture_serve"}
+    assert any(scope == "attn/scores/div" for _, scope, *_ in table["by_scope"])
+    assert any(name.startswith("tpu_dist/engine.") for name, _ in table["idle_gaps"])
+    assert not (tmp_path / "trace").exists()
 
 
 # ---------------------------------------------------------------- schedule
