@@ -466,7 +466,7 @@ class TestServingPoolIsNotRelayoutOnTheV5e:
             (params, cache, ints, flt))
         text = fn.lower(*args).compile().as_text()
 
-        pool = math.prod(cache[0]["k"].shape)
+        pool = math.prod(cache["kv"][0]["k"].shape)
         # "pool-sized" is unambiguous: nothing else in the program is as large
         assert pool > max(math.prod(a.shape) for a in jax.tree.leaves(params))
         copy_of = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(")
@@ -476,6 +476,6 @@ class TestServingPoolIsNotRelayoutOnTheV5e:
             and math.prod(int(d) for d in m.group(1).split(",") if d) == pool]
         assert not copies, (
             f"{program} copies a whole pool array "
-            f"{cache[0]['k'].shape}:\n" + "\n".join(copies[:4]))
+            f"{cache['kv'][0]['k'].shape}:\n" + "\n".join(copies[:4]))
         # every pool array is donated AND aliased to its output
         assert donated_buffer_count(text) >= len(jax.tree.leaves(cache))

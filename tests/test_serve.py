@@ -203,6 +203,51 @@ class TestPoolLayout:
             assert not got[nblk, :2].any()
 
 
+class TestServingProtocol:
+    """The engine asks the model for `init_serve_cache` / `apply_paged`
+    and knows nothing of a block's inside (docs/serving.md); the
+    transformer offers the two over the paged pool, keeps no per-slot
+    state and counts nothing itself.  `tests/test_hybrid_lm.py` serves a
+    model that does both through the same engine."""
+
+    def test_the_transformer_delegates_to_the_paged_pool(self, lm, lm_params):
+        bs, nblk = 4, 6
+        cache = lm.init_serve_cache(3, nblk, bs)
+        assert set(cache) == {"kv", "state"} and not jax.tree.leaves(cache["state"])
+        tokens = jnp.asarray([[3, 9, 27, 17], [5, 25, 61, 49]], jnp.int32)
+        positions = jnp.asarray([[2, 3, 4, 5], [0, 1, 2, 3]], jnp.int32)
+        tables = jnp.asarray([[5, 1, nblk], [2, nblk, nblk]], jnp.int32)
+        mask = jnp.asarray([[True] * 4, [True, True, False, False]])
+        want, want_kv = serve.paged_apply_cached(
+            lm, lm_params, tokens, serve.init_paged_cache(lm, nblk, bs),
+            tables, positions, mask, bs)
+        got, new, counters = lm.apply_paged(
+            lm_params, tokens, cache, tables, positions, mask,
+            jnp.asarray([1, 0]), bs)
+        assert counters is None and lm.serve_counters == ()
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert all(np.array_equal(a, b) for a, b in zip(
+            jax.tree.leaves(new["kv"]), jax.tree.leaves(want_kv)))
+
+    def test_an_engine_without_a_state_accounts_none(self, lm, lm_params):
+        eng = serve.ServeEngine(lm, lm_params, _cfg(bytes_limit=1 << 40))
+        bd = eng.memory_breakdown()
+        assert eng.state_bytes == bd["state_bytes"] == 0
+        assert bd["activation_headroom_bytes"] == (
+            (1 << 40) - eng.weights_bytes - eng.kv_pool_bytes)
+        assert set(eng.analysis_programs()) == {"serve_decode", "serve_prefill"}
+        # a prefill row carries its slot: one more column of the packed ints
+        p_ints = eng.analysis_programs()["serve_prefill"][1][2]
+        assert p_ints.shape == (eng.cfg.prefill_batch,
+                                eng.cfg.prefill_chunk + eng.blocks_per_seq + 5)
+        eng.submit(np.arange(1, 12, dtype=np.int32), 4)
+        eng.run_until_drained()
+        from tpu_dist.observe import spans
+
+        done = [s for s in spans.recent() if s.name == "request.prefill"][-1]
+        assert done.attrs["state_reset"] is False
+
+
 class TestEngineScheduling:
     def test_deterministic_under_seeded_trace(self, lm, lm_params):
         """Same trace, same engine config -> identical admission /

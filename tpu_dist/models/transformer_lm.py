@@ -278,6 +278,35 @@ class TransformerLM(Module):
         logits = h @ params["embed"]["table"].T
         return logits, new_cache
 
+    # ---- serving (the model-side protocol `serve.ServeEngine` asks) ------
+
+    # this model's serving programs count nothing themselves
+    serve_counters = ()
+
+    def init_serve_cache(self, max_batch: int, num_blocks: int,
+                         block_size: int, dtype=None):
+        """What `ServeEngine` keeps on the device for this model: paged
+        pools of keys and values under ``"kv"`` (`serve.paged_kv`) and no
+        per-slot state."""
+        from tpu_dist.serve.paged_kv import init_paged_cache
+
+        del max_batch
+        return {"kv": init_paged_cache(self, num_blocks, block_size, dtype),
+                "state": {}}
+
+    def apply_paged(self, params, tokens, cache, block_tables, positions,
+                    write_mask, slots, block_size: int):
+        """`serve.paged_kv.paged_apply_cached` under the engine's
+        protocol: ``-> (logits, cache, counters)``, here no counters."""
+        from tpu_dist.serve.paged_kv import paged_apply_cached
+
+        del slots
+        logits, kv = paged_apply_cached(
+            self, params, tokens, cache["kv"], block_tables, positions,
+            write_mask, block_size,
+        )
+        return logits, {"kv": kv, "state": cache["state"]}, None
+
     def generate(
         self,
         params,

@@ -25,6 +25,7 @@ def dot_product_attention(
     causal: bool = False,
     mask: jax.Array | None = None,
     window: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Softmax attention. Shapes: (..., heads, seq, head_dim).
 
@@ -47,6 +48,10 @@ def dot_product_attention(
     blocks outside the band are skipped, O(S·w) work — while the dense
     path materializes the band mask.
 
+    ``scale`` multiplies the scores; ``None`` is ``head_dim ** -0.5``
+    (a model with an explicit attention multiplier passes its own, and
+    stays on the dense path: the flash kernel fixes the default).
+
     With ``TPU_DIST_FLASH=1`` the blockwise Pallas kernel
     (`tpu_dist.ops.flash_attention`) takes over for sequences past its
     block size — no (S, S) materialization; numerics match to fp
@@ -63,6 +68,7 @@ def dot_product_attention(
             and S >= 128
             and S % bq == 0
             and mask is None  # kernel has no arbitrary-mask path
+            and scale in (None, q.shape[-1] ** -0.5)  # the kernel's own
         )
         if eligible:
             from tpu_dist import ops
@@ -73,7 +79,8 @@ def dot_product_attention(
             )
         # fall through to the dense path for shapes the kernel can't take
         # (cross-attention, indivisible block sizes, short sequences)
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("...hqd,...hkd->...hqk", q * scale, k)
     sq, sk = logits.shape[-2], logits.shape[-1]
     visible = None
@@ -134,6 +141,9 @@ class MultiHeadAttention(Module):
     the modern long-context inference layout (``kv_heads=1`` is
     multi-query attention).  With ``kv_heads == heads`` (default) the
     layer is exactly the classic fused-QKV MHA, param structure and all.
+    ``use_bias=False`` drops the projections' biases; ``scale`` replaces
+    the ``head_dim ** -0.5`` on the scores (an explicit attention
+    multiplier).
     """
 
     def __init__(
@@ -145,12 +155,15 @@ class MultiHeadAttention(Module):
         kv_heads: int | None = None,
         use_rope: bool = False,
         sliding_window: int | None = None,
+        use_bias: bool = True,
+        scale: float | None = None,
     ):
         if dim % heads:
             raise ValueError(f"dim {dim} not divisible by heads {heads}")
         self.dim = dim
         self.heads = heads
         self.head_dim = dim // heads
+        self.scale = self.head_dim**-0.5 if scale is None else scale
         self.causal = causal
         self.use_rope = use_rope
         if use_rope and self.head_dim % 2:
@@ -169,11 +182,11 @@ class MultiHeadAttention(Module):
         self.sliding_window = sliding_window
         self.group = heads // self.kv_heads
         if self.group == 1:
-            self._qkv = Dense(3 * dim)
+            self._qkv = Dense(3 * dim, use_bias=use_bias)
         else:
-            self._q = Dense(dim)
-            self._kv = Dense(2 * self.kv_heads * self.head_dim)
-        self._out = Dense(dim)
+            self._q = Dense(dim, use_bias=use_bias)
+            self._kv = Dense(2 * self.kv_heads * self.head_dim, use_bias=use_bias)
+        self._out = Dense(dim, use_bias=use_bias)
 
     def init(self, key, input_shape):
         k1, k2, k3 = jax.random.split(key, 3)
@@ -222,6 +235,7 @@ class MultiHeadAttention(Module):
         o = dot_product_attention(
             q, self._expand_kv(k), self._expand_kv(v),
             causal=self.causal, mask=mask, window=self.sliding_window,
+            scale=self.scale,
         )
         o = jnp.moveaxis(o, 1, 2).reshape(b, s, self.dim)
         y, _ = self._out.apply(params["out"], {}, o)
@@ -261,10 +275,9 @@ class MultiHeadAttention(Module):
             v_cache, v.astype(v_cache.dtype), index, axis=2
         )
         cache_len = k_cache.shape[2]
-        scale = self.head_dim**-0.5
         logits = jnp.einsum(
             "bhqd,bhkd->bhqk",
-            q * scale,
+            q * self.scale,
             self._expand_kv(k_cache).astype(q.dtype),
         )
         pos = jnp.arange(cache_len)[None, :]

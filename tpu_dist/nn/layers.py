@@ -266,6 +266,44 @@ class LayerNorm(Module):
         return y * params["scale"] + params["bias"], state
 
 
+class RMSNorm(Module):
+    """Root-mean-square norm with a learned gain and no bias (Zhang &
+    Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * scale``.  The statistics
+    are taken in float32 whatever ``x`` is; the result has ``x``'s dtype."""
+
+    def __init__(self, eps: float = 1e-5):
+        self.eps = eps
+
+    def init(self, key, input_shape):
+        return {"scale": jnp.ones((input_shape[-1],))}, {}
+
+    def apply(self, params, state, x, *, train=False, key=None):
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + self.eps)
+        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype), state
+
+
+class GatedMLP(Module):
+    """Gated feed-forward (SwiGLU, Shazeer 2020), bias-free:
+    ``(act(a) * b) @ w_out`` with ``[a | b] = x @ w_in``; ``w_in`` holds the
+    gate's columns first, then the value's."""
+
+    def __init__(self, width: int, *, activation: Callable = jax.nn.silu):
+        self.width = width
+        self.activation = activation
+
+    def init(self, key, input_shape):
+        d = input_shape[-1]
+        k1, k2 = jax.random.split(key)
+        return {"w_in": fanin_uniform(k1, (d, 2 * self.width), d),
+                "w_out": fanin_uniform(k2, (self.width, d), self.width)}, {}
+
+    def apply(self, params, state, x, *, train=False, key=None):
+        ab = x @ params["w_in"]
+        a, b = ab[..., : self.width], ab[..., self.width:]
+        return (self.activation(a) * b) @ params["w_out"], state
+
+
 class Embedding(Module):
     def __init__(self, vocab: int, features: int):
         self.vocab = vocab

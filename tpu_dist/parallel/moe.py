@@ -19,6 +19,14 @@ Switch-Transformer style):
 
 Everything is static-shaped (capacity bound), so the whole layer compiles
 into the surrounding SPMD program; both all_to_alls ride ICI.
+
+`routed_experts` is the layer WITHOUT a capacity, for a rank that holds a
+range of the experts (serving's cut: the router scores all of them, this
+rank computes its own experts' part of the sum): top-k over the router's
+whole width, the picks that land here sorted by expert, one grouped
+product over the held experts' stacked weights, un-sort and gate-weighted
+sum.  No token is ever dropped; the work follows the picks.  It is the
+one home of routing for the models that are served.
 """
 
 from __future__ import annotations
@@ -261,6 +269,74 @@ def moe_mlp_expert_choice(
         "mean_experts_per_token": cover.mean(),
     }
     return y, stats
+
+
+def routed_experts(
+    x: jax.Array,
+    router_w: jax.Array,
+    w_in: jax.Array,
+    w_out: jax.Array,
+    *,
+    top_k: int,
+    held: tuple[int, int] | None = None,
+    mask: jax.Array | None = None,
+    activation=jax.nn.silu,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Top-``top_k`` routed gated experts, the part the held experts give.
+
+    Args:
+      x: tokens ``(T, d)``.
+      router_w: ``(d, n_experts)``, every expert's column.  Scored in
+        float32 at ``highest`` precision: a near tie decides a pick.
+      w_in, w_out: the HELD experts' stacked weights ``(H, d, 2 * width)``
+        (gate's columns first, then the value's) and ``(H, width, d)``.
+      held: ``(lo, hi)``, the experts ``lo .. hi - 1`` whose weights these
+        are; default all of them.
+      mask: ``(T,)``, False for a pad token: its picks do no work.
+
+    ``y[t] = sum_j g[t, j] * expert_{idx[t, j]}(x[t])`` over the picks
+    with ``idx[t, j]`` held, where ``(v, idx) = top_k(x @ router_w)`` and
+    ``g = softmax(v)`` over ALL ``top_k`` picks, held or not: what the
+    absent experts would add is left out, not renormalised away.
+
+    Returns ``(y (T, d), counts)``; ``counts``: int32 ``picks`` (real
+    tokens x top_k), ``picks_held`` and ``expert_tokens (H,)``.
+    """
+    T, d = x.shape
+    H = w_in.shape[0]
+    lo, hi = held if held is not None else (0, router_w.shape[1])
+    if hi - lo != H:
+        raise ValueError(f"held experts [{lo}, {hi}) but weights of {H}")
+    with jax.named_scope("moe/router"):
+        scores = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        top_v, top_e = lax.top_k(scores, top_k)
+        gates = jax.nn.softmax(top_v, axis=-1)
+    with jax.named_scope("moe/sort"):
+        real = jnp.ones((T,), bool) if mask is None else mask
+        local = top_e.reshape(-1) - lo
+        here = (local >= 0) & (local < H) & jnp.repeat(real, top_k)
+        group = jnp.where(here, local, H)        # what is not held sorts last
+        order = jnp.argsort(group, stable=True)  # picks by expert, then by token
+        sizes = jnp.zeros((H + 1,), jnp.int32).at[group].add(1)[:H]
+        undo = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
+    with jax.named_scope("moe/experts"):
+        rows = x[order // top_k]
+        ab = lax.ragged_dot(rows, w_in, sizes)
+        width = w_out.shape[1]
+        hidden = (activation(ab[:, :width]) * ab[:, width:]).astype(x.dtype)
+        out = lax.ragged_dot(hidden, w_out, sizes)
+    with jax.named_scope("moe/combine"):
+        # rows past the last group were never computed: take none of them
+        weight = jnp.where(here, gates.reshape(-1), 0.0)
+        picked = jnp.where(here[:, None], out[undo].astype(jnp.float32), 0.0)
+        y = (picked * weight[:, None]).reshape(T, top_k, d).sum(axis=1).astype(x.dtype)
+    counts = {
+        "picks": real.sum(dtype=jnp.int32) * top_k,
+        "picks_held": sizes.sum(dtype=jnp.int32),
+        "expert_tokens": sizes,
+    }
+    return y, counts
 
 
 def stack_expert_params(experts: list[dict[str, Any]]) -> dict[str, Any]:

@@ -50,11 +50,7 @@ import jax.numpy as jnp
 from tpu_dist.observe import events as ev_mod
 from tpu_dist.observe import spans
 from tpu_dist.observe.registry import REGISTRY
-from tpu_dist.serve.paged_kv import (
-    BlockAllocator,
-    init_paged_cache,
-    paged_apply_cached,
-)
+from tpu_dist.serve.paged_kv import BlockAllocator
 from tpu_dist.serve.sampling import sample_slots, slot_keys
 
 
@@ -192,8 +188,10 @@ class ServeEngine:
         self.context_len = self.blocks_per_seq * cfg.block_size
         self.allocator = BlockAllocator(cfg.num_blocks)
         dtype = cfg.cache_dtype or params["embed"]["table"].dtype
-        self.cache = init_paged_cache(
-            lm, cfg.num_blocks, cfg.block_size, dtype
+        # the model's own: paged pools under "kv", and under "state"
+        # whatever it keeps per decode slot (a recurrent state)
+        self.cache = lm.init_serve_cache(
+            cfg.max_batch, cfg.num_blocks, cfg.block_size, dtype
         )
         self.scratch = cfg.num_blocks
 
@@ -279,6 +277,15 @@ class ServeEngine:
             "decode dispatches that rebuilt the packed slot state",
         )
         self._c_decode_steps = counter("decode_steps", "decode steps dispatched")
+        # what the model's serving programs count themselves (routed
+        # picks, tokens an expert): running totals that ride the decode
+        # step's readback, position by position as `lm.serve_counters` says
+        self._model_counters = [
+            (name, counter(name, f"{name}, counted by the model's programs"),
+             key, values)
+            for name, key, values in lm.serve_counters
+        ]
+        self._model_counted = None
         # Memory breakdown: what this engine keeps resident — weights
         # vs KV pool (allocated in full at init; blocks are GRANTS of
         # that pool) vs whatever headroom the device has left for
@@ -287,7 +294,8 @@ class ServeEngine:
         from tpu_dist.parallel import per_device_bytes
 
         self.weights_bytes = int(per_device_bytes(self.params))
-        self.kv_pool_bytes = int(per_device_bytes(self.cache))
+        self.kv_pool_bytes = int(per_device_bytes(self.cache["kv"]))
+        self.state_bytes = int(per_device_bytes(self.cache["state"]))
         # the pool holds num_blocks grantable blocks + 1 scratch block
         self.kv_block_bytes = self.kv_pool_bytes // (cfg.num_blocks + 1)
         self.bytes_limit = (
@@ -302,6 +310,10 @@ class ServeEngine:
             "tpu_dist_serve_kv_pool_bytes",
             "paged KV pool bytes resident (allocated at init)",
         ).set(self.kv_pool_bytes)
+        REGISTRY.gauge(
+            "tpu_dist_serve_state_bytes",
+            "per-slot recurrent state bytes resident (allocated at init)",
+        ).set(self.state_bytes)
 
     # ------------------------------------------------------------- jit fns
 
@@ -333,9 +345,10 @@ class ServeEngine:
             active = ints[:, MB + self._ACTIVE].astype(bool)
             last_tok = ints[:, MB + self._LASTTOK]
             index = ints[:, MB + self._INDEX]
-            logits, cache = paged_apply_cached(
-                lm, params, last_tok[:, None], cache, block_tables,
-                index[:, None], active[:, None], bs,
+            # row i is slot i: no slot indices
+            logits, cache, counted = lm.apply_paged(
+                params, last_tok[:, None], cache, block_tables,
+                index[:, None], active[:, None], None, bs,
             )
             with jax.named_scope("sample"):
                 if greedy:
@@ -359,6 +372,8 @@ class ServeEngine:
                 )
                 ints = ints.at[:, MB + self._INDEX].add(inc)
                 ints = ints.at[:, MB + self._COUNTER].add(inc)
+            if counted is not None:  # one readback carries both
+                toks = jnp.concatenate([toks, counted.astype(toks.dtype)])
             return toks, ints, cache
 
         # the program's name on the trace's `XLA Modules` line
@@ -377,16 +392,16 @@ class ServeEngine:
 
         def serve_prefill(params, cache, ints, flt):
             # ints columns: [tokens(C) | block_table(MB) | start |
-            #                real_len | top_k | seed]
+            #                real_len | top_k | seed | slot]
             tokens = ints[:, :C]
             block_tables = ints[:, C : C + MB]
             start = ints[:, C + MB]
             real_len = ints[:, C + MB + 1]
             positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)
             write_mask = jnp.arange(C)[None, :] < real_len[:, None]
-            logits, cache = paged_apply_cached(
-                lm, params, tokens, cache, block_tables, positions,
-                write_mask, bs,
+            logits, cache, _ = lm.apply_paged(
+                params, tokens, cache, block_tables, positions,
+                write_mask, ints[:, C + MB + 4], bs,
             )
             with jax.named_scope("sample"):
                 last = jnp.take_along_axis(
@@ -430,7 +445,7 @@ class ServeEngine:
             self.cfg.prefill_chunk, self.blocks_per_seq,
             self.cfg.prefill_batch,
         )
-        p_ints = jax.ShapeDtypeStruct((Pb, C + MB + 4), np.int32)
+        p_ints = jax.ShapeDtypeStruct((Pb, C + MB + 5), np.int32)
         p_flt = jax.ShapeDtypeStruct((Pb, 2), np.float32)
         return {
             "serve_decode": (
@@ -445,7 +460,8 @@ class ServeEngine:
 
     def memory_breakdown(self) -> dict:
         """The serve-side resident story: weights vs KV pool (split
-        into granted and free blocks) vs activation headroom against
+        into granted and free blocks) vs the per-slot recurrent state
+        (whole at init, like the pool) vs activation headroom against
         ``bytes_limit`` (None when no limit is known — CPU-sim without
         a configured budget).  The `observe.memory` snapshot rides
         along so plan (this breakdown) and live (HBM/RSS) are one
@@ -453,6 +469,7 @@ class ServeEngine:
         granted = self.allocator.used * self.kv_block_bytes
         headroom = (
             int(self.bytes_limit) - self.weights_bytes - self.kv_pool_bytes
+            - self.state_bytes
             if self.bytes_limit is not None else None
         )
         return {
@@ -460,6 +477,7 @@ class ServeEngine:
             "kv_pool_bytes": self.kv_pool_bytes,
             "kv_granted_bytes": int(granted),
             "kv_block_bytes": self.kv_block_bytes,
+            "state_bytes": self.state_bytes,
             "bytes_limit": self.bytes_limit,
             "activation_headroom_bytes": headroom,
             "live": self._memory.snapshot(),
@@ -469,11 +487,13 @@ class ServeEngine:
         return [
             {"class": "weights", "bytes": self.weights_bytes},
             {"class": "kv_pool", "bytes": self.kv_pool_bytes},
+            {"class": "state", "bytes": self.state_bytes},
         ]
 
     def _check_block_grant(self, req: Request, need: int) -> None:
         """Admission memory check: warn (once per request) when this
-        grant pushes weights + granted KV blocks past ``bytes_limit``
+        grant pushes weights + per-slot state + granted KV blocks past
+        ``bytes_limit``
         — the pool itself is preallocated, so the grant cannot OOM by
         itself, but a plan whose grants exceed the budget means the
         pool was sized past the device and the NEXT activation spike
@@ -483,7 +503,8 @@ class ServeEngine:
         if self.bytes_limit is None:
             return
         projected = (
-            self.weights_bytes + self.allocator.used * self.kv_block_bytes
+            self.weights_bytes + self.state_bytes
+            + self.allocator.used * self.kv_block_bytes
         )
         if projected <= self.bytes_limit:
             return
@@ -750,7 +771,7 @@ class ServeEngine:
         take = list(self._prefillq)[: self.cfg.prefill_batch]
         P = len(take)
         with spans.span("engine.prefill_dispatch", rows=P, chunk=C) as sp:
-            ints = np.zeros((P, C + MB + 4), np.int32)
+            ints = np.zeros((P, C + MB + 5), np.int32)
             flt = np.zeros((P, 2), np.float32)
             chunks = []
             for r, s in enumerate(take):
@@ -764,6 +785,7 @@ class ServeEngine:
                 ints[r, C + MB + 1] = chunk.size
                 ints[r, C + MB + 2] = self.top_k[s]
                 ints[r, C + MB + 3] = self.seeds[s]
+                ints[r, C + MB + 4] = s
                 flt[r, 0] = self.temperature[s]
                 flt[r, 1] = self.top_p[s]
             first_toks, self.cache = self._prefill_fn(
@@ -815,6 +837,8 @@ class ServeEngine:
                 spans.record(
                     "request.prefill", req.admit_time, tnow,
                     request_id=req.request_id,
+                    # the slot's recurrent state began from zero
+                    state_reset=self.state_bytes > 0,
                 )
                 if not self._warming:
                     self._h_ttft.observe(tnow - req.arrival_time)
@@ -860,7 +884,9 @@ class ServeEngine:
         with spans.span("engine.decode_wait"):
             toks_np = np.asarray(toks)  # host sync: the step boundary
         tnow = self._now()
-        with spans.span("engine.decode_apply"):
+        with spans.span("engine.decode_apply") as sp:
+            if self._model_counters:
+                self._count_model(toks_np[self.cfg.max_batch:], sp)
             active = np.nonzero(self.active)[0]
             self.last_tok[active] = toks_np[active]
             self.index[active] += 1
@@ -875,6 +901,29 @@ class ServeEngine:
                 if self._finished_by(req, tok):
                     self._evict(s, self._finish_reason(req, tok), tnow)
         return True
+
+    def _count_model(self, totals: np.ndarray, sp) -> None:
+        """The model's running int32 totals since the last readback, into
+        the registry and onto the ``engine.decode_apply`` span (a count of
+        several labels as a tuple).  Prefill rounds count into the same
+        totals, so theirs arrive with the next decode step's."""
+        if self._model_counted is None:
+            self._model_counted = np.zeros_like(totals)
+        # int32 wraps; the difference of two wrapped totals does not
+        delta = (totals - self._model_counted).astype(np.uint32)
+        self._model_counted = totals
+        at = 0
+        for name, counter, key, values in self._model_counters:
+            if key is None:
+                sp.attrs[name] = int(delta[at])
+                self._count(counter, sp.attrs[name])
+                at += 1
+                continue
+            part = delta[at : at + len(values)]
+            sp.attrs[name] = tuple(int(d) for d in part)
+            for value, d in zip(values, sp.attrs[name]):
+                self._count(counter, d, **{key: str(value)})
+            at += len(values)
 
     @staticmethod
     def _finished_by(req: Request, tok: int) -> bool:

@@ -174,8 +174,7 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
         k_full = attn._expand_kv(k_full, axis=2).astype(q.dtype)
         v_full = attn._expand_kv(v_full, axis=2).astype(q.dtype)
     with jax.named_scope("attn/scores"):
-        scale = attn.head_dim**-0.5
-        logits = jnp.einsum("bhqd,bkhd->bhqk", q * scale, k_full)
+        logits = jnp.einsum("bhqd,bkhd->bhqk", q * attn.scale, k_full)
         pos_k = jnp.arange(L)[None, None, :]
         qpos = positions[:, :, None]
         visible = pos_k <= qpos  # (S, s, L), per-slot positions
