@@ -314,6 +314,8 @@ class TestProgramNamesAndScopes:
         return serve.ServeEngine(lm, params, serve.ServeConfig(
             max_batch=4, block_size=8, num_blocks=16, max_seq=32, prefill_chunk=8))
 
+    # lowered for the CPU here, where decode too takes the gathered view:
+    # what the v5e's decode holds, TestDecodeAttendsInThePoolOnTheV5e says
     @pytest.mark.parametrize("key,module,extra", [
         ("serve_decode", "jit_serve_decode_sampled", ["state_update"]),
         ("serve_prefill", "jit_serve_prefill", []),
@@ -390,6 +392,13 @@ class TestProgramNamesAndScopes:
         x = jnp.ones((128, 128), jnp.float32)
         assert "name=matmul_fused\n" in str(jax.make_jaxpr(
             lambda x: matmul(x, x, interpret=True))(x))
+        from tpu_dist.ops import paged_attention_decode
+
+        pool = jnp.ones((3, 8, 128), jnp.float32)
+        assert "name=paged_attn_decode\n" in str(jax.make_jaxpr(
+            lambda pool: paged_attention_decode(
+                pool[:2, :2, :64], pool, pool, jnp.zeros((2, 2), jnp.int32),
+                jnp.ones((2,), jnp.int32), interpret=True))(pool))
 
 
 @pytest.fixture(scope="module")
@@ -410,6 +419,52 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+# head_dim 64, block_size 16: the two minor dimensions decide.  Where
+# the row (kv_heads * head_dim) is no multiple of 128 the device
+# still picks the dimension whose padding to a 128-lane tile wastes
+# least: 192 -> 256 against 257 -> 384 blocks keeps the row minor
+# (docs/serving.md has the rule and its limits).
+V5E_SERVING_SHAPES = {
+    "mha_row128": dict(dim=128, heads=2, num_blocks=255),
+    "gqa_row192": dict(dim=384, heads=6, kv_heads=3, num_blocks=256),
+}
+
+
+@pytest.fixture(scope="module", params=list(V5E_SERVING_SHAPES))
+def v5e_engine(request):
+    from tpu_dist import serve
+
+    shape = dict(V5E_SERVING_SHAPES[request.param])
+    num_blocks = shape.pop("num_blocks")
+    lm = models.TransformerLM(vocab=128, depth=2, max_seq=64, **shape)
+    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                          lm.init(jax.random.key(0))[0])
+    return serve.ServeEngine(lm, params, serve.ServeConfig(
+        max_batch=4, block_size=16, num_blocks=num_blocks, max_seq=64,
+        prefill_chunk=16, prefill_batch=2))
+
+
+def _compiled_for_the_v5e(engine, v5e_chip, program, rows):
+    """-> (the program's compiled text, the cache's shapes); each program
+    of each engine is compiled once for both classes below."""
+    programs = engine.analysis_programs()
+    if rows:  # the prefill program is retraced for each row count
+        fn, (params, cache, ints, flt) = programs["serve_prefill"]
+        ints, flt = (jax.ShapeDtypeStruct((rows,) + a.shape[1:], a.dtype)
+                     for a in (ints, flt))
+    else:
+        _, (params, cache, ints, flt) = programs["serve_decode"]
+        fn = (engine._decode_fn_greedy if program.endswith("greedy")
+              else engine._decode_fn)
+    texts = engine.__dict__.setdefault("_v5e_texts", {})
+    if (program, rows) not in texts:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            (params, cache, ints, flt))
+        texts[program, rows] = fn.lower(*args).compile().as_text()
+    return texts[program, rows], params, cache
+
+
 class TestServingPoolIsNotRelayoutOnTheV5e:
     """The serving programs, compiled for the v5e, update the donated KV
     pool in place.  With a 64-wide last dimension the device's own layout
@@ -417,55 +472,21 @@ class TestServingPoolIsNotRelayoutOnTheV5e:
     then copied every layer's whole pool on entry and again on exit (two
     thirds of the serving cells' device time, ledger PR 25); with heads
     and head_dim folded into one minor dimension (serve/paged_kv.py) the
-    argument, the scatter and the gather agree."""
-
-    # head_dim 64, block_size 16: the two minor dimensions decide.  Where
-    # the row (kv_heads * head_dim) is no multiple of 128 the device
-    # still picks the dimension whose padding to a 128-lane tile wastes
-    # least: 192 -> 256 against 257 -> 384 blocks keeps the row minor
-    # (docs/serving.md has the rule and its limits).
-    SHAPES = {
-        "mha_row128": dict(dim=128, heads=2, num_blocks=255),
-        "gqa_row192": dict(dim=384, heads=6, kv_heads=3, num_blocks=256),
-    }
-
-    @pytest.fixture(scope="class", params=list(SHAPES))
-    def engine(self, request):
-        from tpu_dist import serve
-
-        shape = dict(self.SHAPES[request.param])
-        num_blocks = shape.pop("num_blocks")
-        lm = models.TransformerLM(vocab=128, depth=2, max_seq=64, **shape)
-        params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
-                              lm.init(jax.random.key(0))[0])
-        return serve.ServeEngine(lm, params, serve.ServeConfig(
-            max_batch=4, block_size=16, num_blocks=num_blocks, max_seq=64,
-            prefill_chunk=16, prefill_batch=2))
+    argument, the scatter and the read side (decode's kernel, prefill's
+    gather) agree."""
 
     @pytest.mark.parametrize("program,rows", [
         ("serve_decode_greedy", None), ("serve_decode_sampled", None),
         ("serve_prefill", 1), ("serve_prefill", 2)])
     def test_no_pool_sized_copy_and_the_pool_is_aliased(
-            self, engine, v5e_chip, program, rows):
+            self, v5e_engine, v5e_chip, program, rows):
         import math
         import re
 
         from tpu_dist.analysis.lints import donated_buffer_count
 
-        programs = engine.analysis_programs()
-        if rows:  # the prefill program is retraced for each row count
-            fn, (params, cache, ints, flt) = programs["serve_prefill"]
-            ints, flt = (jax.ShapeDtypeStruct((rows,) + a.shape[1:], a.dtype)
-                         for a in (ints, flt))
-        else:
-            _, (params, cache, ints, flt) = programs["serve_decode"]
-            fn = (engine._decode_fn_greedy if program.endswith("greedy")
-                  else engine._decode_fn)
-        args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
-            (params, cache, ints, flt))
-        text = fn.lower(*args).compile().as_text()
-
+        text, params, cache = _compiled_for_the_v5e(
+            v5e_engine, v5e_chip, program, rows)
         pool = math.prod(cache["kv"][0]["k"].shape)
         # "pool-sized" is unambiguous: nothing else in the program is as large
         assert pool > max(math.prod(a.shape) for a in jax.tree.leaves(params))
@@ -479,3 +500,54 @@ class TestServingPoolIsNotRelayoutOnTheV5e:
             f"{cache['kv'][0]['k'].shape}:\n" + "\n".join(copies[:4]))
         # every pool array is donated AND aliased to its output
         assert donated_buffer_count(text) >= len(jax.tree.leaves(cache))
+
+
+class TestDecodeAttendsInThePoolOnTheV5e:
+    """How often the mechanism engages, statically: every decode step.
+    Compiled for the v5e, both decode programs hold ONE
+    `paged_attn_decode` kernel call an attention layer and nothing of
+    the gathered view — no array of ``slots x (max_blocks * block_size)``
+    places of K/V rows, whole or split into heads — while `serve_prefill`
+    (s > 1) still gathers its view.  The parent's decode programs fail
+    both halves."""
+
+    @staticmethod
+    def _view_arrays(text, engine, rows):
+        """Instructions that make an array of the gathered view's size
+        whose leading dimension is its rows."""
+        import math
+        import re
+
+        attn = engine.lm.blocks[0].attn
+        places = engine.blocks_per_seq * engine.cfg.block_size
+        sizes = {rows * places * heads * attn.head_dim
+                 for heads in (attn.kv_heads, attn.heads)}
+        shaped = re.compile(r"=\s*\w+\[([\d,]+)\]")
+        found = []
+        for line in text.splitlines():
+            if (m := shaped.search(line)):
+                dims = [int(d) for d in m.group(1).split(",")]
+                if dims[0] == rows and math.prod(dims) in sizes:
+                    found.append(line.strip()[:160])
+        return found
+
+    @pytest.mark.parametrize("program", ["serve_decode_greedy",
+                                         "serve_decode_sampled"])
+    def test_decode_holds_the_kernel_and_no_gathered_view(
+            self, v5e_engine, v5e_chip, program):
+        import re
+
+        text, _, cache = _compiled_for_the_v5e(
+            v5e_engine, v5e_chip, program, None)
+        calls = re.findall(
+            r"^\s*%paged_attn_decode\S* = .*custom-call\(.*"
+            r'custom_call_target="tpu_custom_call"', text, re.M)
+        assert len(calls) == len(cache["kv"]) == 2
+        view = self._view_arrays(text, v5e_engine, v5e_engine.cfg.max_batch)
+        assert not view, "\n".join(view[:4])
+
+    def test_prefill_still_gathers_its_view(self, v5e_engine, v5e_chip):
+        text, _, _ = _compiled_for_the_v5e(
+            v5e_engine, v5e_chip, "serve_prefill", 2)
+        assert "paged_attn_decode" not in text
+        assert self._view_arrays(text, v5e_engine, 2)

@@ -3,6 +3,9 @@
 - `matmul`: tiled MXU matmul with fused bias+activation epilogue.
 - `flash_attention`: blockwise attention, forward and both backward
   passes as kernels.
+- `paged_attention_decode`: one query token a slot attends its slot's
+  held blocks of a paged KV pool where they lie (serving's decode step
+  on the TPU).
 - `ring_all_reduce_pallas`: the hand-rolled ring allreduce at the RDMA
   level (the reference's allreduce.py exercise at its native depth).
 
@@ -11,7 +14,8 @@ TPU (and raises anywhere else), ``True`` runs the Pallas interpreter (the
 CPU test path).  Library code that must run on both — `nn.Dense`,
 `nn.dot_product_attention` — goes through `kernel_for_platform`, which
 makes that choice when the program is lowered, from the platform it is
-lowered FOR.
+lowered FOR.  (`serve.paged_kv` makes the same choice the same way, but
+its other branch is its own gathered view, not the interpreter.)
 """
 
 import functools
@@ -23,6 +27,7 @@ from tpu_dist.ops.flash_attention import (
     flash_attention_lse,
 )
 from tpu_dist.ops.matmul import matmul, use_pallas_dense
+from tpu_dist.ops.paged_attention import paged_attention_decode
 from tpu_dist.ops.pallas_ring import ring_all_reduce_pallas
 
 
@@ -46,6 +51,7 @@ __all__ = [
     "flash_attention_lse",
     "kernel_for_platform",
     "matmul",
+    "paged_attention_decode",
     "ring_all_reduce_pallas",
     "use_pallas_dense",
 ]

@@ -21,19 +21,28 @@ token-identical to the dense `generate`) with two differences:
   ``pool[table[pos // block_size], pos % block_size]`` instead of a
   ``dynamic_update_slice`` into a contiguous cache (masked-off tokens —
   pads, inactive slots — write to a reserved scratch block);
-- **read**: the per-slot tables gather the pool back into a contiguous
-  ``(slots, L, kv_heads, head_dim)`` view by reshape alone, contracted
-  where it lies; the attention (scale, position mask, -1e30 fill,
-  softmax) is exactly the dense incremental attention, per-slot
-  positions included.
+- **read**, by the number of new tokens a slot brings, a shape the code
+  sees.  ONE (every decode step), in a program lowered for a TPU:
+  `ops.paged_attention_decode`, a Pallas kernel that attends each slot's
+  held blocks where they lie in the pool — ``ceil(len / block_size)`` of
+  them, none for an inactive slot — with the scores' statistics in
+  float32.  SEVERAL (a prefill chunk), and decode lowered for any other
+  platform, where the kernel could only be interpreted: the per-slot
+  tables gather the pool into a contiguous ``(slots, L, kv_heads,
+  head_dim)`` view by reshape alone, contracted where it lies
+  (`_gathered_attention`, the plain reference the kernel is tested
+  against).  Either way the attention (scale, position mask, -1e30 fill,
+  softmax) is the dense incremental attention, per-slot positions
+  included.
 
 Heads and ``head_dim`` share the pool's minor dimension because of the
 TPU's layouts: with a 64-wide last dimension the device stores the
 array with another dimension minor-most, the scatter/gather body wants
 ``head_dim`` minor, and every program then relayouts every layer's
 whole pool on entry and again on exit (docs/serving.md has the table).
-Folded, the argument's layout IS the body's and the donated pool is
-updated in place.
+Folded, the argument's layout IS the body's, the donated pool is
+updated in place, and the kernel's blocks are whole rows: it never
+splits one into heads.
 
 Everything is static-shape: one compiled program serves every decode
 step and every prefill chunk regardless of which requests occupy which
@@ -42,8 +51,13 @@ slots.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+from tpu_dist import ops
 
 
 class BlockAllocator:
@@ -130,6 +144,67 @@ def _rope_slots(x, positions, *, base: float = 10000.0):
     return out.astype(x.dtype)
 
 
+def _gathered_attention(q, k_pool, v_pool, block_tables, positions, *,
+                        sliding_window):
+    """The plain read side (prefill's, decode's off the TPU, and the
+    reference the decode kernel is tested against): gather the per-slot
+    tables back into a contiguous per-slot view, token-major ``(S, L,
+    kv_heads, hd)`` by reshape alone; from there on the math is exactly
+    `apply_cached`'s, contracted where the view lies.  ``q``: ``(S,
+    heads, s, hd)``, scaled; ``positions``: ``(S, s)``."""
+    S, heads, _, hd = q.shape
+    kv_heads = k_pool.shape[2] // hd
+    L = block_tables.shape[1] * k_pool.shape[1]
+    with jax.named_scope("attn/kv_gather"):
+        k_full = k_pool[block_tables].reshape(S, L, kv_heads, hd)
+        v_full = v_pool[block_tables].reshape(S, L, kv_heads, hd)
+        if heads != kv_heads:  # GQA: each K/V head once a query head
+            k_full = jnp.repeat(k_full, heads // kv_heads, axis=2)
+            v_full = jnp.repeat(v_full, heads // kv_heads, axis=2)
+        k_full, v_full = k_full.astype(q.dtype), v_full.astype(q.dtype)
+    with jax.named_scope("attn/scores"):
+        logits = jnp.einsum("bhqd,bkhd->bhqk", q, k_full)
+        pos_k = jnp.arange(L)[None, None, :]
+        qpos = positions[:, :, None]
+        visible = pos_k <= qpos  # (S, s, L), per-slot positions
+        if sliding_window is not None:
+            visible = visible & (pos_k > qpos - sliding_window)
+        logits = jnp.where(visible[:, None], logits, -1e30)
+        weights = jax.nn.softmax(logits, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bhqd", weights, v_full)
+
+
+@functools.partial(jax.jit, static_argnames=("sliding_window",))
+def _attend_in_pool(q, k_pool, v_pool, block_tables, lengths, *,
+                    sliding_window):
+    """Decode's read side: query ``q[s]`` ``(S, heads, hd)`` attends the
+    ``lengths[s]`` places its slot holds (0: nothing, a row of zeros).
+    Which way is decided where the program is LOWERED, by the platform it
+    is lowered for: `ops.paged_attention_decode` on a TPU; anywhere else
+    the gathered view, because what another platform could do with the
+    kernel is interpret it, at several times the view's cost on a CPU
+    (the kernel's own tests do, tests/test_paged_attention_kernel.py).
+
+    A function of its own under `jax.jit` so that a model's layers, which
+    call it with the same shapes, share ONE trace and ONE lowered kernel:
+    traced inline, 48 layers put 48 copies of the kernel into the
+    program's module and twelve seconds onto every start."""
+
+    def view(q, k_pool, v_pool, block_tables, lengths):
+        o = _gathered_attention(
+            q[:, :, None], k_pool, v_pool, block_tables,
+            lengths[:, None] - 1, sliding_window=sliding_window,
+        )[:, :, 0]
+        return jnp.where((lengths > 0)[:, None, None], o, 0).astype(q.dtype)
+
+    return lax.platform_dependent(
+        q, k_pool, v_pool, block_tables, lengths,
+        tpu=functools.partial(ops.paged_attention_decode,
+                              sliding_window=sliding_window),
+        default=view,
+    )
+
+
 def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
                      positions, write_mask, block_size: int):
     """One block's incremental attention against the paged pool.
@@ -140,7 +215,9 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
     (pads / inactive slots) write to the scratch block.  Returns
     ``(y, k_pool, v_pool)`` — same contract as
     `MultiHeadAttention.apply_cached`, with the contiguous cache
-    replaced by the (scatter, gather) pair."""
+    replaced by the scatter and, on the read side, `_attend_in_pool`
+    (``s == 1``: the decode kernel on a TPU) or the gathered view
+    (``s > 1``)."""
     S, s, _ = x.shape
     with jax.named_scope("attn/qkv"):
         q, k, v = attn._project(params, x)
@@ -160,29 +237,20 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
         k_pool = k_pool.at[blk, off].set(k_w)
         v_pool = v_pool.at[blk, off].set(v_w)
 
-    # gather the per-slot tables back into a contiguous per-slot view,
-    # token-major (S, L, kv_heads, hd) by reshape alone; from here on the
-    # math is exactly apply_cached's, contracted where the view lies
-    L = block_tables.shape[1] * block_size
-    with jax.named_scope("attn/kv_gather"):
-        k_full = k_pool[block_tables].reshape(
-            S, L, attn.kv_heads, attn.head_dim
-        )
-        v_full = v_pool[block_tables].reshape(
-            S, L, attn.kv_heads, attn.head_dim
-        )
-        k_full = attn._expand_kv(k_full, axis=2).astype(q.dtype)
-        v_full = attn._expand_kv(v_full, axis=2).astype(q.dtype)
-    with jax.named_scope("attn/scores"):
-        logits = jnp.einsum("bhqd,bkhd->bhqk", q * attn.scale, k_full)
-        pos_k = jnp.arange(L)[None, None, :]
-        qpos = positions[:, :, None]
-        visible = pos_k <= qpos  # (S, s, L), per-slot positions
-        if attn.sliding_window is not None:
-            visible = visible & (pos_k > qpos - attn.sliding_window)
-        logits = jnp.where(visible[:, None], logits, -1e30)
-        weights = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bhqd", weights, v_full)
+    if s == 1:
+        # decode: one query a slot attends the blocks its slot holds, in
+        # the pool (the new token's row was written just above); a slot
+        # that writes nothing attends nothing
+        with jax.named_scope("attn/scores"):
+            o = _attend_in_pool(
+                (q * attn.scale)[:, :, 0], k_pool, v_pool, block_tables,
+                jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0),
+                sliding_window=attn.sliding_window,
+            )[:, :, None]
+    else:
+        o = _gathered_attention(q * attn.scale, k_pool, v_pool, block_tables,
+                                positions,
+                                sliding_window=attn.sliding_window)
     with jax.named_scope("attn/out"):
         o = jnp.moveaxis(o, 1, 2).reshape(S, s, attn.dim)
         y, _ = attn._out.apply(params["out"], {}, o)
@@ -201,9 +269,10 @@ def paged_apply_cached(lm, params, tokens, cache, block_tables, positions,
     are garbage the caller ignores).  Returns
     ``(logits (S, s, vocab), new_cache)``.
 
-    Token-identical to the dense path by construction: the gathered
-    pool view holds the dense contiguous cache's values for every
-    visible position, and every op after the gather is the dense op."""
+    Token-identical to the dense path (tested): the held blocks hold the
+    dense contiguous cache's values for every visible position; the
+    gathered view runs the dense ops on them, the decode kernel the same
+    attention with a streaming softmax."""
     L = block_tables.shape[1] * block_size
     with jax.named_scope("embed"):
         positions = jnp.clip(positions, 0, min(lm.max_seq, L) - 1)
