@@ -48,7 +48,7 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpu_dist import comm, parallel
-    from tpu_dist.nn import dot_product_attention
+    from tpu_dist.nn.attention import dense_attention
 
     mesh = comm.make_mesh(args.world, ("seq",), platform=args.platform)
     shard = NamedSharding(mesh, P(None, None, "seq", None))
@@ -90,7 +90,7 @@ def main():
             return lambda y: mapped(y, y, y)
 
         cases = [
-            ("full", lambda y: dot_product_attention(y, y, y, causal=args.causal)),
+            ("full", lambda y: dense_attention(y, y, y, causal=args.causal)),
             ("ring", sharded("ring")),
             ("ring_flash", sharded("ring_flash")),
             ("ulysses", sharded("ulysses")),

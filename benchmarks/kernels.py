@@ -2,7 +2,7 @@
 
 Times `tpu_dist.ops.matmul` (fused-epilogue Pallas kernel) against XLA's
 `jnp.dot`, and `tpu_dist.ops.flash_attention` against the dense XLA
-attention (`tpu_dist.nn.dot_product_attention`), forward and
+attention (`tpu_dist.nn.attention.dense_attention`), forward and
 forward+backward.  Reports ms and achieved TFLOP/s per case, then one
 JSON line for machines.
 
@@ -148,31 +148,6 @@ def main():
             file=sys.stderr,
         )
 
-    if args.tune and not interpret:
-        # Persist the winners so `ops.matmul` re-tunes its defaults from
-        # measured data on this device kind (committed by the battery).
-        from pathlib import Path
-
-        tuned = {
-            f"{r['n']}x{r['n']}x{r['n']}": r["tuned_blocks"]
-            for r in results["matmul"]
-            if "tuned_blocks" in r
-        }
-        if tuned:
-            kind = dev.device_kind.replace(" ", "_").replace("/", "_")
-            path = (
-                Path(__file__).parent / "results"
-                / f"tuned_blocks_{kind}.json"
-            )
-            try:
-                existing = json.loads(path.read_text())
-            except (OSError, ValueError):
-                existing = {}
-            existing.update(tuned)
-            path.parent.mkdir(parents=True, exist_ok=True)
-            path.write_text(json.dumps(existing, indent=1))
-            print(f"tuned table -> {path}", file=sys.stderr)
-
     # ---- flash attention vs dense XLA attention, fwd and fwd+bwd ----
     for S in args.seqs:
         kq, kk, kv, key = jax.random.split(key, 4)
@@ -191,7 +166,7 @@ def main():
             return ops.flash_attention(qc, _k, _v, causal=True, interpret=interpret)
 
         def dense_step(qc, _k=k, _v=v):
-            return nn.dot_product_attention(qc, _k, _v, causal=True)
+            return nn.attention.dense_attention(qc, _k, _v, causal=True)
 
         def loss_flash(qc, _k=k, _v=v):
             return (
@@ -202,7 +177,7 @@ def main():
 
         def loss_dense(qc, _k=k, _v=v):
             return (
-                nn.dot_product_attention(qc, _k, _v, causal=True)
+                nn.attention.dense_attention(qc, _k, _v, causal=True)
                 .astype(jnp.float32)
                 .sum()
             )

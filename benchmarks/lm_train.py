@@ -72,7 +72,6 @@ def build_args(argv=None):
     )
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--warmup", type=int, default=3)
-    ap.add_argument("--no-flash", action="store_true")
     ap.add_argument(
         "--remat-from", type=int, default=4096,
         help="use jax.checkpoint for seq >= this (memory headroom)",
@@ -281,21 +280,6 @@ def sweep(args) -> dict:
     """Run the (batch, seq) sweep on the current backend and return the
     result record (the caller prints/embeds it).  Platform selection is
     the script entry's job."""
-    # Set/restore, not set: an in-process caller must not inherit the
-    # flash path for every later attention call.
-    prev_flash = os.environ.get("TPU_DIST_FLASH")
-    if not args.no_flash:
-        os.environ["TPU_DIST_FLASH"] = "1"
-    try:
-        return _sweep(args)
-    finally:
-        if prev_flash is None:
-            os.environ.pop("TPU_DIST_FLASH", None)
-        else:
-            os.environ["TPU_DIST_FLASH"] = prev_flash
-
-
-def _sweep(args) -> dict:
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -342,7 +326,6 @@ def _sweep(args) -> dict:
         "unit": "mfu_fraction",
         "platform": dev.platform,
         "device_kind": dev.device_kind,
-        "flash": not args.no_flash,
         "best": best,
         "sweep": results,
     }

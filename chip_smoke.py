@@ -383,15 +383,6 @@ def _tokens(ctx: Ctx, batch: int, seq: int):
     )
 
 
-def _flash():
-    """``TPU_DIST_FLASH=1`` exactly as benchmarks/lm_train.py turns it on:
-    set BEFORE the trainer is built (attention reads it at trace time),
-    restored after, so later phases' dense references stay dense."""
-    from unittest import mock
-
-    return mock.patch.dict(os.environ, TPU_DIST_FLASH="1")
-
-
 def _lm_steps(ctx, trainer, batch, warmup: int, steps: int) -> dict:
     """``warmup`` steps (set-up: compile included), then ``steps`` timed
     steps closed by a host readback; counts programs lowered inside the
@@ -432,20 +423,19 @@ def phase_lm_train_1chip(ctx: Ctx) -> dict:
 
     s = ctx.sizes
     mesh = comm.make_mesh(1, ("data",), mesh_devices=ctx.devices[:1])
-    with _flash():
-        trainer = train.LMTrainer(
-            _lm(ctx), mesh,
-            train.LMTrainConfig(global_batch=s.lm_batch,
-                                compute_dtype="bfloat16", log=say),
-        )
-        batch = parallel.shard_batch((_tokens(ctx, s.lm_batch, s.lm_seq),), mesh)
-        info = _lm_steps(ctx, trainer, batch, warmup=2, steps=5)
-        # The compiled step itself (persistent-cache hit of the program
-        # the warm-up just compiled): did flash run, or the fall-through?
-        step = trainer._partition.step
-        hlo = step.lower(
-            trainer.params, trainer.opt_state, batch, jax.random.key(0)
-        ).compile().as_text()
+    trainer = train.LMTrainer(
+        _lm(ctx), mesh,
+        train.LMTrainConfig(global_batch=s.lm_batch,
+                            compute_dtype="bfloat16", log=say),
+    )
+    batch = parallel.shard_batch((_tokens(ctx, s.lm_batch, s.lm_seq),), mesh)
+    info = _lm_steps(ctx, trainer, batch, warmup=2, steps=5)
+    # The compiled step itself (persistent-cache hit of the program
+    # the warm-up just compiled): did the program pick flash?
+    step = trainer._partition.step
+    hlo = step.lower(
+        trainer.params, trainer.opt_state, batch, jax.random.key(0)
+    ).compile().as_text()
     info["pallas_custom_calls"] = hlo.count("tpu_custom_call")
     info["tokens_per_s"] = round(s.lm_batch * s.lm_seq / (info["step_ms"] / 1e3))
     info["peak_hbm_mb"] = peak_hbm_mb(ctx.devices[0])
@@ -467,7 +457,7 @@ def phase_lm_train_1chip(ctx: Ctx) -> dict:
         # unrolled: at least the three distinct kernels must be there
         check(info["pallas_custom_calls"] >= 3,
               "the compiled step has no Pallas TPU custom call: the dense "
-              "fall-through ran, not flash")
+              "form ran, not flash")
     return info
 
 
@@ -537,21 +527,20 @@ def phase_lm_train_4chip(ctx: Ctx) -> dict:
     # microbatches of 8 — the same mean loss over the same 16 rows — with
     # flash, as in lm_train_1chip (dense scores at 16x2048 need ~16 GB).
     mesh1 = comm.make_mesh(1, ("data",), mesh_devices=devs[:1])
-    with _flash():
-        ref_trainer = train.LMTrainer(
-            _lm(ctx), mesh1,
-            train.LMTrainConfig(global_batch=s.lm4_batch, accum_steps=2,
-                                compute_dtype="bfloat16", log=say),
-        )
-        ref = _lm_steps(ctx, ref_trainer,
-                        parallel.shard_batch((toks,), mesh1), warmup=1, steps=0)
+    ref_trainer = train.LMTrainer(
+        _lm(ctx), mesh1,
+        train.LMTrainConfig(global_batch=s.lm4_batch, accum_steps=2,
+                            compute_dtype="bfloat16", log=say),
+    )
+    ref = _lm_steps(ctx, ref_trainer,
+                    parallel.shard_batch((toks,), mesh1), warmup=1, steps=0)
     say(f"  [1-chip reference, accum 2, flash] first loss "
         f"{ref['first_loss']:.5f} (set-up {ref['setup_s']}s)")
     del ref_trainer
     # The four-chip layouts run DENSE attention: the engine's step is one
-    # GSPMD program, and a Mosaic kernel cannot be partitioned
-    # automatically ("wrap the call in a shard_map") — TPU_DIST_FLASH=1
-    # under mesh_axes on >1 chip is refused at trace time (PERF.md).
+    # GSPMD program, a Mosaic kernel cannot be partitioned automatically
+    # ("wrap the call in a shard_map"), and `ops.kernel_for_platform`
+    # keeps it out of a program the compiler partitions (PERF.md).
     for spec in ("dp=2,fsdp=2", "dp=2,tp=2"):
         mesh = parallel.build_mesh(spec, mesh_devices=devs)
         trainer = train.LMTrainer(
@@ -705,7 +694,7 @@ def phase_kernels(ctx: Ctx) -> dict:
                 return jnp.sum(out.astype(jnp.float32) * wgt), out
 
             def dense_loss(q, kx, v):
-                out = nn.dot_product_attention(
+                out = nn.attention.dense_attention(
                     q, kx, v, causal=True, window=window)
                 return jnp.sum(out * wgt), out
 

@@ -39,3 +39,17 @@ def spmd_run(fn, *args, world=8):
     from tpu_dist import comm
 
     return comm.spmd(fn, *args, world=world, platform="cpu")
+
+
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    """The Pallas interpreter in `ops.kernel_for_platform`'s place: what
+    a program lowered for the TPU computes where the selection picks the
+    kernel, run here.  (The selection itself never interprets: off the
+    TPU it takes the caller's plain form.)"""
+    from tpu_dist import ops
+
+    monkeypatch.setattr(
+        ops, "kernel_for_platform",
+        lambda kernel, plain, *operands: kernel(*operands, interpret=True),
+    )

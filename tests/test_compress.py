@@ -225,13 +225,14 @@ def test_resized_residual_is_zeroed_on_restore():
     assert reset["ef"]["residual"].shape == live.shape  # live layout wins
 
 
-def test_resolve_env_and_override(monkeypatch):
-    monkeypatch.setenv(compress.ENV_COMPRESS, "bf16")
-    assert compress.resolve(None).wire == "bfloat16"
-    assert compress.resolve("int8").wire == "int8"  # explicit wins
-    assert compress.resolve("off") is None  # force-disable beats env
-    monkeypatch.delenv(compress.ENV_COMPRESS)
-    assert compress.resolve(None) is None
+def test_trainer_wire_is_the_config_field_alone(monkeypatch):
+    """``grad_compress`` is the one way: None is the exact sync whatever
+    the environment holds, a spec is its config."""
+    monkeypatch.setenv("TPU_DIST_COMPRESS", "bf16")
+    t, _ = _mnist_trainer()
+    assert t._compress is None
+    t2, _ = _mnist_trainer(grad_compress="int8")
+    assert t2._compress.wire == "int8"
 
 
 def test_trainer_rejects_bad_wire_at_construction():
@@ -494,15 +495,6 @@ def test_lm_trainer_fsdp_compressed_sharded_checkpoint(tmp_path):
         np.asarray(t.opt_state["ef"]["residual"]),
         np.asarray(t2.opt_state["ef"]["residual"]),
     )
-
-
-def test_trainer_env_var_enables_compression(monkeypatch):
-    monkeypatch.setenv(compress.ENV_COMPRESS, "bf16")
-    t, _ = _mnist_trainer()
-    assert t._compress is not None and t._compress.wire == "bfloat16"
-    # explicit 'off' beats the env var
-    t2, _ = _mnist_trainer(grad_compress="off")
-    assert t2._compress is None
 
 
 @pytest.mark.parametrize("spec,bind", [

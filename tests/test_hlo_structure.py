@@ -551,3 +551,132 @@ class TestDecodeAttendsInThePoolOnTheV5e:
             v5e_engine, v5e_chip, "serve_prefill", 2)
         assert "paged_attn_decode" not in text
         assert self._view_arrays(text, v5e_engine, 2)
+
+
+class TestTheTrainerPicksFlashItself:
+    """`LMTrainer` with nothing in the environment: the step of one chip
+    holds the three flash kernels where it is lowered for a TPU; the same
+    trainer under ``mesh_axes="fsdp=4"``, which XLA partitions over four
+    devices, lowers too (Mosaic is not asked) and holds none.
+
+    The trainer places its state as it is built, so it is built on the
+    CPU's devices and its step lowered FOR the TPU from here
+    (``lowering_platforms``): the TPU's lowering rules, Mosaic's refusal
+    of a partitioned program among them, with no chip.  What XLA then
+    makes of the dead dense branch is `test_flash_grads_compiled_for_
+    the_v5e_hold_no_scores`'s, below."""
+
+    KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+
+    @pytest.mark.parametrize("mesh_axes,grad_compress,kernels", [
+        (None, None, KERNELS),
+        ("fsdp=4", None, ()),
+        # the compressed wire is a `shard_map` over the data axes: every
+        # axis of an fsdp mesh, so its body is one device's and keeps the
+        # kernels; beside a ``tp`` axis the compiler still partitions the
+        # body, be that axis of size 1 (Mosaic refuses both).  No error
+        # feedback there: its `lax.axis_index` beside an automatic axis
+        # does not lower on any platform (ROADMAP C8's standing failure)
+        ("fsdp=4", "int8", KERNELS),
+        ("dp=2,tp=2", "int8,ef=off", ()),
+        ("dp=4,tp=1", "int8,ef=off", ()),
+    ])
+    def test_step_lowered_for_a_tpu(self, mesh_axes, grad_compress, kernels,
+                                    monkeypatch):
+        monkeypatch.delenv("TPU_DIST_FLASH", raising=False)
+        lm = models.TransformerLM(vocab=64, dim=32, depth=2, heads=2,
+                                  max_seq=1024)
+        mesh = (parallel.build_mesh(mesh_axes, mesh_devices=jax.devices()[:4])
+                if mesh_axes else
+                comm.make_mesh(1, ("data",), mesh_devices=jax.devices()[:1]))
+        tr = train.LMTrainer(lm, mesh, train.LMTrainConfig(
+            global_batch=8, accum_steps=2, mesh_axes=mesh_axes,
+            grad_compress=grad_compress,
+            compute_dtype="bfloat16", log=lambda m: None))
+        batch = (jnp.zeros((8, 1024), jnp.int32),)
+        text = tr._partition.step.trace(
+            tr.params, tr.opt_state, batch, jax.random.key(0),
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert "module @jit_train_step " in text
+        for name in self.KERNELS:
+            assert (f'kernel_name = "{name}"' in text) == (name in kernels), name
+        assert ("@tpu_custom_call" in text) == bool(kernels)
+
+    @pytest.mark.parametrize("devices,mesh_axes,kernel", [
+        (1, None, True), (4, None, False), (4, "fsdp=4", False)])
+    def test_evaluation_lowered_for_a_tpu(self, devices, mesh_axes, kernel):
+        """`Trainer.evaluate` shards its batches over the mesh, under the
+        `shard_map` step and under the engine alike: over four devices a
+        program XLA partitions, which must lower (Mosaic is not asked);
+        on one device the kernel.  1024 tokens: a length flash takes."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        model = nn.Sequential([
+            nn.MultiHeadAttention(32, 2),
+            nn.Lambda(lambda h: h.mean(axis=1), lambda shape: shape[1:]),
+            nn.Dense(10),
+            nn.log_softmax(),
+        ])
+        mesh = (parallel.build_mesh(mesh_axes, mesh_devices=jax.devices()[:4])
+                if mesh_axes else
+                comm.make_mesh(devices, ("data",),
+                               mesh_devices=jax.devices()[:devices]))
+        tr = train.Trainer(model, (1024, 32), mesh, train.TrainConfig(
+            global_batch=4, mesh_axes=mesh_axes, log=lambda m: None))
+        xs = jax.ShapeDtypeStruct(
+            (4, 1024, 32), jnp.float32,
+            sharding=NamedSharding(mesh, P(mesh.axis_names[0])))
+        text = tr._eval_apply.trace(tr.params, tr.model_state, xs).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert ('kernel_name = "flash_fwd"' in text) == kernel
+        assert ("@tpu_custom_call" in text) == kernel
+
+    @pytest.mark.parametrize("S", [1024, 2048])
+    def test_flash_grads_compiled_for_the_v5e_hold_no_scores(self, v5e_chip, S):
+        """The selection's other branch is the dense form, whose residuals
+        are ``(b, heads, S, S)``; differentiated, the kernel's branch hands
+        zeros of that shape to a backward that never reads them.  Compiled
+        for the v5e they are gone: three kernels and no S x S array, at
+        the least length the rule takes and at twice that."""
+        import re
+
+        q = jax.ShapeDtypeStruct((2, 2, S, 64), jnp.bfloat16, sharding=v5e_chip)
+        grads = jax.jit(jax.grad(
+            lambda q, k, v: nn.dot_product_attention(q, k, v, causal=True)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+        lowered = grads.lower(q, q, q)
+        assert f"2x2x{S}x{S}" in lowered.as_text()  # still there as lowered
+        text = lowered.compile().as_text()
+        for name in self.KERNELS:
+            assert len(re.findall(
+                rf"^\s*%{name}\S* = .*custom-call\(.*"
+                r'custom_call_target="tpu_custom_call"', text, re.M)) == 1, name
+        assert not re.findall(rf"\[[\d,]*{S},{S}\]", text)
+
+
+def test_the_environment_decides_nothing_that_is_compiled():
+    """The ``TPU_DIST_*`` names `tpu_dist/` knows are a deployment's: where
+    telemetry, metrics and dumps go, where the data is, how processes find
+    each other and how long they retry, what chaos to inject.  None picks a
+    kernel, a wire or a layout: the program does (`ops.kernel_for_platform`)
+    or the config says (``grad_compress``, ``partition_rules``).  A new
+    name fails here first."""
+    import pathlib
+    import re
+
+    import tpu_dist
+
+    deployment = {
+        "TELEMETRY", "TELEMETRY_RANK", "TELEMETRY_EVERY", "METRICS_PORT",
+        "RUN_ID", "FLIGHTREC", "FLIGHTREC_DIR", "DATA_DIR", "PLATFORM",
+        "INIT_METHOD", "PROBE_WORLD", "CHAOS", "CHAOS_ATTEMPT",
+        "RDZV_RETRIES", "RDZV_BASE_DELAY", "RDZV_MAX_DELAY",
+        "STARTUP_DEADLINE",
+    }
+    root = pathlib.Path(tpu_dist.__file__).parent
+    named = set()
+    for path in root.rglob("*.py"):
+        # "TPU_DIST_RDZV_*" in a comment names the family, not a variable
+        named |= {n for n in re.findall(r"TPU_DIST_([A-Z0-9_]*[A-Z0-9])\b(?!_)",
+                                        path.read_text())}
+    assert named == deployment, sorted(named ^ deployment)

@@ -85,12 +85,12 @@ def test_mha_shapes_and_causality():
     np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(y2[:, 0]), atol=1e-6)
 
 
-def test_flash_flag_in_dot_product_attention(monkeypatch):
-    """TPU_DIST_FLASH=1 routes long sequences through the flash kernel;
-    results match the dense path."""
-    q = jax.random.normal(jax.random.key(0), (1, 2, 128, 16))
+def test_flash_in_dot_product_attention(request):
+    """Where the selection takes the flash kernel (the interpreter stands
+    in for it here) the results match the dense form it takes off the TPU."""
+    q = jax.random.normal(jax.random.key(0), (1, 2, 1024, 16))
     dense = nn.dot_product_attention(q, q, q, causal=True)
-    monkeypatch.setenv("TPU_DIST_FLASH", "1")
+    request.getfixturevalue("kernels_interpreted")
     flash = nn.dot_product_attention(q, q, q, causal=True)
     np.testing.assert_allclose(
         np.asarray(flash), np.asarray(dense), rtol=2e-5, atol=2e-5
@@ -294,17 +294,16 @@ class TestSlidingWindowAttention:
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
         )
 
-    def test_flash_path_matches_dense_path(self, monkeypatch):
+    def test_flash_path_matches_dense_path(self, request):
         from tpu_dist import nn as tnn
 
         attn = tnn.MultiHeadAttention(
-            dim=32, heads=2, causal=True, sliding_window=32
+            dim=32, heads=2, causal=True, sliding_window=320
         )
-        params, _ = attn.init(jax.random.key(2), (1, 128, 32))
-        x = jax.random.normal(jax.random.key(3), (1, 128, 32))
-        monkeypatch.setenv("TPU_DIST_FLASH", "0")
+        params, _ = attn.init(jax.random.key(2), (1, 1024, 32))
+        x = jax.random.normal(jax.random.key(3), (1, 1024, 32))
         dense, _ = attn.apply(params, {}, x)
-        monkeypatch.setenv("TPU_DIST_FLASH", "1")
+        request.getfixturevalue("kernels_interpreted")
         flash, _ = attn.apply(params, {}, x)
         np.testing.assert_allclose(
             np.asarray(flash), np.asarray(dense), rtol=2e-4, atol=2e-4

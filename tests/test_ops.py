@@ -73,26 +73,6 @@ class TestPallasMatmul:
                 np.asarray(a), np.asarray(b_), rtol=2e-5, atol=2e-5
             )
 
-    def test_dense_pallas_flag(self, monkeypatch):
-        """Dense routes through the kernel when the flag is set; results
-        match the default path."""
-        from tpu_dist import nn
-
-        layer = nn.Dense(8)
-        params, state = layer.init(jax.random.key(0), (16,))
-        x = jax.random.normal(jax.random.key(1), (4, 16))
-        y_default, _ = layer.apply(params, state, x)
-        monkeypatch.setenv("TPU_DIST_PALLAS_DENSE", "1")
-        # CPU can't run compiled pallas; assert the flag is honored by
-        # checking the kernel path raises-or-matches in interpret context.
-        from tpu_dist.ops.matmul import matmul, use_pallas_dense
-
-        assert use_pallas_dense()
-        y_kernel = matmul(x, params["w"], params["b"], interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(y_default), np.asarray(y_kernel), rtol=2e-5, atol=2e-5
-        )
-
 
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [False, True])
@@ -213,25 +193,25 @@ class TestFlashAttention:
         with pytest.raises(ValueError, match="window"):
             ops.flash_attention(q, q, q, window=0, interpret=True)
 
-    def test_gqa_through_module_grads_match_dense(self, monkeypatch):
+    def test_gqa_through_module_grads_match_dense(self, request):
         """VERDICT r4 #5: the Pallas backward kernels must hold for the
         GQA composition too — `nn.MultiHeadAttention(kv_heads < heads)`
         repeats K/V across each query-head group BEFORE the kernel, so
         the flash VJP's dK/dV must sum correctly back through the repeat.
-        Compare the whole module's param grads flash-on vs flash-off."""
+        Compare the whole module's param grads: the dense form (what the
+        selection takes here) against the interpreted kernel in its place."""
         from tpu_dist import nn as tnn
 
         attn = tnn.MultiHeadAttention(dim=32, heads=4, kv_heads=2, causal=True)
-        params, _ = attn.init(jax.random.key(0), (2, 128, 32))
-        x = jax.random.normal(jax.random.key(1), (2, 128, 32))
+        params, _ = attn.init(jax.random.key(0), (1, 1024, 32))
+        x = jax.random.normal(jax.random.key(1), (1, 1024, 32))
 
         def loss(p):
             out, _ = attn.apply(p, {}, x)
             return jnp.sum(out**2)
 
-        monkeypatch.setenv("TPU_DIST_FLASH", "0")
         g_dense = jax.grad(loss)(params)
-        monkeypatch.setenv("TPU_DIST_FLASH", "1")
+        request.getfixturevalue("kernels_interpreted")
         g_flash = jax.grad(loss)(params)
         for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_dense)):
             np.testing.assert_allclose(
@@ -377,33 +357,115 @@ def test_explicit_nondividing_block_skips_useless_padding():
     assert "pad" not in jaxpr_explicit
 
 
-def test_tuned_block_table_overrides_heuristic(tmp_path, monkeypatch):
-    """A measured tuned-blocks table (kernels.py --tune output) wins over
-    the _auto_blocks heuristic for its exact shapes; other shapes and
-    explicit args are untouched."""
-    import importlib
-    import json as _json
+# ------------------------------------------------ the program picks its kernel
 
-    mm = importlib.import_module("tpu_dist.ops.matmul")
-    table = tmp_path / "tuned.json"
-    table.write_text(_json.dumps({"512x512x512": [128, 128, 256]}))
-    monkeypatch.setenv("TPU_DIST_TUNED_BLOCKS", str(table))
-    monkeypatch.setattr(mm, "_TUNED_CACHE", None)  # force reload
-    assert mm._resolve_blocks(512, 512, 512, None, None, None) == (
-        128, 128, 256,
-    )
-    # explicit arg beats the table
-    assert mm._resolve_blocks(512, 512, 512, 256, None, None)[0] == 256
-    # unknown shape falls back to the heuristic
-    assert mm._resolve_blocks(256, 256, 256, None, None, None) == (
-        mm._auto_blocks(256, 256, 256)
-    )
-    # correctness through the kernel with the tuned pick
-    x = jax.random.normal(jax.random.key(30), (512, 512))
-    w = jax.random.normal(jax.random.key(31), (512, 512))
-    out = mm.matmul(x, w, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(x @ w), rtol=1e-4, atol=1e-4
-    )
-    monkeypatch.setattr(mm, "_TUNED_CACHE", None)  # don't leak to others
-    monkeypatch.delenv("TPU_DIST_TUNED_BLOCKS")
+
+def _qkv(sq=1024, sk=None, d=16):
+    q = jax.ShapeDtypeStruct((1, 2, sq, d), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 2, sk or sq, d), jnp.float32)
+    return q, k, k
+
+
+def _attend(q, k, v, **kw):
+    from tpu_dist import nn
+
+    return nn.dot_product_attention(q, k, v, causal=True, **kw)
+
+
+def _lowered(fn, *shapes, platform="tpu", **jit_kw):
+    """``fn``'s program as lowered FOR ``platform``, from this CPU host:
+    the lowering rules are the platform's (a Pallas call becomes a Mosaic
+    ``tpu_custom_call``, or is refused), no chip and no libtpu needed."""
+    return jax.jit(fn, **jit_kw).trace(*shapes).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+def _flash_grads(q, k, v):
+    return jax.grad(lambda *a: _attend(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def _over_four_devices(partitioned_by, attend=_attend):
+    """``attend`` over a 4-device mesh, batch of 4 sharded: either XLA
+    partitions the program (the builder says so with
+    `parallel.partitioned_over`, as `make_partitioned_train_step` does;
+    ``"nobody says"`` leaves it out), or a `shard_map` body holds one
+    device's share: manual over every axis of the mesh, or over ``dp``
+    alone beside a ``tp`` of size 1 that stays the compiler's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_dist import parallel
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct((4, 2, 1024, 16), jnp.float32)
+    sh = NamedSharding(mesh, P("dp"))
+    if partitioned_by in ("shard_map", "shard_map over dp alone"):
+        names = {"dp"} if partitioned_by.endswith("alone") else {"dp", "tp"}
+        fn = jax.shard_map(attend, mesh=mesh, in_specs=P("dp"),
+                           out_specs=P("dp"), axis_names=names,
+                           check_vma=False)
+    elif partitioned_by == "the compiler":
+        def fn(q, k, v):
+            with parallel.partitioned_over(mesh):
+                return attend(q, k, v)
+    else:
+        fn = attend
+    return _lowered(fn, q, q, q, in_shardings=(sh, sh, sh), out_shardings=sh)
+
+
+RULE_CASES = {
+    # what sends attention to the dense form, lowered for a TPU ...
+    "cross-attention lengths": (lambda: _lowered(_attend, *_qkv(1024, 2048)), 0),
+    "S=128, one block of the kernel's": (lambda: _lowered(_attend, *_qkv(128)), 0),
+    "S=512, under where the kernel stops losing": (
+        lambda: _lowered(_attend, *_qkv(512)), 0),
+    "S=1152, no multiple of its block": (lambda: _lowered(_attend, *_qkv(1152)), 0),
+    "an explicit mask": (lambda: _lowered(
+        lambda q, k, v, m: _attend(q, k, v, mask=m), *_qkv(),
+        jax.ShapeDtypeStruct((1, 1, 1024, 1024), jnp.bool_)), 0),
+    "a model's own scale": (lambda: _lowered(
+        lambda q, k, v: _attend(q, k, v, scale=0.5), *_qkv()), 0),
+    "partitioned over several devices": (
+        lambda: _over_four_devices("the compiler"), 0),
+    "a shard_map that leaves an axis, of size 1, to the compiler": (
+        lambda: _over_four_devices("shard_map over dp alone"), 0),
+    # ... and what keeps the kernel
+    "eligible": (lambda: _lowered(_attend, *_qkv()), 1),
+    "eligible, S=2048": (lambda: _lowered(_attend, *_qkv(2048)), 1),
+    "eligible, forward and backward": (lambda: _lowered(_flash_grads, *_qkv()), 3),
+    "a shard_map body": (lambda: _over_four_devices("shard_map"), 1),
+    # off the TPU the plain form, never the interpreter
+    "eligible, lowered for the cpu": (
+        lambda: _lowered(_attend, *_qkv(), platform="cpu"), 0),
+}
+
+
+@pytest.mark.parametrize("case", RULE_CASES)
+def test_attention_picks_its_kernel_where_it_is_lowered(case):
+    """`ops.kernel_for_platform` under `nn.dot_product_attention`: a Mosaic
+    call in the text lowered for a TPU exactly where `flash_attention_takes`
+    the shapes and XLA does not partition the program; the dense form
+    (no Pallas call, compiled or interpreted) everywhere else."""
+    text, kernels = RULE_CASES[case]
+    text = text()
+    assert text.count("@tpu_custom_call") == kernels, case
+    names = [n for n in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+             if f'kernel_name = "{n}"' in text]
+    assert len(names) == kernels
+    # the interpreter runs a kernel's grid as a loop; the dense form has none
+    assert "stablehlo.while" not in text
+
+
+@pytest.mark.parametrize("described_by", ["nobody says",
+                                          "shard_map over dp alone"])
+def test_mosaic_refuses_a_partitioned_program(described_by):
+    """What the rule stands in front of.  The four-device program whose
+    builder says nothing reaches Mosaic, which cannot be partitioned; and
+    Mosaic wants EVERY axis of a `shard_map`'s mesh manual, so the kernel
+    itself is refused where one of size 1 is left over: the rule asks what
+    Mosaic asks, not whether more than one device is involved."""
+    attend = _attend
+    if described_by != "nobody says":
+        def attend(q, k, v):
+            return ops.flash_attention(q, k, v, causal=True, interpret=False)
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        _over_four_devices(described_by, attend)

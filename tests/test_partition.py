@@ -112,13 +112,6 @@ class TestRuleMatching:
         assert specs["m"]["mlp"]["fc1"]["w"] == P(None, "tp")
         assert specs["step"] == P()
 
-    def test_parse_rules_env_format(self):
-        rules = part.parse_rules("embed/table$=None,tp; blocks/0/.*=replicated")
-        assert rules[0] == ("embed/table$", P(None, "tp"))
-        assert rules[1] == ("blocks/0/.*", P())
-        with pytest.raises(ValueError, match="malformed"):
-            part.parse_rules("no-equals-sign")
-
     def test_mesh_axes_parse_errors(self):
         with pytest.raises(ValueError, match="unknown mesh axis"):
             part.parse_mesh_axes("dp=2,banana=4")
@@ -334,29 +327,20 @@ class TestLMTrainerParity:
 
 
 class TestUserOverrides:
-    def test_config_rules_pin_a_layer(self):
+    @pytest.mark.parametrize("pin,want", [
+        ("replicated", P()), ("None,fsdp", P(None, "fsdp")),
+    ])
+    def test_config_rules_pin_a_layer(self, pin, want):
         spec = f"fsdp={N}"
         mesh = part.build_mesh(spec, platform="cpu")
         rules = part.resolve_rules(
-            spec, mesh, user_rules=[("embed/table$", "replicated")]
+            spec, mesh, user_rules=[("embed/table$", pin)]
         )
         lm = small_lm()
         params, _ = lm.init(jax.random.key(0))
         specs = part.match_partition_rules(rules.param_rules, params, mesh)
-        assert specs["embed"]["table"] == P()  # pinned replicated
+        assert specs["embed"]["table"] == want  # pinned, as a spec string
         assert specs["blocks"][0]["mlp"]["fc1"]["w"] != P()  # builtin sharded
-
-    def test_env_rules_win_over_config_and_builtins(self, monkeypatch):
-        spec = f"fsdp={N}"
-        mesh = part.build_mesh(spec, platform="cpu")
-        monkeypatch.setenv(part.ENV_RULES, "embed/table$=fsdp,None")
-        rules = part.resolve_rules(
-            spec, mesh, user_rules=[("embed/table$", "replicated")]
-        )
-        lm = small_lm()
-        params, _ = lm.init(jax.random.key(0))
-        specs = part.match_partition_rules(rules.param_rules, params, mesh)
-        assert specs["embed"]["table"] == P("fsdp")  # env beat the config pin
 
     def test_trainer_accepts_partition_rules(self):
         spec = f"fsdp={N}"

@@ -50,15 +50,14 @@ compressed sync therefore reduces a global all-finite predicate first
 the OUTPUT gradients are NaN'd, so a `resilience.nan_guard` optimizer
 skips the step exactly as it would under exact sync.
 
-Config parsing (`parse` / `resolve`) rejects unknown wire dtypes at
-config-parse time — a typo'd ``TPU_DIST_COMPRESS`` fails at trainer
-construction, not at trace time deep inside a compiled step.
+Config parsing (`parse`) rejects unknown wire dtypes at config-parse
+time — a typo'd ``grad_compress`` fails at trainer construction, not at
+trace time deep inside a compiled step.
 """
 
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass
 from typing import Any
 
@@ -68,8 +67,6 @@ from jax import lax
 
 from tpu_dist.comm.collectives import WIRE_ALIASES, _wire_spec
 from tpu_dist.comm.mesh import DEFAULT_AXIS
-
-ENV_COMPRESS = "TPU_DIST_COMPRESS"
 
 _OFF = ("", "off", "none", "0", "false")
 
@@ -157,15 +154,6 @@ def parse(spec) -> CompressConfig | None:
         else:
             raise ValueError(f"unknown compress option {k!r} in {spec!r}")
     return CompressConfig(**kw)
-
-
-def resolve(config_value=None) -> CompressConfig | None:
-    """The effective compression config: an explicit config value wins
-    (use ``"off"`` to force-disable); otherwise the ``TPU_DIST_COMPRESS``
-    environment variable; otherwise off."""
-    if config_value is not None:
-        return parse(config_value)
-    return parse(os.environ.get(ENV_COMPRESS))
 
 
 def refuse_model_axes(

@@ -103,8 +103,8 @@ class TransformerLM(Module):
         self.moe_balance_weight = moe_balance_weight
         # sliding_window=w: every block attends only the local band
         # (q-w, q] — Mistral-style long-context attention; flows through
-        # dense forward, cached decode/generate, and (with
-        # TPU_DIST_FLASH=1) the windowed flash kernels.
+        # dense forward, cached decode/generate, and the windowed flash
+        # kernels.
         self.sliding_window = sliding_window
         # Rematerialize each block's forward during backward
         # (jax.checkpoint): activation HBM drops from O(depth · B·S·d)

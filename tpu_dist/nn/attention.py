@@ -10,9 +10,12 @@ numerically comparable by construction.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from tpu_dist import ops
 from tpu_dist.nn.core import Module
 from tpu_dist.nn.layers import Dense
 
@@ -52,33 +55,37 @@ def dot_product_attention(
     (a model with an explicit attention multiplier passes its own, and
     stays on the dense path: the flash kernel fixes the default).
 
-    With ``TPU_DIST_FLASH=1`` the blockwise Pallas kernel
-    (`tpu_dist.ops.flash_attention`) takes over for sequences past its
-    block size — no (S, S) materialization; numerics match to fp
-    tolerance (differentiable either way)."""
-    import os
+    The program picks its own kernel (`ops.kernel_for_platform`): where
+    `ops.flash_attention_takes` these shapes and the program is lowered
+    for a TPU, the blockwise Pallas kernel (`tpu_dist.ops.flash_attention`)
+    computes it with no (S, S) array; anywhere else `dense_attention`.
+    Numerics match to fp tolerance, differentiable either way."""
+    dense = functools.partial(
+        dense_attention, causal=causal, mask=mask, window=window, scale=scale
+    )
+    if not ops.flash_attention_takes(q, k, v, mask=mask, scale=scale):
+        return dense(q, k, v)
+    return ops.kernel_for_platform(
+        functools.partial(ops.flash_attention, causal=causal, window=window),
+        dense, q, k, v,
+    )
 
+
+def dense_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = False,
+    mask: jax.Array | None = None,
+    window: int | None = None,
+    scale: float | None = None,
+) -> jax.Array:
+    """`dot_product_attention`'s plain form, on every platform: the
+    ``(sq, sk)`` scores materialized.  What the kernels are measured and
+    checked against."""
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    if os.environ.get("TPU_DIST_FLASH", "0") == "1":
-        S = q.shape[-2]
-        bq = bk = min(256, S)
-        eligible = (
-            q.shape == k.shape == v.shape  # self-attention lengths only
-            and S >= 128
-            and S % bq == 0
-            and mask is None  # kernel has no arbitrary-mask path
-            and scale in (None, q.shape[-1] ** -0.5)  # the kernel's own
-        )
-        if eligible:
-            from tpu_dist import ops
-
-            return ops.kernel_for_platform(
-                ops.flash_attention, q, k, v,
-                causal=causal, bq=bq, bk=bk, window=window,
-            )
-        # fall through to the dense path for shapes the kernel can't take
-        # (cross-attention, indivisible block sizes, short sequences)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("...hqd,...hkd->...hqk", q * scale, k)
@@ -136,9 +143,10 @@ class MultiHeadAttention(Module):
     ``kv_heads`` enables grouped-query attention (GQA): fewer key/value
     heads than query heads, each shared by ``heads // kv_heads`` query
     heads.  ``sliding_window=w`` restricts attention to the local band
-    ``k > q - w`` in BOTH the parallel forward (flash kernel skips
-    out-of-band blocks under TPU_DIST_FLASH=1) and cached decode.  The KV cache shrinks by the same factor — the reason GQA is
-    the modern long-context inference layout (``kv_heads=1`` is
+    ``k > q - w`` in BOTH the parallel forward (the flash kernel skips
+    out-of-band blocks) and cached decode.  The KV cache shrinks by the
+    same factor — the reason GQA is the modern long-context inference
+    layout (``kv_heads=1`` is
     multi-query attention).  With ``kv_heads == heads`` (default) the
     layer is exactly the classic fused-QKV MHA, param structure and all.
     ``use_bias=False`` drops the projections' biases; ``scale`` replaces

@@ -42,16 +42,6 @@ class Dense(Module):
         return input_shape[:-1] + (self.features,)
 
     def apply(self, params, state, x, *, train=False, key=None):
-        # Optional hand-tuned path: fused pallas matmul (+bias) kernel for
-        # 2-D activations (TPU_DIST_PALLAS_DENSE=1); default is XLA's dot,
-        # which it tiles onto the MXU itself.
-        from tpu_dist import ops
-
-        if self.use_bias and x.ndim == 2 and ops.use_pallas_dense():
-            y = ops.kernel_for_platform(
-                ops.matmul, x, params["w"], params["b"]
-            )
-            return y, state
         y = x @ params["w"]
         if self.use_bias:
             y = y + params["b"]

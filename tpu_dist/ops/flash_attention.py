@@ -328,6 +328,28 @@ def _flash_bwd(causal, bq, bk, interpret, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def flash_attention_takes(q, k, v, *, mask=None, scale=None) -> bool:
+    """Whether `flash_attention` at its default blocks (256) is the form
+    to compute this attention in: self-attention lengths from 1024 up
+    that the block divides, no arbitrary mask, the kernel's own scale.
+    Anything else (cross-attention, short or indivisible sequences,
+    padding or segment masks, a model's own multiplier) is the dense
+    form's.  The floor is where the kernel stops losing: on a v5e,
+    forward and backward at 128 heads of 64, causal, it takes 2.3-3.3
+    times the dense form's time at 128, 256 and 512 and the same time at
+    1024, where the dense form's (S, S) scores begin to cost memory
+    (PERF.md section 6, PR 30).  Below it some lengths do not even
+    compile (causal at 197: the kernel wants a multiple of 8)."""
+    S = q.shape[-2]
+    return (
+        q.shape == k.shape == v.shape
+        and S >= 1024
+        and S % 256 == 0
+        and mask is None
+        and scale in (None, q.shape[-1] ** -0.5)
+    )
+
+
 @functools.partial(
     jax.jit, static_argnames=("causal", "bq", "bk", "interpret", "window")
 )

@@ -10,15 +10,14 @@ output).
 Grid is (M/bm, N/bn, K/bk) with a float32 VMEM accumulator carried across
 the K dimension ("arbitrary" semantics — K iterations revisit the same
 output tile); inputs may be bf16 (MXU-native) while accumulation stays f32.
-Used by `tpu_dist.nn.Dense` when ``TPU_DIST_PALLAS_DENSE=1``; always
-available directly as `matmul`.  Tested against jnp.dot in interpret mode
-on CPU and compiled on real TPU.
+A library kernel: called directly as `matmul` (no layer routes through
+it; XLA's own product carries every model here).  Tested against jnp.dot
+in interpret mode on CPU and compiled on real TPU.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 from typing import Callable
 
@@ -57,55 +56,13 @@ def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, epilogue: str, nk: in
 
 _VMEM_BUDGET = 8 * 1024 * 1024  # ~half of a core's ~16MB VMEM
 
-_TUNED_CACHE: dict | None = None
-
-
-def _tuned_table() -> dict:
-    """Measured block winners from ``benchmarks/kernels.py --tune``,
-    keyed "MxNxK" per device kind.  Looked up before the `_auto_blocks`
-    heuristic so a committed hardware sweep re-tunes the defaults from
-    data (the profile -> iterate loop).  Source: the path in
-    ``TPU_DIST_TUNED_BLOCKS``, else
-    ``benchmarks/results/tuned_blocks_<device_kind>.json`` in the repo;
-    absent/unreadable -> empty (heuristic only)."""
-    global _TUNED_CACHE
-    if _TUNED_CACHE is not None:
-        return _TUNED_CACHE
-    import json
-    from pathlib import Path
-
-    path = os.environ.get("TPU_DIST_TUNED_BLOCKS")
-    if not path:
-        try:
-            import jax
-
-            kind = (
-                jax.devices()[0].device_kind.replace(" ", "_").replace("/", "_")
-            )
-        except Exception:
-            kind = "unknown"
-        path = str(
-            Path(__file__).resolve().parents[2]
-            / "benchmarks" / "results" / f"tuned_blocks_{kind}.json"
-        )
-    try:
-        _TUNED_CACHE = {
-            key: tuple(int(b) for b in blocks)
-            for key, blocks in json.loads(Path(path).read_text()).items()
-        }
-    except (OSError, ValueError):
-        _TUNED_CACHE = {}
-    return _TUNED_CACHE
-
-
 def _resolve_blocks(
     m: int, n: int, k: int, bm, bn, bk
 ) -> tuple[int, int, int]:
-    """Final block sizes: explicit args win, then a measured tuned-table
-    entry for this exact shape, then the `_auto_blocks` heuristic."""
+    """Final block sizes: explicit args win, then the `_auto_blocks`
+    heuristic."""
     if bm is None or bn is None or bk is None:
-        tuned = _tuned_table().get(f"{m}x{n}x{k}")
-        abm, abn, abk = tuned if tuned is not None else _auto_blocks(m, n, k)
+        abm, abn, abk = _auto_blocks(m, n, k)
         bm, bn, bk = bm or abm, bn or abn, bk or abk
     return bm, bn, bk
 
@@ -293,7 +250,3 @@ def matmul(
     # (1, N) internally — see _matmul_kernel's layout note.
     return _matmul_core(x, w, b.reshape(1, n), epilogue, bm, bn, bk, interpret)
 
-
-def use_pallas_dense() -> bool:
-    """Feature flag: route `tpu_dist.nn.Dense` through this kernel."""
-    return os.environ.get("TPU_DIST_PALLAS_DENSE", "0") == "1"

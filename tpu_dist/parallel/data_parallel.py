@@ -323,6 +323,17 @@ def make_spmd_train_step(
     return jax.jit(mapped, donate_argnums=(0, 1, 2) if donate else ())
 
 
+def partitioned_over(mesh: Mesh):
+    """The context in which the builder of a `jax.jit` whose arguments
+    span ``mesh`` traces what the model computes.  XLA partitions such a
+    program over the mesh, and nothing inside the trace can see that:
+    the context mesh says it (`ops.kernel_for_platform` then keeps a
+    Mosaic kernel, which cannot be partitioned, out of the program).  A
+    `shard_map` says as much by itself; a mesh of one device changes
+    nothing."""
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+
+
 def make_train_step_auto(
     loss_fn: Callable[..., Any],
     optimizer,
@@ -350,9 +361,10 @@ def make_train_step_auto(
     sharded = NamedSharding(mesh, P(axis_name))
 
     def train_step(params, model_state, opt_state, batch, key):
-        (loss, (new_state, aux)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(params, model_state, batch, key)
+        with partitioned_over(mesh):
+            (loss, (new_state, aux)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params, model_state, batch, key)
         with jax.named_scope("optimizer"):
             params, opt_state = optimizer.update(params, grads, opt_state)
         return params, new_state, opt_state, loss, aux

@@ -27,7 +27,7 @@ the strategy is DATA, not code:
   composes them with a Megatron-layout ``tp`` vocabulary for
   `TransformerLM` — 2-D/3-D meshes come from one config knob
   (`TrainConfig.mesh_axes` / `LMTrainConfig.mesh_axes`), and per-layer
-  overrides ride user rules (config list or the ``TPU_DIST_RULES`` env)
+  overrides ride user rules (the config's ``partition_rules`` list)
   matched FIRST.
 
 Numerics: the partitioned program is the SAME global math, partitioned —
@@ -38,7 +38,6 @@ against the legacy builders and the dense reference).
 
 from __future__ import annotations
 
-import os
 import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
@@ -48,17 +47,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_dist.parallel.data_parallel import partitioned_over
+
 DP_AXIS = "dp"
 FSDP_AXIS = "fsdp"
 TP_AXIS = "tp"
 KNOWN_AXES = (DP_AXIS, FSDP_AXIS, TP_AXIS)
-ENV_RULES = "TPU_DIST_RULES"
 
 __all__ = [
     "DP_AXIS",
     "FSDP_AXIS",
     "TP_AXIS",
-    "ENV_RULES",
     "RuleSet",
     "PartitionedTrainStep",
     "build_mesh",
@@ -68,7 +67,6 @@ __all__ = [
     "make_shard_and_gather_fns",
     "gather_replicated",
     "parse_mesh_axes",
-    "parse_rules",
     "partition_summary",
     "per_device_bytes",
     "state_bytes_by_class",
@@ -253,7 +251,7 @@ def match_partition_rules(rules, tree: Any, mesh: Mesh) -> Any:
     raises (built-in rule sets always end with a catch-all).
 
     ``rules``: iterable of ``(pattern, value)`` where value is a
-    `PartitionSpec`, a spec string (see `parse_rules`), or a callable
+    `PartitionSpec`, a spec string (see `_parse_spec`), or a callable
     ``(path, leaf, mesh) -> PartitionSpec`` (e.g. `shard_over`)."""
     matched, treedef = _match_leaves(rules, tree, mesh)
     return jax.tree_util.tree_unflatten(
@@ -336,26 +334,6 @@ def _parse_spec(text: str) -> P:
         else:
             entries.append(part)
     return P(*entries)
-
-
-def parse_rules(text: str) -> tuple:
-    """User rules from a string (the ``TPU_DIST_RULES`` env format):
-    ``'pattern=spec;pattern=spec'`` with spec per `_parse_spec`, e.g.
-    ``'embed/table$=None,tp;blocks/0/.*=replicated'``.  Returned rules
-    are matched FIRST (ahead of config and built-in rules)."""
-    rules = []
-    for clause in text.split(";"):
-        clause = clause.strip()
-        if not clause:
-            continue
-        if "=" not in clause:
-            raise ValueError(
-                f"malformed {ENV_RULES} clause {clause!r} — expected "
-                "'pattern=spec' (spec like 'None,tp' or 'replicated')"
-            )
-        pattern, spec = clause.split("=", 1)
-        rules.append((pattern.strip(), _parse_spec(spec)))
-    return tuple(rules)
 
 
 def _normalize_user_rules(user_rules) -> tuple:
@@ -544,7 +522,6 @@ def resolve_rules(
     mesh: Mesh,
     *,
     user_rules=None,
-    env: bool = True,
     bind: dict[str, str] | None = None,
 ) -> RuleSet:
     """The `RuleSet` for a mesh_axes spec, validated against ``mesh``.
@@ -569,10 +546,9 @@ def resolve_rules(
     therefore checkpoint/telemetry provenance) stays role-based;
     ``data_axes``/``model_axes`` and every rule carry the BOUND names.
 
-    ``user_rules`` (list of ``(pattern, spec)``) and the
-    ``TPU_DIST_RULES`` env (when ``env=True``) are matched ahead of the
-    built-ins, env first — so a single layer can be pinned to a
-    different spec without forking the rule set.  User rules apply to
+    ``user_rules`` (list of ``(pattern, spec)``) are matched ahead of
+    the built-ins — so a single layer can be pinned to a different spec
+    without forking the rule set.  User rules apply to
     params AND optimizer state (the update follows the pinned layout).
     """
     prefix, axes = parse_mesh_axes(spec)
@@ -627,8 +603,7 @@ def resolve_rules(
         opt_rules = tuple(
             (pat, _fill(val, update_axes)) for pat, val in param_rules
         )
-    user = parse_rules(os.environ.get(ENV_RULES, "")) if env else ()
-    user += _normalize_user_rules(user_rules)
+    user = _normalize_user_rules(user_rules)
     return RuleSet(
         name=name,
         param_rules=user + tuple(param_rules),
@@ -1004,10 +979,14 @@ def make_partitioned_train_step(
     if ccfg is None:
 
         def train_step(params, opt_state, batch, key):
-            if accum_steps == 1:
-                (loss, aux), grads = vg(params, batch, key)
-            else:
-                grads, loss, aux = accumulate(params, batch, key)
+            # XLA partitions this program over the mesh, and only this
+            # builder knows (the compressed path's `shard_map` below
+            # says as much by itself)
+            with partitioned_over(mesh):
+                if accum_steps == 1:
+                    (loss, aux), grads = vg(params, batch, key)
+                else:
+                    grads, loss, aux = accumulate(params, batch, key)
             # The sharded weight update: pin the gradient (same shapes
             # as params) to the UPDATE layout, so the optimizer's
             # elementwise math — and the momenta it reads/writes —

@@ -55,7 +55,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from tpu_dist import ops
 
@@ -179,9 +178,9 @@ def _attend_in_pool(q, k_pool, v_pool, block_tables, lengths, *,
                     sliding_window):
     """Decode's read side: query ``q[s]`` ``(S, heads, hd)`` attends the
     ``lengths[s]`` places its slot holds (0: nothing, a row of zeros).
-    Which way is decided where the program is LOWERED, by the platform it
-    is lowered for: `ops.paged_attention_decode` on a TPU; anywhere else
-    the gathered view, because what another platform could do with the
+    Which way is `ops.kernel_for_platform`'s to say, where the program is
+    LOWERED: `ops.paged_attention_decode` on a TPU; anywhere else the
+    gathered view, because what another platform could do with the
     kernel is interpret it, at several times the view's cost on a CPU
     (the kernel's own tests do, tests/test_paged_attention_kernel.py).
 
@@ -197,11 +196,10 @@ def _attend_in_pool(q, k_pool, v_pool, block_tables, lengths, *,
         )[:, :, 0]
         return jnp.where((lengths > 0)[:, None, None], o, 0).astype(q.dtype)
 
-    return lax.platform_dependent(
-        q, k_pool, v_pool, block_tables, lengths,
-        tpu=functools.partial(ops.paged_attention_decode,
-                              sliding_window=sliding_window),
-        default=view,
+    return ops.kernel_for_platform(
+        functools.partial(ops.paged_attention_decode,
+                          sliding_window=sliding_window),
+        view, q, k_pool, v_pool, block_tables, lengths,
     )
 
 

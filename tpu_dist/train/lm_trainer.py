@@ -103,8 +103,8 @@ class LMTrainConfig:
     # engine-routed config — dp, fsdp, zero1, composed mesh_axes
     # (dp×fsdp, dp×tp: model-sharded grads compress at their shard
     # shape over the data axes).  The EF residual rides the
-    # optimizer-state checkpoint.  None = follow TPU_DIST_COMPRESS;
-    # 'off' = force-disable.  Refused by the shard_map-only modes
+    # optimizer-state checkpoint.  None = exact f32 sync.  Refused by
+    # the shard_map-only modes
     # (sequence/pipeline/moe, and the tensor_parallel flag without
     # fsdp/zero1 — use mesh_axes 'dp=A,tp=B' instead).
     grad_compress: str | None = None
@@ -138,7 +138,7 @@ class LMTrainConfig:
     # composes (the quantized wire rides inside the engine step).
     mesh_axes: str | None = None
     # Per-model overrides for the engine: (regex, spec) pairs matched
-    # ahead of the built-ins (TPU_DIST_RULES env rules come first).
+    # ahead of the built-ins.
     partition_rules: list | None = None
     log: Callable[[str], None] = print
 
@@ -176,13 +176,13 @@ class LMTrainer:
                 self.optimizer, self.config.grad_clip
             )
 
-        # Compressed gradient sync: resolved (and VALIDATED — a typo'd
-        # wire dtype fails here, not at trace time) from config or the
-        # TPU_DIST_COMPRESS env var.  The wire itself lives INSIDE the
-        # partition engine (`make_partitioned_train_step(compress=)`).
+        # Compressed gradient sync: parsed (and VALIDATED — a typo'd
+        # wire dtype fails here, not at trace time) from the config.
+        # The wire itself lives INSIDE the partition engine
+        # (`make_partitioned_train_step(compress=)`).
         from tpu_dist.comm import compress as compress_mod
 
-        self._compress = compress_mod.resolve(self.config.grad_compress)
+        self._compress = compress_mod.parse(self.config.grad_compress)
         self._wrap_ef = (
             self._compress is not None and self._compress.error_feedback
         )

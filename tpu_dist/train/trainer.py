@@ -81,7 +81,7 @@ class TrainConfig:
     # (the residual is per-rank, so a single-writer npz cannot hold it
     # multi-host).  Requires a stateless model, grad_reduce='psum', and
     # no loss_scale (those need the explicit shard_map step, which has
-    # no wire).  None = follow TPU_DIST_COMPRESS; 'off' = force-disable.
+    # no wire).  None = exact f32 sync.
     grad_compress: str | None = None
     # NaN guard (resilience.nan_guard): fused non-finite detection on
     # loss/grads inside the compiled step — a bad step is skipped
@@ -112,8 +112,7 @@ class TrainConfig:
     mesh_axes: str | None = None
     # Per-model overrides for the engine: list of (regex, spec) pairs
     # matched AHEAD of the built-in rules (spec = PartitionSpec or a
-    # string like "None,tp"); the TPU_DIST_RULES env var prepends
-    # further rules ahead of these.  Ignored without mesh_axes.
+    # string like "None,tp").  Ignored without mesh_axes.
     partition_rules: list | None = None
 
 
@@ -147,13 +146,13 @@ class Trainer:
         self.world = int(np.prod(mesh.devices.shape))
         self.optimizer = optimizer or sgd(self.config.lr, self.config.momentum)
         self._loss = loss
-        # Compressed gradient sync: resolved (and VALIDATED — a typo'd
-        # wire dtype fails here, not at trace time) from config or the
-        # TPU_DIST_COMPRESS env var.  The wire itself lives INSIDE the
-        # partition engine now (`make_partitioned_train_step(compress=)`).
+        # Compressed gradient sync: parsed (and VALIDATED — a typo'd
+        # wire dtype fails here, not at trace time) from the config.
+        # The wire itself lives INSIDE the partition engine
+        # (`make_partitioned_train_step(compress=)`).
         from tpu_dist.comm import compress as compress_mod
 
-        self._compress = compress_mod.resolve(self.config.grad_compress)
+        self._compress = compress_mod.parse(self.config.grad_compress)
         self._wrap_ef = (
             self._compress is not None and self._compress.error_feedback
         )
@@ -357,9 +356,13 @@ class Trainer:
             self._compress_summary = self._partition.flat_plan.wire_summary(
                 "all_reduce"
             )
-        self._eval_apply = jax.jit(
-            lambda params, state, x: model.apply(params, state, x, train=False)[0]
-        )
+
+        def eval_apply(params, state, x):
+            # batches are sharded over the mesh: a partitioned program
+            with parallel.partitioned_over(mesh):
+                return model.apply(params, state, x, train=False)[0]
+
+        self._eval_apply = jax.jit(eval_apply)
 
     @property
     def _sharded_mode(self) -> bool:
