@@ -378,6 +378,45 @@ class TestProgramNamesAndScopes:
         for scope in want:
             assert self._scoped(text, scope), scope
 
+    @pytest.mark.parametrize("causal,window", [
+        (True, None), (False, None), (True, 24)])
+    def test_the_flash_kernels_feed_the_mxu_the_inputs_dtype(self, causal, window):
+        """Traced with bfloat16 inputs, every product of the three flash
+        kernels takes bfloat16 operands and gives float32, and nothing
+        bfloat16 is converted to float32 inside a kernel (q, k, v and dO
+        reach the MXU as they arrive; everything else is born float32):
+        an edit that brings the upcast back fails here, on the CPU."""
+        from tpu_dist.ops.flash_attention import flash_attention
+
+        q = jnp.ones((1, 2, 64, 16), jnp.bfloat16)
+        closed = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, window=window, bq=16, bk=16,
+                interpret=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(q, q, q)
+
+        def eqns(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from eqns(sub)
+
+        kernels = {e.params["name"]: e.params["jaxpr"]
+                   for e in eqns(closed.jaxpr) if e.primitive.name == "pallas_call"}
+        products = {"flash_fwd": 2, "flash_bwd_dkv": 4, "flash_bwd_dq": 3}
+        assert set(kernels) == set(products)
+        for name, kernel in kernels.items():
+            dots = [e for e in eqns(kernel) if e.primitive.name == "dot_general"]
+            assert len(dots) == products[name], (name, len(dots))
+            for e in dots:
+                assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2, name
+                assert e.outvars[0].aval.dtype == jnp.float32, name
+            upcasts = [e for e in eqns(kernel)
+                       if e.primitive.name == "convert_element_type"
+                       and e.invars[0].aval.dtype == jnp.bfloat16
+                       and e.params["new_dtype"] == jnp.float32]
+            assert not upcasts, (name, [str(e.invars[0].aval) for e in upcasts])
+
     def test_the_kernels_have_names(self):
         """`name=` on every `pl.pallas_call`: the trace's one
         `flash_attention` row becomes forward, dK/dV and dQ."""

@@ -1,6 +1,8 @@
 """Pallas kernel tests (interpret mode on CPU; real-TPU compile paths are
 gated behind the `tpu` marker)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -192,6 +194,94 @@ class TestFlashAttention:
         q = jnp.ones((1, 1, 128, 8))
         with pytest.raises(ValueError, match="window"):
             ops.flash_attention(q, q, q, window=0, interpret=True)
+
+    @pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32)])
+    @pytest.mark.parametrize("causal,window", [
+        (False, None), (True, None), (True, 24), (False, 24)])
+    def test_bfloat16_inputs_match_dense_on_float32_copies(
+            self, causal, window, blocks):
+        """The kernels hand the MXU the input's dtype: bfloat16 q, k, v
+        and dO, float32 accumulation, ``p`` and ``ds`` rounded to bfloat16
+        for their products.  Forward and the three gradients against the
+        dense form on the float32 copies of the same values, at a length
+        where a row of tiles lies under, on and past the diagonal and the
+        window's edge (S = 64, blocks of 16 and 32, a window of 24), in
+        the relative Frobenius norm (`chip_smoke.py` bounds the max norm
+        by 2e-2 / 4e-2).  What bfloat16 gives here: the output's own
+        rounding (2^-9 a value) reads 1.8e-3 to 2.1e-3, the gradients
+        2.6e-3 to 3.2e-3; the float32 upcast of the operands read 1.7e-3
+        and up to 2.6e-3 (PR 30's kernel, same cases)."""
+        from tpu_dist.nn.attention import dense_attention
+
+        ks = jax.random.split(jax.random.key(0), 4)
+        shape = (1, 2, 64, 16)
+        q, k, v = (jax.random.normal(kk, shape).astype(jnp.bfloat16)
+                   for kk in ks[:3])
+        wgt = jax.random.normal(ks[3], shape)  # non-trivial cotangent
+        bq, bk = blocks
+
+        def loss_flash(q, k, v):
+            out = ops.flash_attention(q, k, v, causal=causal, window=window,
+                                      bq=bq, bk=bk, interpret=True)
+            return jnp.sum(out.astype(jnp.float32) * wgt), out
+
+        def loss_dense(q, k, v):
+            out = dense_attention(q, k, v, causal=causal, window=window)
+            return jnp.sum(out * wgt), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        assert out.dtype == jnp.bfloat16
+        assert all(g.dtype == jnp.bfloat16 for g in grads)
+        (_, want), want_g = jax.value_and_grad(
+            loss_dense, argnums=(0, 1, 2), has_aux=True)(
+                *(x.astype(jnp.float32) for x in (q, k, v)))
+
+        def rel(a, b):
+            a, b = (np.asarray(x, np.float32) for x in (a, b))
+            return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+        assert rel(out, want) < 4e-3
+        for g, wg in zip(grads, want_g):
+            assert rel(g, wg) < 7e-3
+
+    @pytest.mark.parametrize("d", [16, 128, 160])
+    def test_row_sums_with_and_without_spare_lanes(self, d):
+        """Where the head size leaves lanes of a 128-wide MXU tile to
+        spare (16: 112 of them, 160: 96), the forward takes p's row sums
+        from a block of ones beside V; at 128 it reduces over lanes.  Both
+        give the dense form's values and gradients."""
+        from tpu_dist.nn import dot_product_attention
+
+        ks = jax.random.split(jax.random.key(3), 3)
+        q, k, v = (jax.random.normal(kk, (1, 1, 32, d)) for kk in ks)
+
+        def loss(attn):
+            return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+
+        flash = functools.partial(ops.flash_attention, causal=True, bq=16,
+                                  bk=16, interpret=True)
+        dense = functools.partial(dot_product_attention, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)),
+            rtol=2e-5, atol=2e-5)
+        for a, b in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
+
+    def test_default_blocks_follow_the_length(self):
+        """512 where that divides the sequence, 256 where it does not,
+        the whole sequence below that; the caller's blocks win."""
+        from tpu_dist.ops.flash_attention import _blocks
+
+        assert _blocks(1024, None, None) == (512, 512)
+        assert _blocks(1280, None, None) == (256, 256)
+        assert _blocks(512, None, None) == (512, 512)
+        assert _blocks(64, None, None) == (64, 64)
+        assert _blocks(1024, 128, None) == (128, 512)
+        with pytest.raises(ValueError, match="not divisible"):
+            _blocks(1000, None, None)
 
     def test_gqa_through_module_grads_match_dense(self, request):
         """VERDICT r4 #5: the Pallas backward kernels must hold for the

@@ -188,8 +188,8 @@ def ring_attention_flash(
     axis_name: str,
     *,
     causal: bool = False,
-    bq: int = 256,
-    bk: int = 256,
+    bq: int | None = None,
+    bk: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """`ring_attention` with each block computed by the Pallas flash
