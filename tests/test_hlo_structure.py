@@ -693,6 +693,53 @@ class TestTheTrainerPicksFlashItself:
         assert not re.findall(rf"\[[\d,]*{S},{S}\]", text)
 
 
+@pytest.fixture(scope="module")
+def v5e_latent_engine():
+    """Latent rows of 96 + 8 = 104 and 160 + 8 = 168 values, neither a
+    whole number of 128-lane tiles, as the published 576 and 1088 are not;
+    257 blocks and rings of 24 rows, which pad less than those rows."""
+    from tpu_dist import serve
+
+    sizes = dict(heads=4, q_rank=64, nope_dim=32, rope_dim=8, v_dim=32, rope_base=1e4)
+    lm = models.HybridLM(
+        vocab=128, dim=128, layer_types=["full_attention", "sliding_attention"],
+        mixers={"full_attention": dict(sizes, kv_rank=96, index_heads=4, index_dim=128,
+                                       index_topk=16),
+                "sliding_attention": dict(sizes, kv_rank=160, window=9, chunk=16)},
+        n_experts=8, experts_per_token=2, expert_width=32, shared_width=32,
+        held_experts=(0, 4), expert_scoring="sigmoid_normalised", dense_layers=1,
+        dense_width=64, tied_head=False, max_seq=64)
+    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), lm.init(jax.random.key(0))[0])
+    return serve.ServeEngine(lm, params, serve.ServeConfig(
+        max_batch=4, block_size=16, num_blocks=256, max_seq=64, prefill_chunk=16,
+        prefill_batch=2))
+
+
+class TestLatentCachesAreNotRelayoutOnTheV5e:
+    """The latent pool and the per-slot ring keep their row as whole
+    128-lane tiles (`serve.paged_kv.init_latent_cache`), so the row stays
+    the minor dimension on the device and the donated arrays are updated
+    in place.  Left at 576 / 1088 values, the published sizes compiled
+    with 8 copies of a 264-MB pool and 12 of a 27-MB ring in every decode
+    step (PERF.md section 6, PR 32)."""
+
+    @pytest.mark.parametrize("program,rows", [("serve_decode_greedy", None),
+                                              ("serve_prefill", 2)])
+    def test_no_cache_sized_copy(self, v5e_latent_engine, v5e_chip, program, rows):
+        import math
+        import re
+
+        text, _, cache = _compiled_for_the_v5e(v5e_latent_engine, v5e_chip, program, rows)
+        kept = {"ckv": cache["kv"][0]["ckv"].shape, "ring": cache["state"]["layers"][1]["ring"].shape}
+        assert kept == {"ckv": (257, 16, 128), "ring": (4, 24, 256)}
+        copy_of = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(")
+        sizes = {math.prod(shape) for shape in kept.values()}
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if (m := copy_of.search(line))
+                  and math.prod(int(d) for d in m.group(1).split(",") if d) in sizes]
+        assert not copies, "\n".join(copies[:4])
+
+
 def test_the_environment_decides_nothing_that_is_compiled():
     """The ``TPU_DIST_*`` names `tpu_dist/` knows are a deployment's: where
     telemetry, metrics and dumps go, where the data is, how processes find

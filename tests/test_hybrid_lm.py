@@ -165,6 +165,9 @@ def test_the_engine_serves_the_dense_paths_tokens(model):
     per_expert = [REGISTRY.counter("tpu_dist_serve_moe_expert_tokens_total").value(expert=str(e))
                   for e in range(*CFG["held_experts"])]
     assert picks > 0 and 0 < held < picks and sum(per_expert) == held
+    # a held expert given a token in a layer's call is one whose weights were read
+    hit = REGISTRY.counter("tpu_dist_serve_moe_experts_hit_total").value()
+    assert 0 < hit <= held
     assert picks % (CFG["num_experts_per_tok"] * CFG["num_hidden_layers"]) == 0
 
 
@@ -173,7 +176,7 @@ def test_state_bytes_are_accounted_beside_weights_and_pool(model):
     eng = ServeEngine(lm, params, ServeConfig(
         max_batch=3, block_size=8, num_blocks=36, max_seq=96, prefill_chunk=16, bytes_limit=1))
     held = CFG["held_experts"][1] - CFG["held_experts"][0]
-    assert eng.state_bytes == 3 * family.state_bytes_per_slot(CFG) + 4 * (2 + held)
+    assert eng.state_bytes == 3 * family.state_bytes_per_slot(CFG) + 4 * (3 + held)
     pool = 2 * 37 * 8 * CFG["num_key_value_heads"] * 16 * 4   # one attention layer, float32
     assert eng.kv_pool_bytes == pool * CFG["layer_types"].count("attention")
     bd = eng.memory_breakdown()

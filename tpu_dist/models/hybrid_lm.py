@@ -1,23 +1,39 @@
 """A decoder whose layers are of several kinds: each layer is a MIXER
-chosen by ``layer_types[l]`` (a Mamba-2 state-space mixer, or grouped-query
-attention with no positional encoding) followed by a routed
-mixture-of-experts feed-forward with one shared expert.
+chosen by ``layer_types[l]`` from the table `MIXERS` followed by a
+feed-forward chosen by the layer's place: a dense gated one in the first
+``dense_layers`` layers, after them a routed mixture of experts with one
+shared expert.
 
     h0 = embedding_multiplier * E[token]
     h <- h + residual_multiplier * mixer_l(RMSNorm(h))
-    h <- h + residual_multiplier * (routed(u) + shared(u)),  u = RMSNorm(h)
-    logits = RMSNorm(h_L) @ E^T / logits_scaling            (tied table)
+    h <- h + residual_multiplier * ff_l(RMSNorm(h))
+    logits = RMSNorm(h_L) @ W_head^T / logits_scaling    (float32)
 
-**Mamba-2 mixer** (Dao & Gu 2024; one group): ``[z | xBC | dt] = x @
-in_proj``; ``xBC <- silu(causal depthwise conv(xBC))``; ``[xs | B | C] =
-xBC``; ``dt <- softplus(dt + dt_bias)``; the scan of
-`tpu_dist.ops.ssm_scan` with ``A = -exp(A_log)``; ``y <- RMSNorm(y *
-silu(z))`` over all inner channels; ``y @ out_proj``.  No biases but the
-convolution's.  **Attention**: bias-free projections, scores scaled by
-``attention_multiplier``, causal.  **Experts**: `parallel.moe.routed_experts`
-(top-k over ``n_experts`` router outputs, gates the softmax of the picked
-logits) over the ``held_experts`` this rank holds, and a `nn.GatedMLP` of
-``shared_width`` computed whole.
+``W_head`` is the embedding table (``tied_head``) or a matrix of its own.
+
+**The mixers** (`MIXERS`; each offers its weights, its dense form, what
+it keeps between calls and its cached form to the ONE block function):
+
+- ``"mamba"``, Mamba-2 (Dao & Gu 2024; one group): ``[z | xBC | dt] = x @
+  in_proj``; ``xBC <- silu(causal depthwise conv(xBC))``; ``[xs | B | C] =
+  xBC``; ``dt <- softplus(dt + dt_bias)``; the scan of
+  `tpu_dist.ops.ssm_scan` with ``A = -exp(A_log)``; ``y <- RMSNorm(y *
+  silu(z))`` over all inner channels; ``y @ out_proj``.  No biases but the
+  convolution's.  Keeps a float32 ``{"conv", "ssm"}`` state a decode slot.
+- ``"attention"``: bias-free grouped-query attention with no positions,
+  scores scaled by ``attention_multiplier``, causal.  Keeps a ``{"k",
+  "v"}`` pool of `serve.paged_kv`'s layout.
+- ``"full_attention"``, ``"sliding_attention"``: `nn.LatentAttention`
+  (low-rank queries and keys/values, a rope part shared by the heads, a
+  gate a head), the first with the learned top-k selection of its keys,
+  the second windowed; each with its own sizes (``latent[kind]``).  The
+  first keeps a pool of latent rows and one of index keys under the
+  engine's block tables; the second no pool but a ring of ``window - 1 +
+  chunk`` positions a decode slot (`serve.paged_kv.init_latent_cache`).
+
+**Experts**: `parallel.moe.routed_experts` (top-k over ``n_experts``
+router outputs, gates by ``expert_scoring``) over the ``held_experts``
+this rank holds, and a `nn.GatedMLP` of ``shared_width`` computed whole.
 
 There is ONE block function (`_block`), which takes the mixer as a
 callable: the dense `apply` (whole sequences, no cache: tests and
@@ -28,155 +44,88 @@ the mixer of each layer's kind in its dense or its cached form.
 knows nothing of a block's inside):
 
 - ``init_serve_cache(max_batch, num_blocks, block_size, dtype)`` ->
-  ``{"kv": [...], "state": ...}``: by layer a ``{"k", "v"}`` pool of
-  `serve.paged_kv`'s layout for an attention layer; for a Mamba layer a
-  float32 ``{"conv": (max_batch, K-1, channels), "ssm": (max_batch, heads,
-  head_dim, d_state)}`` indexed by decode slot.  ``state`` also holds the
-  running expert-load counts (`serve_counters`).
+  ``{"kv": [...], "state": ...}``: by layer what its mixer keeps, pools
+  under ``kv``, what is indexed by decode slot under ``state["layers"]``.
+  ``state`` also holds the running counts (`serve_counters`).
 - ``apply_paged(params, tokens, cache, block_tables, positions,
   write_mask, slots, block_size)`` -> ``(logits, cache, counters)``.
   ``slots`` is each row's decode slot, or None where row ``i`` IS slot
   ``i`` (the decode step).  A row whose first token is real and at
-  position 0 starts from a zero state; a masked token leaves the state
-  as it was, so an inactive slot's state is left alone.
+  position 0 starts from a zero recurrent state; a masked token leaves
+  every state as it was, so an inactive slot's is left alone.
 """
 
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from tpu_dist.nn.attention import MultiHeadAttention
 from tpu_dist.nn.core import Module
+from tpu_dist.nn.latent_attention import LatentAttention
 from tpu_dist.nn.layers import GatedMLP, RMSNorm
 from tpu_dist.ops.ssm_scan import causal_conv, ssd_chunked, ssm_step
 from tpu_dist.parallel.moe import routed_experts
 
-MIXERS = ("mamba", "attention")
+
+class Paged(NamedTuple):
+    """What a cached mixer is told of the call beside its input."""
+    block_tables: jax.Array
+    positions: jax.Array
+    write_mask: jax.Array
+    slots: jax.Array | None
+    block_size: int
+    fresh: jax.Array     # (rows,): the row starts a request
 
 
-class HybridLM(Module):
-    def __init__(
-        self,
-        *,
-        vocab: int,
-        dim: int,
-        layer_types: list[str],
-        heads: int,
-        kv_heads: int,
-        ssm_heads: int,
-        ssm_head_dim: int,
-        ssm_state: int,
-        ssm_conv: int = 4,
-        ssm_chunk: int = 256,
-        n_experts: int,
-        experts_per_token: int,
-        expert_width: int,
-        shared_width: int,
-        held_experts: tuple[int, int] | None = None,
-        embedding_multiplier: float = 1.0,
-        residual_multiplier: float = 1.0,
-        attention_multiplier: float | None = None,
-        logits_scaling: float = 1.0,
-        norm_eps: float = 1e-5,
-        max_seq: int = 2048,
-    ):
-        unknown = set(layer_types) - set(MIXERS)
-        if unknown:
-            raise ValueError(f"layer_types of {sorted(unknown)}; known: {MIXERS}")
-        self.vocab, self.dim, self.max_seq = vocab, dim, max_seq
-        self.layer_types = list(layer_types)
-        self.ssm_heads, self.ssm_head_dim, self.ssm_state = ssm_heads, ssm_head_dim, ssm_state
-        self.ssm_inner = ssm_heads * ssm_head_dim
-        self.ssm_channels = self.ssm_inner + 2 * ssm_state   # what the convolution sees
-        self.ssm_conv, self.ssm_chunk = ssm_conv, ssm_chunk
-        self.n_experts, self.experts_per_token = n_experts, experts_per_token
-        self.expert_width = expert_width
-        self.held_experts = tuple(held_experts) if held_experts else (0, n_experts)
-        self.embedding_multiplier = embedding_multiplier
-        self.residual_multiplier = residual_multiplier
-        self.logits_scaling = logits_scaling
-        self.norm = RMSNorm(norm_eps)
-        self.attn = MultiHeadAttention(
-            dim, heads, causal=True, kv_heads=kv_heads, use_bias=False,
-            scale=attention_multiplier,
-        )
-        self.shared = GatedMLP(shared_width)
-        lo, hi = self.held_experts
-        # what `apply_paged`'s counters count, position by position:
-        # (name, label key, label values) -> tpu_dist_serve_<name>_total
-        self.serve_counters = (
-            ("moe_picks", None, ()),
-            ("moe_picks_held", None, ()),
-            ("moe_expert_tokens", "expert", tuple(range(lo, hi))),
-        )
+def _normal(key, *shape):
+    return jax.random.normal(key, shape) * 0.02
 
-    # ------------------------------------------------------------ weights
 
-    def init(self, key=None, input_shape=None):
-        """Seeded weights: normal(0, 0.02) matrices (attention's as
-        `MultiHeadAttention` draws them), unit norms, and the
-        scan's ``A_log`` / ``dt_bias`` / ``D`` as Mamba-2 initialises
-        them (``A`` uniform in [1, 16], ``dt`` log-uniform in [1e-3,
-        1e-1] through the inverse of softplus, ``D`` one)."""
-        del input_shape
-        key = jax.random.key(0) if key is None else key
-        D, E = self.dim, self.n_experts
-        H = self.held_experts[1] - self.held_experts[0]
-        n = lambda k, *shape: jax.random.normal(k, shape) * 0.02  # noqa: E731
-        ones = lambda d: {"scale": jnp.ones((d,))}  # noqa: E731
+class MambaMixer:
+    """``init(key)``; ``dense(p, x) -> y``; ``init_cache(...) -> (pools,
+    per-slot state)``; ``cached(p, x, pools, state, at) -> (y, pools,
+    state, counts)``, the counts in the order of ``counters``."""
 
-        def mixer(kind, k):
-            if kind == "attention":
-                return self.attn.init(k, (1, D))[0]
-            ks = jax.random.split(k, 5)
-            nh, di, ch = self.ssm_heads, self.ssm_inner, self.ssm_channels
-            dt = jnp.exp(jax.random.uniform(ks[3], (nh,)) * math.log(100.0) + math.log(1e-3))
-            return {
-                "in_proj": n(ks[0], D, di + ch + nh),
-                "conv_w": jax.random.normal(ks[1], (ch, self.ssm_conv)) * self.ssm_conv**-0.5,
-                "conv_b": jnp.zeros((ch,)),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                "A_log": jnp.log(jax.random.uniform(ks[4], (nh,), minval=1.0, maxval=16.0)),
-                "D": jnp.ones((nh,)),
-                "norm": ones(di),
-                "out_proj": n(ks[2], di, D),
-            }
+    counters = ()
 
-        def block(kind, k):
-            ks = jax.random.split(k, 6)
-            return {
-                "ln1": ones(D), "mixer": mixer(kind, ks[0]), "ln2": ones(D),
-                "moe": {"router": n(ks[1], D, E),
-                        "w_in": n(ks[2], H, D, 2 * self.expert_width),
-                        "w_out": n(ks[3], H, self.expert_width, D)},
-                "shared": {"w_in": n(ks[4], D, 2 * self.shared.width),
-                           "w_out": n(ks[5], self.shared.width, D)},
-            }
+    def __init__(self, dim: int, norm: RMSNorm, *, heads: int, head_dim: int, state: int,
+                 conv: int = 4, chunk: int = 256):
+        self.dim, self.norm = dim, norm
+        self.heads, self.head_dim, self.state = heads, head_dim, state
+        self.inner = heads * head_dim
+        self.channels = self.inner + 2 * state   # what the convolution sees
+        self.conv, self.chunk = conv, chunk
 
-        k_emb, *k_blocks = jax.random.split(key, len(self.layer_types) + 1)
+    def init(self, key):
+        """``A_log`` / ``dt_bias`` / ``D`` as Mamba-2 initialises them
+        (``A`` uniform in [1, 16], ``dt`` log-uniform in [1e-3, 1e-1]
+        through the inverse of softplus, ``D`` one)."""
+        ks = jax.random.split(key, 5)
+        nh, di, ch = self.heads, self.inner, self.channels
+        dt = jnp.exp(jax.random.uniform(ks[3], (nh,)) * math.log(100.0) + math.log(1e-3))
         return {
-            "embed": {"table": n(k_emb, self.vocab, D)},
-            "blocks": [block(kind, k) for kind, k in zip(self.layer_types, k_blocks)],
-            "ln": ones(D),
-        }, {}
+            "in_proj": _normal(ks[0], self.dim, di + ch + nh),
+            "conv_w": jax.random.normal(ks[1], (ch, self.conv)) * self.conv**-0.5,
+            "conv_b": jnp.zeros((ch,)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(ks[4], (nh,), minval=1.0, maxval=16.0)),
+            "D": jnp.ones((nh,)),
+            "norm": {"scale": jnp.ones((di,))},
+            "out_proj": _normal(ks[2], di, self.dim),
+        }
 
-    # -------------------------------------------------------- the layers
-
-    def _ln(self, p, x):
-        with jax.named_scope("ln"):
-            return self.norm.apply(p, {}, x)[0]
-
-    def _mamba(self, p, x, conv, ssm, mask):
-        """The Mamba-2 mixer over ``x (rows, s, dim)`` from the carried
-        ``conv`` window and ``ssm`` state -> ``(y, conv', ssm')``."""
+    def mix(self, p, x, conv, ssm, mask):
+        """Over ``x (rows, s, dim)`` from the carried ``conv`` window and
+        ``ssm`` state -> ``(y, conv', ssm')``."""
         rows, s, _ = x.shape
-        nh, hd, N, di = self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_inner
+        nh, hd, N, di = self.heads, self.head_dim, self.state, self.inner
         with jax.named_scope("ssm/in_proj"):
             zxbcdt = x @ p["in_proj"]
-            z, xbc, dt = jnp.split(zxbcdt, [di, di + self.ssm_channels], axis=-1)
+            z, xbc, dt = jnp.split(zxbcdt, [di, di + self.channels], axis=-1)
         with jax.named_scope("ssm/conv"):
             xbc, conv = causal_conv(xbc, p["conv_w"], p["conv_b"], conv, mask)
             xs, B, C = jnp.split(jax.nn.silu(xbc), [di, di + N], axis=-1)
@@ -188,31 +137,257 @@ class HybridLM(Module):
                 y, ssm = ssm_step(xs[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], p["D"], ssm,
                                   None if mask is None else mask[:, 0])
             else:
-                y, ssm = ssd_chunked(xs, dt, A, B, C, p["D"], ssm, mask, chunk=self.ssm_chunk)
+                y, ssm = ssd_chunked(xs, dt, A, B, C, p["D"], ssm, mask, chunk=self.chunk)
         with jax.named_scope("ssm/gate_norm"):
             y = y.reshape(rows, s, di) * jax.nn.silu(z.astype(jnp.float32))
             y = self.norm.apply(p["norm"], {}, y)[0].astype(x.dtype)
         with jax.named_scope("ssm/out_proj"):
             return y @ p["out_proj"], conv, ssm
 
-    def _zero_state(self, rows: int):
-        return (jnp.zeros((rows, self.ssm_conv - 1, self.ssm_channels), jnp.float32),
-                jnp.zeros((rows, self.ssm_heads, self.ssm_head_dim, self.ssm_state),
-                          jnp.float32))
+    def zero_state(self, rows: int):
+        return (jnp.zeros((rows, self.conv - 1, self.channels), jnp.float32),
+                jnp.zeros((rows, self.heads, self.head_dim, self.state), jnp.float32))
+
+    def dense(self, p, x):
+        return self.mix(p, x, *self.zero_state(x.shape[0]), None)[0]
+
+    def init_cache(self, max_batch, num_blocks, block_size, dtype):
+        return {}, dict(zip(("conv", "ssm"), self.zero_state(max_batch)))
+
+    def cached(self, p, x, pools, state, at: Paged):
+        with jax.named_scope("ssm/state_rw"):
+            conv, ssm = ((state["conv"], state["ssm"]) if at.slots is None
+                         else (state["conv"][at.slots], state["ssm"][at.slots]))
+            conv = jnp.where(at.fresh[:, None, None], 0.0, conv)
+            ssm = jnp.where(at.fresh[:, None, None, None], 0.0, ssm)
+        y, conv, ssm = self.mix(p, x, conv, ssm, at.write_mask)
+        with jax.named_scope("ssm/state_rw"):
+            if at.slots is not None:
+                conv = state["conv"].at[at.slots].set(conv)
+                ssm = state["ssm"].at[at.slots].set(ssm)
+        return y, {}, {"conv": conv, "ssm": ssm}, ()
+
+
+class GroupedQueryMixer:
+    counters = ()
+
+    def __init__(self, dim: int, norm: RMSNorm, *, heads: int, kv_heads: int,
+                 scale: float | None = None):
+        del norm
+        self.dim = dim
+        self.attn = MultiHeadAttention(dim, heads, causal=True, kv_heads=kv_heads,
+                                       use_bias=False, scale=scale)
+
+    def init(self, key):
+        return self.attn.init(key, (1, self.dim))[0]
+
+    def dense(self, p, x):
+        return self.attn.apply(p, {}, x)[0]
+
+    def init_cache(self, max_batch, num_blocks, block_size, dtype):
+        pool = (num_blocks + 1, block_size, self.attn.kv_heads * self.attn.head_dim)
+        return {"k": jnp.zeros(pool, dtype), "v": jnp.zeros(pool, dtype)}, {}
+
+    def cached(self, p, x, pools, state, at: Paged):
+        from tpu_dist.serve.paged_kv import _paged_attention
+
+        y, k, v = _paged_attention(self.attn, p, x, pools["k"], pools["v"], at.block_tables,
+                                   at.positions, at.write_mask, at.block_size)
+        return y, {"k": k, "v": v}, {}, ()
+
+
+class LatentMixer:
+    """Both latent kinds: one that selects its keys (``index_topk``; pools
+    under the block tables) and one with a ``window`` (a ring a slot, of
+    ``window - 1 + chunk`` rows: ``chunk`` is the most new tokens a call
+    may bring a row)."""
+
+    def __init__(self, dim: int, norm: RMSNorm, *, chunk: int = 1, **sizes):
+        self.attn = LatentAttention(dim, eps=norm.eps, **sizes)
+        windowed = self.attn.window is not None
+        self.ring_rows = self.attn.window - 1 + chunk if windowed else None
+        self.counters = (("swa_rows_attended",) if windowed
+                         else ("dsa_keys_scored", "dsa_rows_selected"))
+
+    def init(self, key):
+        return self.attn.init(key)[0]
+
+    def dense(self, p, x):
+        return self.attn.apply(p, {}, x)[0]
+
+    def init_cache(self, max_batch, num_blocks, block_size, dtype):
+        from tpu_dist.serve.paged_kv import init_latent_cache
+
+        return init_latent_cache(self.attn, max_batch, num_blocks, block_size, dtype,
+                                 self.ring_rows)
+
+    def cached(self, p, x, pools, state, at: Paged):
+        from tpu_dist.serve.paged_kv import _paged_latent_attention, _ring_latent_attention
+
+        if self.attn.window is None:
+            y, pools, counts = _paged_latent_attention(
+                self.attn, p, x, pools, at.block_tables, at.positions, at.write_mask,
+                at.block_size)
+            return y, pools, {}, counts
+        y, ring, attended = _ring_latent_attention(
+            self.attn, p, x, state["ring"], at.positions, at.write_mask, at.slots)
+        return y, {}, {"ring": ring}, (attended,)
+
+
+# layer kind -> the mixer that computes it
+MIXERS = {
+    "mamba": MambaMixer, "attention": GroupedQueryMixer,
+    "full_attention": LatentMixer, "sliding_attention": LatentMixer,
+}
+
+
+class HybridLM(Module):
+    """``mixers``: by layer kind, the sizes its `MIXERS` entry is built
+    with.  The ``ssm_*`` and ``heads`` / ``kv_heads`` /
+    ``attention_multiplier`` arguments are the sizes of ``"mamba"`` and
+    ``"attention"`` under the names their first caller gives them
+    (`chipbench/families/granitemoehybrid.py`); they go when that file
+    passes ``mixers=``."""
+
+    def __init__(
+        self,
+        *,
+        vocab: int,
+        dim: int,
+        layer_types: list[str],
+        heads: int | None = None,
+        kv_heads: int | None = None,
+        ssm_heads: int | None = None,
+        ssm_head_dim: int | None = None,
+        ssm_state: int | None = None,
+        ssm_conv: int = 4,
+        ssm_chunk: int = 256,
+        mixers: dict[str, dict] | None = None,
+        n_experts: int,
+        experts_per_token: int,
+        expert_width: int,
+        shared_width: int,
+        held_experts: tuple[int, int] | None = None,
+        expert_scoring: str = "softmax_of_picks",
+        dense_layers: int = 0,
+        dense_width: int | None = None,
+        tied_head: bool = True,
+        embedding_multiplier: float = 1.0,
+        residual_multiplier: float = 1.0,
+        attention_multiplier: float | None = None,
+        logits_scaling: float = 1.0,
+        norm_eps: float = 1e-5,
+        max_seq: int = 2048,
+    ):
+        unknown = sorted(set(layer_types) - set(MIXERS))
+        if unknown:
+            raise ValueError(f"layer_types of {unknown}; the kinds are {sorted(MIXERS)}")
+        self.vocab, self.dim, self.max_seq = vocab, dim, max_seq
+        self.layer_types = list(layer_types)
+        self.norm = RMSNorm(norm_eps)
+        sizes = {
+            "mamba": dict(heads=ssm_heads, head_dim=ssm_head_dim, state=ssm_state,
+                          conv=ssm_conv, chunk=ssm_chunk),
+            "attention": dict(heads=heads, kv_heads=kv_heads, scale=attention_multiplier),
+            **(mixers or {}),
+        }
+        self.mixers = {kind: MIXERS[kind](dim, self.norm, **sizes[kind])
+                       for kind in dict.fromkeys(self.layer_types)}
+        self.n_experts, self.experts_per_token = n_experts, experts_per_token
+        self.expert_width = expert_width
+        self.held_experts = tuple(held_experts) if held_experts else (0, n_experts)
+        self.expert_scoring = expert_scoring
+        self.dense_layers, self.tied_head = dense_layers, tied_head
+        self.embedding_multiplier = embedding_multiplier
+        self.residual_multiplier = residual_multiplier
+        self.logits_scaling = logits_scaling
+        self.shared = GatedMLP(shared_width)
+        self.mlp = GatedMLP(dense_width) if dense_layers else None
+        lo, hi = self.held_experts
+        # what `apply_paged`'s counters count, position by position:
+        # (name, label key, label values) -> tpu_dist_serve_<name>_total
+        own = [name for mixer in self.mixers.values() for name in mixer.counters]
+        self.serve_counters = (
+            ("moe_picks", None, ()),
+            ("moe_picks_held", None, ()),
+            ("moe_expert_tokens", "expert", tuple(range(lo, hi))),
+            ("moe_experts_hit", None, ()),
+            *((name, None, ()) for name in own),
+        )
+        self._count_at = {name: 3 + hi - lo + i for i, name in enumerate(own)}
+        self._counts = 3 + hi - lo + len(own)
+
+    # ------------------------------------------------------------ weights
+
+    def init(self, key=None, input_shape=None):
+        """Seeded weights: normal(0, 0.02) matrices (grouped-query
+        attention's as `MultiHeadAttention` draws them), unit norms, zero
+        selection bias, each mixer's own as its ``init`` says."""
+        del input_shape
+        key = jax.random.key(0) if key is None else key
+        D, E = self.dim, self.n_experts
+        H = self.held_experts[1] - self.held_experts[0]
+        ones = lambda d: {"scale": jnp.ones((d,))}  # noqa: E731
+
+        def block(at, kind, k):
+            ks = jax.random.split(k, 6)
+            p = {"ln1": ones(D), "mixer": self.mixers[kind].init(ks[0]), "ln2": ones(D)}
+            if at < self.dense_layers:
+                p["mlp"] = {"w_in": _normal(ks[1], D, 2 * self.mlp.width),
+                            "w_out": _normal(ks[2], self.mlp.width, D)}
+                return p
+            p["moe"] = {"router": _normal(ks[1], D, E),
+                        "w_in": _normal(ks[2], H, D, 2 * self.expert_width),
+                        "w_out": _normal(ks[3], H, self.expert_width, D)}
+            if self.expert_scoring == "sigmoid_normalised":
+                p["moe"]["bias"] = jnp.zeros((E,))
+            p["shared"] = {"w_in": _normal(ks[4], D, 2 * self.shared.width),
+                           "w_out": _normal(ks[5], self.shared.width, D)}
+            return p
+
+        k_emb, k_head, *k_blocks = jax.random.split(key, len(self.layer_types) + 2)
+        params = {
+            "embed": {"table": _normal(k_emb, self.vocab, D)},
+            "blocks": [block(at, kind, k)
+                       for at, (kind, k) in enumerate(zip(self.layer_types, k_blocks))],
+            "ln": ones(D),
+        }
+        if not self.tied_head:
+            params["head"] = {"table": _normal(k_head, self.vocab, D)}
+        return params, {}
+
+    # -------------------------------------------------------- the layers
+
+    def _ln(self, p, x):
+        with jax.named_scope("ln"):
+            return self.norm.apply(p, {}, x)[0]
 
     def _experts(self, p, u, mask):
         """Routed experts held here plus the shared expert, over ``u
-        (rows, s, dim)`` -> ``(y, counts (2 + held,))``."""
+        (rows, s, dim)`` -> ``(y, counts (3 + held,))``: picks, picks held,
+        tokens a held expert, held experts given a token (those whose
+        weights the grouped product has to read)."""
         flat = u.reshape(-1, u.shape[-1])
         y, c = routed_experts(
             flat, p["moe"]["router"], p["moe"]["w_in"], p["moe"]["w_out"],
             top_k=self.experts_per_token, held=self.held_experts,
             mask=None if mask is None else mask.reshape(-1),
+            scoring=self.expert_scoring, bias=p["moe"].get("bias"),
         )
         with jax.named_scope("moe/shared"):
             y = y + self.shared.apply(p["shared"], {}, flat)[0]
-        counts = jnp.concatenate([jnp.stack([c["picks"], c["picks_held"]]), c["expert_tokens"]])
+        hit = (c["expert_tokens"] > 0).sum(dtype=jnp.int32)
+        counts = jnp.concatenate([jnp.stack([c["picks"], c["picks_held"]]), c["expert_tokens"],
+                                  hit[None]])
         return y.reshape(u.shape), counts
+
+    def _feed_forward(self, p, u, mask):
+        """The layer's own: dense where its weights are (``counts`` None),
+        else the experts."""
+        if "mlp" not in p:
+            return self._experts(p, u, mask)
+        with jax.named_scope("mlp"):
+            return self.mlp.apply(p["mlp"], {}, u)[0], None
 
     def _block(self, p, h, mixer, mask):
         """One layer, whatever its kind: ``mixer(params, x) -> (y, kept)``
@@ -220,7 +395,7 @@ class HybridLM(Module):
         what it keeps for the next call."""
         y, kept = mixer(p["mixer"], self._ln(p["ln1"], h))
         h = h + self.residual_multiplier * y.astype(h.dtype)
-        f, counts = self._experts(p, self._ln(p["ln2"], h), mask)
+        f, counts = self._feed_forward(p, self._ln(p["ln2"], h), mask)
         return h + self.residual_multiplier * f, kept, counts
 
     def _embed(self, params, tokens):
@@ -230,8 +405,8 @@ class HybridLM(Module):
     def _head(self, params, h):
         with jax.named_scope("lm_head"):
             h = self.norm.apply(params["ln"], {}, h)[0]
-            logits = jnp.einsum("...d,vd->...v", h, params["embed"]["table"],
-                                preferred_element_type=jnp.float32)
+            table = params["embed" if self.tied_head else "head"]["table"]
+            logits = jnp.einsum("...d,vd->...v", h, table, preferred_element_type=jnp.float32)
             return logits / self.logits_scaling
 
     # ------------------------------------------------------------- dense
@@ -241,12 +416,9 @@ class HybridLM(Module):
         float32: every sequence whole, from a zero state, no cache."""
         del train, key
         h = self._embed(params, tokens)
-        dense = {
-            "attention": lambda p, x: (self.attn.apply(p, {}, x)[0], None),
-            "mamba": lambda p, x: (self._mamba(p, x, *self._zero_state(x.shape[0]), None)[0], None),
-        }
         for kind, p in zip(self.layer_types, params["blocks"]):
-            h, _, _ = self._block(p, h, dense[kind], None)
+            dense = self.mixers[kind].dense
+            h, _, _ = self._block(p, h, lambda pm, x, f=dense: (f(pm, x), None), None)
         return self._head(params, h), state
 
     # ----------------------------------------------------------- serving
@@ -254,49 +426,36 @@ class HybridLM(Module):
     def init_serve_cache(self, max_batch: int, num_blocks: int, block_size: int, dtype=None):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
-        pool = (num_blocks + 1, block_size, self.attn.kv_heads * self.attn.head_dim)
-        dt = dtype or jnp.float32
-        kv, state = [], []
-        for kind in self.layer_types:
-            attends = kind == "attention"
-            kv.append({"k": jnp.zeros(pool, dt), "v": jnp.zeros(pool, dt)} if attends else {})
-            state.append({} if attends else dict(zip(("conv", "ssm"), self._zero_state(max_batch))))
-        held = self.held_experts[1] - self.held_experts[0]
-        return {"kv": kv, "state": {"layers": state, "counts": jnp.zeros((2 + held,), jnp.int32)}}
+        kept = [self.mixers[kind].init_cache(max_batch, num_blocks, block_size,
+                                             dtype or jnp.float32)
+                for kind in self.layer_types]
+        counts = jnp.zeros((self._counts,), jnp.int32)
+        return {"kv": [pools for pools, _ in kept],
+                "state": {"layers": [state for _, state in kept], "counts": counts}}
 
     def apply_paged(self, params, tokens, cache, block_tables, positions, write_mask, slots,
                     block_size: int):
-        from tpu_dist.serve.paged_kv import _paged_attention
-
         L = block_tables.shape[1] * block_size
         positions = jnp.clip(positions, 0, L - 1)
         h = self._embed(params, tokens)
         fresh = write_mask[:, 0] & (positions[:, 0] == 0)
+        at = Paged(block_tables, positions, write_mask, slots, block_size, fresh)
         kv, state = [], []
         counts = cache["state"]["counts"]
         for kind, p, ckv, cst in zip(self.layer_types, params["blocks"], cache["kv"],
                                      cache["state"]["layers"]):
-            if kind == "attention":
-                def mixer(pm, x, c=ckv):
-                    y, k, v = _paged_attention(self.attn, pm, x, c["k"], c["v"], block_tables,
-                                               positions, write_mask, block_size)
-                    return y, ({"k": k, "v": v}, {})
-            else:
-                def mixer(pm, x, c=cst):
-                    with jax.named_scope("ssm/state_rw"):
-                        conv, ssm = ((c["conv"], c["ssm"]) if slots is None
-                                     else (c["conv"][slots], c["ssm"][slots]))
-                        conv = jnp.where(fresh[:, None, None], 0.0, conv)
-                        ssm = jnp.where(fresh[:, None, None, None], 0.0, ssm)
-                    y, conv, ssm = self._mamba(pm, x, conv, ssm, write_mask)
-                    with jax.named_scope("ssm/state_rw"):
-                        if slots is not None:
-                            conv = c["conv"].at[slots].set(conv)
-                            ssm = c["ssm"].at[slots].set(ssm)
-                    return y, ({}, {"conv": conv, "ssm": ssm})
-            h, (k_new, s_new), c = self._block(p, h, mixer, write_mask)
+            mixer = self.mixers[kind]
+
+            def cached(pm, x, m=mixer, c=ckv, s=cst):
+                y, pools, kept, own = m.cached(pm, x, c, s, at)
+                return y, (pools, kept, own)
+
+            h, (k_new, s_new, own), c = self._block(p, h, cached, write_mask)
             kv.append(k_new)
             state.append(s_new)
-            counts = counts + c
+            if c is not None:   # the experts' counts lead the vector
+                counts = counts.at[: c.size].add(c)
+            for name, n in zip(mixer.counters, own):
+                counts = counts.at[self._count_at[name]].add(n)
         cache = {"kv": kv, "state": {"layers": state, "counts": counts}}
         return self._head(params, h), cache, counts

@@ -281,6 +281,8 @@ def routed_experts(
     held: tuple[int, int] | None = None,
     mask: jax.Array | None = None,
     activation=jax.nn.silu,
+    scoring: str = "softmax_of_picks",
+    bias: jax.Array | None = None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Top-``top_k`` routed gated experts, the part the held experts give.
 
@@ -293,11 +295,17 @@ def routed_experts(
       held: ``(lo, hi)``, the experts ``lo .. hi - 1`` whose weights these
         are; default all of them.
       mask: ``(T,)``, False for a pad token: its picks do no work.
+      scoring: how picks and gates come from the router's logits ``r = x @
+        router_w``.  ``"softmax_of_picks"``: ``(v, idx) = top_k(r)``, ``g =
+        softmax(v)``.  ``"sigmoid_normalised"`` (DeepSeek-V3's gate with no
+        groups): ``sig = sigmoid(r)``, ``idx = top_k(sig + bias)`` with
+        ``bias (n_experts,)`` a selection bias that does not enter the
+        gate, ``g_j = sig_j / sum over the picks of sig``.
 
     ``y[t] = sum_j g[t, j] * expert_{idx[t, j]}(x[t])`` over the picks
-    with ``idx[t, j]`` held, where ``(v, idx) = top_k(x @ router_w)`` and
-    ``g = softmax(v)`` over ALL ``top_k`` picks, held or not: what the
-    absent experts would add is left out, not renormalised away.
+    with ``idx[t, j]`` held; ``g`` is normalised over ALL ``top_k`` picks,
+    held or not: what the absent experts would add is left out, not
+    renormalised away.
 
     Returns ``(y (T, d), counts)``; ``counts``: int32 ``picks`` (real
     tokens x top_k), ``picks_held`` and ``expert_tokens (H,)``.
@@ -310,8 +318,16 @@ def routed_experts(
     with jax.named_scope("moe/router"):
         scores = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        top_v, top_e = lax.top_k(scores, top_k)
-        gates = jax.nn.softmax(top_v, axis=-1)
+        if scoring == "softmax_of_picks":
+            top_v, top_e = lax.top_k(scores, top_k)
+            gates = jax.nn.softmax(top_v, axis=-1)
+        elif scoring == "sigmoid_normalised":
+            sig = jax.nn.sigmoid(scores)
+            _, top_e = lax.top_k(sig if bias is None else sig + bias.astype(jnp.float32), top_k)
+            top_v = jnp.take_along_axis(sig, top_e, axis=-1)
+            gates = top_v / top_v.sum(axis=-1, keepdims=True)
+        else:
+            raise ValueError(f"scoring {scoring!r}: 'softmax_of_picks' or 'sigmoid_normalised'")
     with jax.named_scope("moe/sort"):
         real = jnp.ones((T,), bool) if mask is None else mask
         local = top_e.reshape(-1) - lo

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist import ops
+from tpu_dist.nn.latent_attention import top_visible
 
 
 class BlockAllocator:
@@ -295,3 +296,133 @@ def paged_apply_cached(lm, params, tokens, cache, block_tables, positions,
         h, _ = lm.ln.apply(params["ln"], {}, h)
         logits = h @ params["embed"]["table"].T
     return logits, new_cache
+
+
+LANES = 128   # the minor tile of the device's layouts
+
+
+def _whole_tiles(width: int) -> int:
+    return -(-width // LANES) * LANES
+
+
+def init_latent_cache(attn, max_batch: int, num_blocks: int, block_size: int,
+                      dtype, ring_rows: int | None = None):
+    """What a `nn.LatentAttention` layer keeps, as ``(pools, per-slot
+    state)``.  A layer that selects its keys: under the engine's block
+    tables a pool of ONE latent row a token, ``ckv (num_blocks + 1,
+    block_size, row)``, and a pool of the indexer's key, ``ik (...,
+    index_dim)``; no state.  A windowed layer: no pool, and a ring of
+    ``ring_rows`` positions a decode slot, ``ring (max_batch, ring_rows,
+    row)``, position ``p`` at row ``p mod ring_rows``: it never holds more
+    of a request, however long.
+
+    ``row`` is the latent row (``kv_rank + rope_dim`` values) rounded up
+    to whole 128-lane tiles, the tail zero: the device pads a minor
+    dimension to that anyway, and where the padding is left to it, it
+    stores an array whose row is no whole number of tiles (576, 1088)
+    with ANOTHER dimension minor-most wherever that pads less, and every
+    program then relayouts every such array on entry and again on exit
+    (the fault `paged_kv`'s folded k/v rows cured; compiled for the v5e
+    at the published sizes: 8 copies of 264 MB and 12 of 27 MB a decode
+    step)."""
+    row = _whole_tiles(attn.row)
+    if attn.window is None:
+        pool = (num_blocks + 1, block_size)
+        return {"ckv": jnp.zeros(pool + (row,), dtype),
+                "ik": jnp.zeros(pool + (attn.index_dim,), dtype)}, {}
+    return {}, {"ring": jnp.zeros((max_batch, ring_rows, row), dtype)}
+
+
+def _padded(rows, width: int):
+    """``rows (..., w)`` with zeros behind, ``width`` wide."""
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, width - rows.shape[-1])])
+
+
+def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
+                            write_mask, block_size: int):
+    """A key-selecting latent layer against its two pools (the contract
+    of `_paged_attention`; ``pools = {"ckv", "ik"}``).  Each new token's
+    latent row and index key are scattered as `_paged_attention` scatters
+    k/v; every query then scores the index keys its slot holds.  ONE
+    query a slot (decode): `lax.top_k` of the scores, those rows of
+    ``ckv`` fetched through the block table, absorbed attention over the
+    fetched rows (a slot that holds fewer than ``index_topk`` masks the
+    rest).  SEVERAL (a prefill chunk): the same picks as a mask over the
+    gathered view, scores and attention walked over it only as far as the
+    call's longest context reaches.  Returns ``(y, pools, (keys scored, rows selected))``,
+    the counts over the real queries."""
+    S, s, _ = x.shape
+    L = block_tables.shape[1] * block_size
+    topk = min(attn.index_topk, L)
+    c_q, q_n, q_r = attn.queries(params, x, positions)
+    rows = attn.rows(params, x, positions)
+    keys = attn.index_keys(params, x, positions)
+    q_i, w = attn.index_queries(params, x, c_q, positions)
+
+    with jax.named_scope("mla/cache_write"):
+        ckv, ik = pools["ckv"], pools["ik"]
+        blk = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
+        blk = jnp.where(write_mask, blk, ckv.shape[0] - 1).reshape(-1)
+        off = (positions % block_size).reshape(-1)
+        rows = _padded(rows.astype(ckv.dtype), ckv.shape[-1])
+        ckv = ckv.at[blk, off].set(rows.reshape(S * s, -1))
+        ik = ik.at[blk, off].set(keys.astype(ik.dtype).reshape(S * s, -1))
+
+    with jax.named_scope("dsa/index"):
+        held_keys = ik[block_tables].reshape(S, L, -1).astype(x.dtype)
+    t = jnp.where(write_mask, positions, -1)      # a masked query sees nothing
+    held = t.max() + 1                            # no row of the call sees a place past it
+    scores = attn.index_scores(q_i, w, held_keys, held)
+    causal = jnp.arange(L)[None, None, :] <= t[:, :, None]
+    if s == 1:
+        with jax.named_scope("dsa/topk"):
+            best, picks = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf)[:, 0], topk)
+            picked = (best > -jnp.inf)[:, None]
+        with jax.named_scope("dsa/gather"):
+            blk = jnp.take_along_axis(block_tables, picks // block_size, axis=1)
+            seen = ckv[blk, picks % block_size][..., :attn.row].astype(x.dtype)
+    else:
+        with jax.named_scope("dsa/topk"):
+            picked = top_visible(scores, causal, topk)
+        with jax.named_scope("dsa/gather"):
+            seen = ckv[block_tables].reshape(S, L, -1)[..., :attn.row].astype(x.dtype)
+    with jax.named_scope("mla/attend"):
+        o = attn.absorbed(params, q_n, q_r, seen, picked, None if s == 1 else held)
+    scored = jnp.where(write_mask, positions + 1, 0)
+    counts = (scored.sum(dtype=jnp.int32), jnp.minimum(scored, topk).sum(dtype=jnp.int32))
+    return attn.output(params, x, o), {"ckv": ckv, "ik": ik}, counts
+
+
+def _ring_latent_attention(attn, params, x, ring, positions, write_mask, slots):
+    """A windowed latent layer against its per-slot ring ``(max_batch, R,
+    row)``; ``slots``: each row's decode slot, None where row ``i`` IS
+    slot ``i``.  The real tokens' rows are written at ``position mod R``
+    (a masked token writes nothing), then every query attends the ring.
+    A ring row is visible by the position it MUST hold, the last ``p <=``
+    the slot's newest with ``p = row (mod R)``: inside a query's window
+    that is a row this request wrote, so a slot's earlier tenant and a
+    wrapped row are never seen and admission resets nothing.  Wants ``R
+    >= window - 1 + s``.  One query a slot attends absorbed, a chunk
+    expanded.  Returns ``(y, ring, rows attended)``."""
+    S, s, _ = x.shape
+    R, W = ring.shape[1], attn.window
+    if R < W - 1 + s:
+        raise ValueError(f"a ring of {R} rows holds a window of {W} and {R - W + 1} "
+                         f"new tokens a call, not {s}")
+    c_q, q_n, q_r = attn.queries(params, x, positions)
+    rows = attn.rows(params, x, positions)
+    with jax.named_scope("swa/ring_rw"):
+        slot = jnp.arange(S) if slots is None else slots
+        at = jnp.where(write_mask, positions % R, R)   # out of range: dropped
+        ring = ring.at[slot[:, None], at].set(
+            _padded(rows.astype(ring.dtype), ring.shape[-1]), mode="drop")
+        seen = (ring if slots is None else ring[slots])[..., :attn.row].astype(x.dtype)
+        t = jnp.where(write_mask, positions, -1)
+        newest = t.max(axis=1, keepdims=True)
+        holds = newest - (newest - jnp.arange(R)[None, :]) % R
+        visible = attn.visible(params, x, c_q, t, holds)
+    with jax.named_scope("swa/attend"):
+        attend = attn.absorbed if s == 1 else attn.expanded
+        o = attend(params, q_n, q_r, seen, visible)
+    attended = jnp.minimum(jnp.where(write_mask, positions + 1, 0), W).sum(dtype=jnp.int32)
+    return attn.output(params, x, o), ring, attended
