@@ -537,10 +537,11 @@ def phase_lm_train_4chip(ctx: Ctx) -> dict:
     say(f"  [1-chip reference, accum 2, flash] first loss "
         f"{ref['first_loss']:.5f} (set-up {ref['setup_s']}s)")
     del ref_trainer
-    # The four-chip layouts run DENSE attention: the engine's step is one
-    # GSPMD program, a Mosaic kernel cannot be partitioned automatically
-    # ("wrap the call in a shard_map"), and `ops.kernel_for_platform`
-    # keeps it out of a program the compiler partitions (PERF.md).
+    # The engine's step is one GSPMD program, which a Mosaic kernel
+    # cannot be part of ("cannot be automatically partitioned"): where
+    # flash takes the length, attention is each chip's share of batch and
+    # heads inside one `shard_map` over the rule set's axes
+    # (`nn.dot_product_attention`, PERF.md section 3), else dense.
     for spec in ("dp=2,fsdp=2", "dp=2,tp=2"):
         mesh = parallel.build_mesh(spec, mesh_devices=devs)
         trainer = train.LMTrainer(

@@ -48,8 +48,13 @@ def kernels_interpreted(monkeypatch):
     kernel, run here.  (The selection itself never interprets: off the
     TPU it takes the caller's plain form.)"""
     from tpu_dist import ops
+    from tpu_dist.nn import attention
 
     monkeypatch.setattr(
         ops, "kernel_for_platform",
         lambda kernel, plain, *operands: kernel(*operands, interpret=True),
     )
+    # what was traced under the other rule is not this test's, nor the next's
+    attention._per_device_attention.cache_clear()
+    yield
+    attention._per_device_attention.cache_clear()

@@ -441,21 +441,28 @@ class TestProgramNamesAndScopes:
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described (not attached) v5e chip: libtpu compiles for it here.
-    Made inside a fixture, never at import: only the worker that runs
-    this file may load the TPU's library."""
+def v5e_devices():
+    """The four described (not attached) chips of a v5e 2x2: libtpu
+    compiles for them here.  Made inside a fixture, never at import: only
+    the worker that runs this file may load the TPU's library."""
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or another process holds its lock
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_devices):
+    """One of them."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_devices[0])
 
 
 # head_dim 64, block_size 16: the two minor dimensions decide.  Where
@@ -594,9 +601,10 @@ class TestDecodeAttendsInThePoolOnTheV5e:
 
 class TestTheTrainerPicksFlashItself:
     """`LMTrainer` with nothing in the environment: the step of one chip
-    holds the three flash kernels where it is lowered for a TPU; the same
-    trainer under ``mesh_axes="fsdp=4"``, which XLA partitions over four
-    devices, lowers too (Mosaic is not asked) and holds none.
+    holds the three flash kernels where it is lowered for a TPU; so does
+    the same trainer under ``mesh_axes="fsdp=4"``, which XLA partitions
+    over four devices: attention is one device's share there, inside one
+    `shard_map` over the axes the engine shards batch and heads by.
 
     The trainer places its state as it is built, so it is built on the
     CPU's devices and its step lowered FOR the TPU from here
@@ -609,7 +617,11 @@ class TestTheTrainerPicksFlashItself:
 
     @pytest.mark.parametrize("mesh_axes,grad_compress,kernels", [
         (None, None, KERNELS),
-        ("fsdp=4", None, ()),
+        ("fsdp=4", None, KERNELS),
+        ("dp=2,fsdp=2", None, KERNELS),
+        # 2 heads over tp=2 divide; the 8 rows of a micro-batch of 4 do
+        # too.  3 heads would not: `test_ops.py`'s rule cases
+        ("dp=2,tp=2", None, KERNELS),
         # the compressed wire is a `shard_map` over the data axes: every
         # axis of an fsdp mesh, so its body is one device's and keeps the
         # kernels; beside a ``tp`` axis the compiler still partitions the
@@ -642,12 +654,15 @@ class TestTheTrainerPicksFlashItself:
         assert ("@tpu_custom_call" in text) == bool(kernels)
 
     @pytest.mark.parametrize("devices,mesh_axes,kernel", [
-        (1, None, True), (4, None, False), (4, "fsdp=4", False)])
+        (1, None, True), (4, None, True), (4, "fsdp=4", True),
+        (4, "dp=2,tp=2", False)])
     def test_evaluation_lowered_for_a_tpu(self, devices, mesh_axes, kernel):
-        """`Trainer.evaluate` shards its batches over the mesh, under the
-        `shard_map` step and under the engine alike: over four devices a
-        program XLA partitions, which must lower (Mosaic is not asked);
-        on one device the kernel.  1024 tokens: a length flash takes."""
+        """`Trainer.evaluate` shards its batches over the mesh's leading
+        axis, under the `shard_map` step and under the engine alike: over
+        four devices a program XLA partitions, which must lower, with the
+        kernel on each device's rows where that axis is the whole mesh and
+        dense beside a ``tp`` axis the batches are not split by; on one
+        device the kernel.  1024 tokens: a length flash takes."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         model = nn.Sequential([
@@ -669,6 +684,142 @@ class TestTheTrainerPicksFlashItself:
             lowering_platforms=("tpu",)).as_text()
         assert ('kernel_name = "flash_fwd"' in text) == kernel
         assert ("@tpu_custom_call" in text) == kernel
+
+    def test_partitioned_attention_is_traced_once_a_step(self):
+        """Four layers, each its own `jax.checkpoint`: the per-device
+        function's Python body runs ONCE while the ``fsdp=4`` step is
+        traced, not once a layer (a `shard_map` traced afresh in each of
+        gpt2-xl's 48 layers cost `train-xl-fsdp4` 24 s of set-up, PR 33),
+        and the text lowered for a TPU holds each kernel's body as often
+        as a model of two layers does.  The builder says what it found."""
+        texts, logs = {}, []
+        for depth in (4, 2):
+            # a trace an earlier step of this process left would be reused
+            # whole, and the count read 0
+            nn.attention._per_device_attention.cache_clear()
+            lm = models.TransformerLM(vocab=64, dim=32, depth=depth, heads=2,
+                                      max_seq=1024, remat=True)
+            mesh = parallel.build_mesh("fsdp=4", mesh_devices=jax.devices()[:4])
+            tr = train.LMTrainer(lm, mesh, train.LMTrainConfig(
+                global_batch=8, mesh_axes="fsdp=4", compute_dtype="bfloat16",
+                log=logs.append))
+            batch = (jnp.zeros((8, 1024), jnp.int32),)
+            assert tr._partition.attention_form() is None  # not traced yet
+            texts[depth] = tr._partition.step.trace(
+                tr.params, tr.opt_state, batch, jax.random.key(0),
+            ).lower(lowering_platforms=("tpu",)).as_text()
+            assert tr._partition.attention_form() == {
+                "form": "flash", "axes": ["fsdp", None],
+                "per_device_shape": [2, 2, 1024, 16], "calls": depth,
+                "per_device_traces": 1}
+        bodies = {d: {k: t.count(f'kernel_name = "{k}"') for k in self.KERNELS}
+                  for d, t in texts.items()}
+        assert bodies[4] == bodies[2], bodies
+        assert all(0 < n <= 2 for n in bodies[4].values()), bodies
+        tr._partition.report_attention(logs.append)
+        tr._partition.report_attention(logs.append)  # once
+        assert [m for m in logs if "attention under the partition engine" in m] == [
+            "attention under the partition engine: flash, batch and heads over "
+            "['fsdp', None], [2, 2, 1024, 16] a device, 2 calls, per-device "
+            "body traced 1 time(s)"]
+
+    def test_one_device_step_is_the_direct_selection(self, monkeypatch):
+        """On one device nothing is wrapped: the step lowers to the text
+        it has with `nn.dot_product_attention` the bare selection between
+        the kernel and the dense form (what it was before the partitioned
+        form existed), but for each kernel's serialised body, which names
+        the Python frames that called it."""
+        import functools
+        import re
+
+        from tpu_dist import ops
+
+        def lowered():
+            lm = models.TransformerLM(vocab=64, dim=32, depth=2, heads=2,
+                                      max_seq=1024, remat=True)
+            mesh = comm.make_mesh(1, ("data",), mesh_devices=jax.devices()[:1])
+            tr = train.LMTrainer(lm, mesh, train.LMTrainConfig(
+                global_batch=8, accum_steps=2, compute_dtype="bfloat16",
+                log=lambda m: None))
+            text = tr._partition.step.trace(
+                tr.params, tr.opt_state, (jnp.zeros((8, 1024), jnp.int32),),
+                jax.random.key(0),
+            ).lower(lowering_platforms=("tpu",)).as_text()
+            return (re.sub(r'backend_config = "[^"]*"', "backend_config = ...", text),
+                    tr._partition.attention_form())
+
+        def bare_selection(q, k, v, *, causal=False, mask=None, window=None,
+                           scale=None):
+            assert ops.flash_attention_takes(q, k, v, mask=mask, scale=scale)
+            return ops.kernel_for_platform(
+                functools.partial(ops.flash_attention, causal=causal, window=window),
+                functools.partial(nn.attention.dense_attention, causal=causal,
+                                  mask=mask, window=window, scale=scale),
+                q, k, v)
+
+        ours, found = lowered()
+        assert found == {
+            "form": "flash", "axes": [], "per_device_shape": [4, 2, 1024, 16],
+            "calls": 2, "per_device_traces": 0}
+        monkeypatch.setattr(nn.attention, "dot_product_attention", bare_selection)
+        assert (ours, None) == lowered()
+        assert ours.count("@tpu_custom_call") >= 3
+
+    def test_step_compiled_for_four_v5e_chips(self, v5e_devices):
+        """Two layers at gpt2-xl's widths under the engine's ``fsdp=4``
+        rules (its specs on the parameters and the gradients, the batch on
+        ``fsdp``, the bfloat16 loss traced as `make_partitioned_train_step`
+        traces it), compiled for the described 2x2: the three kernels on
+        each chip's four rows, no ``(rows, heads, S, S)`` array, and no
+        `all-to-all` under a block.  Without the residual stream pinned to
+        the batch's axes (`TransformerLM.apply`) the partitioner moves it
+        to the feature-sharded layout of the fsdp rule's weights and back
+        around every norm and projection: 46 exchanges of
+        ``bf16[4,4,1024,400]`` and ``[4,4,1024,1200]`` in these two
+        layers.  The two that stay are the embedding's (its table is
+        sharded by features: the looked-up rows change layout once
+        forward and once backward a step, 13 MB)."""
+        import re
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from tpu_dist.models.transformer_lm import lm_loss
+
+        mesh = parallel.build_mesh("fsdp=4", mesh_devices=v5e_devices)
+        rules = parallel.resolve_rules("fsdp=4", mesh)
+        lm = models.TransformerLM(vocab=50257, dim=1600, depth=2, heads=25,
+                                  max_seq=1024, remat=True)
+        shapes = jax.eval_shape(lambda k: lm.init(k)[0], jax.random.key(0))
+        specs = parallel.match_partition_rules(rules.param_rules, shapes, mesh)
+        p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+        said = parallel.partitioned_over(
+            mesh, batch_axes=rules.data_axes, head_axes=rules.model_axes)
+
+        def step(params, tokens):
+            def loss(p):
+                with said:
+                    p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+                    logits, _ = lm.apply(p, {}, tokens)
+                    return lm_loss(logits.astype(jnp.float32), tokens)
+            value, grads = jax.value_and_grad(loss)(params)
+            return value, jax.lax.with_sharding_constraint(grads, p_sh)
+
+        text = jax.jit(step).lower(
+            jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                         shapes, p_sh),
+            jax.ShapeDtypeStruct((16, 1024), jnp.int32,
+                                 sharding=NamedSharding(mesh, rules.batch_spec())),
+        ).compile().as_text()
+        assert said.attention == [("flash", P("fsdp", None), (4, 25, 1024, 64))] * 2
+        assert said.per_device_traces == 1
+        for name in self.KERNELS:
+            assert re.search(rf"^\s*%{name}\S* = .*custom-call\(.*"
+                             r'custom_call_target="tpu_custom_call"', text, re.M), name
+        assert not re.findall(r"\[[\d,]*1024,1024\]", text)
+        exchanges = [line.strip()[:200] for line in text.splitlines()
+                     if re.search(r"= \S+ all-to-all(-start)?\(", line)]
+        assert len(exchanges) <= 2 and all("(embed)" in e for e in exchanges), exchanges
 
     @pytest.mark.parametrize("S", [1024, 2048])
     def test_flash_grads_compiled_for_the_v5e_hold_no_scores(self, v5e_chip, S):

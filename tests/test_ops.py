@@ -474,28 +474,35 @@ def _flash_grads(q, k, v):
     return jax.grad(lambda *a: _attend(*a).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
-def _over_four_devices(partitioned_by, attend=_attend):
-    """``attend`` over a 4-device mesh, batch of 4 sharded: either XLA
-    partitions the program (the builder says so with
-    `parallel.partitioned_over`, as `make_partitioned_train_step` does;
-    ``"nobody says"`` leaves it out), or a `shard_map` body holds one
-    device's share: manual over every axis of the mesh, or over ``dp``
-    alone beside a ``tp`` of size 1 that stays the compiler's."""
+def _over_four_devices(partitioned_by, attend=_attend, shape=(4, 2, 1024, 16),
+                       grid=(4, 1)):
+    """``attend`` over a 4-device mesh ``dp x tp``, the batch sharded over
+    ``dp``: either XLA partitions the program (the builder says so with
+    `parallel.partitioned_over`, as `make_partitioned_train_step` does,
+    naming the axes that split batch and heads, or ``"... of axes it does
+    not name"``; ``"nobody says"`` leaves it out), or a `shard_map` body
+    holds one device's share: manual over every axis of the mesh, or over
+    ``dp`` alone beside a ``tp`` of size 1 that stays the compiler's."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from tpu_dist import parallel
 
-    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1), ("dp", "tp"))
-    q = jax.ShapeDtypeStruct((4, 2, 1024, 16), jnp.float32)
-    sh = NamedSharding(mesh, P("dp"))
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(grid), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct(shape, jnp.float32)
+    # rows that do not divide arrive whole on every device
+    sh = NamedSharding(mesh, P() if shape[0] % grid[0] else P("dp"))
     if partitioned_by in ("shard_map", "shard_map over dp alone"):
         names = {"dp"} if partitioned_by.endswith("alone") else {"dp", "tp"}
         fn = jax.shard_map(attend, mesh=mesh, in_specs=P("dp"),
                            out_specs=P("dp"), axis_names=names,
                            check_vma=False)
-    elif partitioned_by == "the compiler":
+    elif partitioned_by.startswith("the compiler"):
+        axes = ({} if partitioned_by.endswith("does not name") else
+                dict(batch_axes=("dp",), head_axes=("tp",)))
+        said = parallel.partitioned_over(mesh, **axes)
+
         def fn(q, k, v):
-            with parallel.partitioned_over(mesh):
+            with said:
                 return attend(q, k, v)
     else:
         fn = attend
@@ -514,8 +521,13 @@ RULE_CASES = {
         jax.ShapeDtypeStruct((1, 1, 1024, 1024), jnp.bool_)), 0),
     "a model's own scale": (lambda: _lowered(
         lambda q, k, v: _attend(q, k, v, scale=0.5), *_qkv()), 0),
-    "partitioned over several devices": (
-        lambda: _over_four_devices("the compiler"), 0),
+    "partitioned over several devices, of axes it does not name": (
+        lambda: _over_four_devices("the compiler, of axes it does not name"), 0),
+    "partitioned, 6 rows over dp=4": (
+        lambda: _over_four_devices("the compiler", shape=(6, 2, 1024, 16)), 0),
+    "partitioned, 3 heads over tp=2": (
+        lambda: _over_four_devices("the compiler", shape=(4, 3, 1024, 16),
+                                   grid=(2, 2)), 0),
     "a shard_map that leaves an axis, of size 1, to the compiler": (
         lambda: _over_four_devices("shard_map over dp alone"), 0),
     # ... and what keeps the kernel
@@ -523,6 +535,14 @@ RULE_CASES = {
     "eligible, S=2048": (lambda: _lowered(_attend, *_qkv(2048)), 1),
     "eligible, forward and backward": (lambda: _lowered(_flash_grads, *_qkv()), 3),
     "a shard_map body": (lambda: _over_four_devices("shard_map"), 1),
+    # ... and a partitioned program whose builder names the axes that split
+    # batch and heads: one device's share inside a `shard_map` of its own
+    "partitioned over several devices": (
+        lambda: _over_four_devices("the compiler"), 1),
+    "partitioned, heads over tp=2": (
+        lambda: _over_four_devices("the compiler", grid=(2, 2)), 1),
+    "partitioned, forward and backward": (
+        lambda: _over_four_devices("the compiler", _flash_grads), 3),
     # off the TPU the plain form, never the interpreter
     "eligible, lowered for the cpu": (
         lambda: _lowered(_attend, *_qkv(), platform="cpu"), 0),
@@ -543,6 +563,50 @@ def test_attention_picks_its_kernel_where_it_is_lowered(case):
     assert len(names) == kernels
     # the interpreter runs a kernel's grid as a loop; the dense form has none
     assert "stablehlo.while" not in text
+
+
+@pytest.mark.parametrize("mesh_axes,shape,form", [
+    ("fsdp=4", (4, 2, 1024, 16), "flash"),
+    ("dp=2,fsdp=2", (4, 2, 1024, 16), "flash"),
+    ("dp=2,tp=2", (2, 4, 1024, 16), "flash"),
+    # what does not divide stays the dense form, and raises nothing
+    ("fsdp=4", (6, 2, 1024, 16), "dense"),
+    ("dp=2,tp=2", (2, 3, 1024, 16), "dense"),
+])
+def test_partitioned_attention_is_dense_attention(mesh_axes, shape, form, request):
+    """Under the partition engine's rule sets on four devices: value and
+    gradients of `nn.dot_product_attention`, one device's share computed
+    by the kernels (interpreted here) inside its `shard_map`, are
+    `dense_attention`'s; a batch or a head count that an axis does not
+    divide keeps the dense form, which XLA partitions."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_dist import nn, parallel
+
+    if form == "flash":
+        request.getfixturevalue("kernels_interpreted")
+    mesh = parallel.build_mesh(mesh_axes, mesh_devices=jax.devices()[:4])
+    rules = parallel.resolve_rules(mesh_axes, mesh)
+    said = parallel.partitioned_over(
+        mesh, batch_axes=rules.data_axes, head_axes=rules.model_axes)
+
+    def value_and_grads(attend):
+        def loss(q, k, v):
+            return (attend(q, k, v, causal=True) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+    def partitioned(q, k, v):
+        with said:
+            return value_and_grads(nn.dot_product_attention)(q, k, v)
+
+    qkv = [jax.random.normal(k, shape) for k in jax.random.split(jax.random.key(3), 3)]
+    sh = NamedSharding(mesh, rules.batch_spec() if form == "flash" else P())
+    got = jax.jit(partitioned, in_shardings=(sh, sh, sh))(*qkv)
+    want = value_and_grads(nn.attention.dense_attention)(*qkv)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+    assert [a[0] for a in said.attention] == [form]
+    assert said.per_device_traces == (form == "flash")
 
 
 @pytest.mark.parametrize("described_by", ["nobody says",

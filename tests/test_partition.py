@@ -514,6 +514,46 @@ class TestPartitionTelemetry:
         assert ep["mesh"]["rules"] == "dp+fsdp"
         assert ep["mesh"]["axes"] == {"dp": 2, "fsdp": 4}
 
+    @pytest.mark.parametrize("spec,heads,found", [
+        ("fsdp=4", 2, {"form": "flash", "axes": ["fsdp", None],
+                       "per_device_shape": [1, 2, 1024, 16]}),
+        ("dp=2,fsdp=2", 2, {"form": "flash", "axes": [["dp", "fsdp"], None],
+                            "per_device_shape": [1, 2, 1024, 16]}),
+        ("dp=2,tp=2", 2, {"form": "flash", "axes": ["dp", "tp"],
+                          "per_device_shape": [2, 1, 1024, 16]}),
+        # 3 heads over tp=2: what XLA can partition, the dense form
+        ("dp=2,tp=2", 3, {"form": "dense", "axes": [],
+                          "per_device_shape": [4, 3, 1024, 16]}),
+    ])
+    def test_the_first_step_says_what_attention_became(
+            self, spec, heads, found, tmp_path, monkeypatch):
+        """One line through the trainer's ``log`` and one `attention_form`
+        event, after the step that traced the program and not again."""
+        from tpu_dist.observe import events as ev_mod
+
+        monkeypatch.setenv("TPU_DIST_TELEMETRY", str(tmp_path))
+        monkeypatch.delenv("TPU_DIST_RUN_ID", raising=False)
+        nn.attention._per_device_attention.cache_clear()
+        logs = []
+        mesh = part.build_mesh(spec, mesh_devices=jax.devices()[:4])
+        lm = models.TransformerLM(vocab=64, dim=16 * heads, depth=2,
+                                  heads=heads, max_seq=1024)
+        t = train.LMTrainer(lm, mesh, train.LMTrainConfig(
+            mesh_axes=spec, global_batch=4, log=logs.append))
+        batch = (np.zeros((4, 1024), np.int32),)
+        for i in range(2):
+            (t.params, t._model_state, t.opt_state, loss, _) = t.step(
+                t.params, t._model_state, t.opt_state, batch, jax.random.key(i))
+        assert np.isfinite(float(loss))
+        found = {**found, "calls": 2,
+                 "per_device_traces": int(found["form"] == "flash")}
+        recs = [r for r in ev_mod.read_events(str(tmp_path))
+                if r["event"] == "attention_form"]
+        assert [{k: r[k] for k in found} for r in recs] == [found]
+        assert not ev_mod.validate_dir(str(tmp_path))[1]
+        said = [m for m in logs if "attention under the partition engine" in m]
+        assert len(said) == 1 and f": {found['form']}, " in said[0], logs
+
     def test_tpu_top_renders_mesh_column(self, tmp_path, monkeypatch):
         import importlib.util
         import sys as _sys

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist import nn
+from tpu_dist.ops import partitioning
 from tpu_dist.nn.core import Module
 from tpu_dist.models.vit import EncoderBlock
 
@@ -219,6 +220,13 @@ class TransformerLM(Module):
         with the causal mask in every block (use for padded or packed
         batches)."""
         h = self._trunk(params, tokens)
+        # Where XLA partitions the program it is free to move the
+        # residual stream between the batch's layout and the one the
+        # fsdp rule gives the weights' features, around every norm and
+        # projection: 46 `all-to-all` in two layers of gpt2-xl's widths
+        # compiled for four v5e chips, and with it pinned after each
+        # block the embedding's two (PERF.md section 6, PR 36)
+        said = partitioning.partitioned()
         for blk, pb in zip(self.blocks, params["blocks"]):
             def block_fn(pb_, h_, blk=blk):
                 if not self.moe_experts:
@@ -238,6 +246,8 @@ class TransformerLM(Module):
                 h = jax.checkpoint(block_fn)(pb, h)
             else:
                 h = block_fn(pb, h)
+            if said is not None:
+                h = said.pin_to_batch(h)
         with jax.named_scope("lm_head"):
             h, _ = self.ln.apply(params["ln"], {}, h)
             logits = h @ params["embed"]["table"].T

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist import ops
+from tpu_dist.ops import partitioning
 from tpu_dist.nn.core import Module
 from tpu_dist.nn.layers import Dense
 
@@ -59,16 +60,51 @@ def dot_product_attention(
     `ops.flash_attention_takes` these shapes and the program is lowered
     for a TPU, the blockwise Pallas kernel (`tpu_dist.ops.flash_attention`)
     computes it with no (S, S) array; anywhere else `dense_attention`.
+    In a program that XLA partitions over a mesh (traced under
+    `parallel.partitioned_over`) the same choice is made for one device's
+    share, inside a `shard_map` over the axes the builder named for the
+    batch and the heads; where they do not divide these shapes the dense
+    form stays, which the compiler can partition.
     Numerics match to fp tolerance, differentiable either way."""
     dense = functools.partial(
         dense_attention, causal=causal, mask=mask, window=window, scale=scale
     )
     if not ops.flash_attention_takes(q, k, v, mask=mask, scale=scale):
         return dense(q, k, v)
+    said = partitioning.partitioned()
+    spec = said.attention_spec(q.shape) if said is not None else None
+    if spec is not None:
+        return _per_device_attention(causal, window, said.mesh, spec)(q, k, v)
     return ops.kernel_for_platform(
         functools.partial(ops.flash_attention, causal=causal, window=window),
         dense, q, k, v,
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _per_device_attention(causal, window, mesh, spec):
+    """`dot_product_attention` of one device's share of q, k and v, which
+    ``spec`` splits over ``mesh``: ONE jitted function for every call with
+    the same arguments, so that a model's layers after the first, and
+    their `jax.checkpoint`, JVP and transpose, reuse the first's jaxprs
+    (a `shard_map` traced afresh in each of gpt2-xl's 48 layers cost 24 s
+    of set-up: PERF.md section 6, PRs 33 and 36).  Inside, every axis is
+    manual and `ops.kernel_for_platform` takes the kernel by itself."""
+
+    def attention_per_device(q, k, v):
+        said = partitioning.partitioned()
+        if said is not None:
+            said.per_device_traces += 1
+        return ops.kernel_for_platform(
+            functools.partial(ops.flash_attention, causal=causal, window=window),
+            functools.partial(dense_attention, causal=causal, window=window),
+            q, k, v,
+        )
+
+    return jax.jit(jax.shard_map(
+        attention_per_device, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False,
+    ))
 
 
 def dense_attention(

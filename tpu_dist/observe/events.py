@@ -75,6 +75,16 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     "stall": ("what", "timeout_s", "ranks_behind"),
     "preempt": ("signal", "epoch", "step"),
     "warning": ("reason",),
+    # what attention became in a partition-engine step, once, after its
+    # first trace (`PartitionedTrainStep.report_attention`): "flash" |
+    # "dense" | "mixed", the mesh axes its one `shard_map` splits batch
+    # and heads over, one device's (batch, heads, S, d), the calls flash
+    # takes, and how often the per-device body was traced for them (1; a
+    # number that follows the depth costs set-up seconds; 0 where an
+    # earlier step of the process left the trace)
+    "attention_form": (
+        "form", "axes", "per_device_shape", "calls", "per_device_traces",
+    ),
     "print": ("text",),
     "spmd_result": ("spmd_rank", "summary"),
     "bench": ("metric", "value"),

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_dist.ops import partitioning
+
 DATA_AXIS = "data"
 
 
@@ -323,15 +325,26 @@ def make_spmd_train_step(
     return jax.jit(mapped, donate_argnums=(0, 1, 2) if donate else ())
 
 
-def partitioned_over(mesh: Mesh):
+def partitioned_over(
+    mesh: Mesh,
+    *,
+    batch_axes: tuple[str, ...] = (),
+    head_axes: tuple[str, ...] = (),
+) -> partitioning.Partitioned:
     """The context in which the builder of a `jax.jit` whose arguments
-    span ``mesh`` traces what the model computes.  XLA partitions such a
-    program over the mesh, and nothing inside the trace can see that:
-    the context mesh says it (`ops.kernel_for_platform` then keeps a
-    Mosaic kernel, which cannot be partitioned, out of the program).  A
-    `shard_map` says as much by itself; a mesh of one device changes
-    nothing."""
-    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+    span ``mesh`` traces what the model computes (made once, entered
+    wherever the step is traced).  XLA partitions such a program
+    over the mesh, and nothing inside the trace can see that: the
+    context mesh says it (`ops.kernel_for_platform` then keeps a Mosaic
+    kernel, which cannot be partitioned, out of the program).  Nor can
+    the trace see which axes the builder shards the batch over and which
+    the heads: with them `nn.dot_product_attention` computes each
+    device's share inside a `shard_map`, where the kernel is allowed
+    again; without them it stays dense.  A `shard_map` says as much by
+    itself; a mesh of one device changes nothing."""
+    return partitioning.Partitioned(
+        mesh.abstract_mesh, tuple(batch_axes), tuple(head_axes)
+    )
 
 
 def make_train_step_auto(
@@ -360,8 +373,10 @@ def make_train_step_auto(
     repl = NamedSharding(mesh, P())
     sharded = NamedSharding(mesh, P(axis_name))
 
+    said = partitioned_over(mesh, batch_axes=(axis_name,))
+
     def train_step(params, model_state, opt_state, batch, key):
-        with partitioned_over(mesh):
+        with said:
             (loss, (new_state, aux)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params, model_state, batch, key)

@@ -846,9 +846,53 @@ class PartitionedTrainStep:
     # `compress-wire` lint consume
     compress: Any = None
     flat_plan: Any = field(repr=False, default=None)
+    # what the step's trace was told and what attention became under it
+    # (`parallel.partitioned_over`; empty until the step is traced)
+    partitioned: Any = field(repr=False, default=None)
+    _attention_reported: bool = field(repr=False, default=False)
 
     def summary(self) -> dict:
         return partition_summary(self.ruleset, self.mesh)
+
+    def attention_form(self) -> dict | None:
+        """What `nn.dot_product_attention` became in the traced step, for
+        the calls flash takes: the form, the axes one `shard_map` splits
+        batch and heads over, one device's shape, the number of calls and
+        how often the per-device function's body was traced for them
+        (once, whatever the depth: a number that follows the depth is a
+        trace per layer, which costs set-up seconds).  None before the
+        step is traced, and where no call was flash's to take."""
+        seen = self.partitioned.attention if self.partitioned else ()
+        if not seen:
+            return None
+        form, spec, shape = seen[0]
+        return {
+            "form": form if all(s[0] == form for s in seen) else "mixed",
+            "axes": [list(e) if isinstance(e, tuple) else e for e in spec],
+            "per_device_shape": list(shape),
+            "calls": len(seen),
+            "per_device_traces": self.partitioned.per_device_traces,
+        }
+
+    def report_attention(self, log: Callable[[str], None]) -> None:
+        """`attention_form` once, after the step's first call (which
+        traced it): a line through ``log`` and an `observe.events` event
+        of that name; nothing where no call was flash's to take."""
+        if self._attention_reported:
+            return
+        self._attention_reported = True
+        found = self.attention_form()
+        if found is None:
+            return
+        from tpu_dist.observe import events as _events
+
+        _events.from_env().emit("attention_form", **found)
+        log(
+            "attention under the partition engine: {form}, "
+            "batch and heads over {axes}, {per_device_shape} a device, "
+            "{calls} calls, per-device body traced {per_device_traces} "
+            "time(s)".format(**found)
+        )
 
 
 def make_partitioned_train_step(
@@ -976,13 +1020,16 @@ def make_partitioned_train_step(
         return grads, lsum / accum_steps, aux
 
     flat_plan = None
+    said = partitioned_over(
+        mesh, batch_axes=rules.data_axes, head_axes=rules.model_axes
+    )
     if ccfg is None:
 
         def train_step(params, opt_state, batch, key):
             # XLA partitions this program over the mesh, and only this
-            # builder knows (the compressed path's `shard_map` below
-            # says as much by itself)
-            with partitioned_over(mesh):
+            # builder knows that, and by which axes (the compressed
+            # path's `shard_map` below says as much by itself)
+            with said:
                 if accum_steps == 1:
                     (loss, aux), grads = vg(params, batch, key)
                 else:
@@ -1226,4 +1273,5 @@ def make_partitioned_train_step(
         dead_rules=dead,
         compress=ccfg,
         flat_plan=flat_plan,
+        partitioned=said,
     )

@@ -528,6 +528,7 @@ class LMTrainer:
 
             def engine_step(p, ms, os_, batch, key):
                 p2, o2, loss, aux = built.step(p, os_, batch, key)
+                built.report_attention(self.config.log)  # once traced
                 return p2, ms, o2, loss, aux
 
             self.step = engine_step

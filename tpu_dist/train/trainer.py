@@ -340,6 +340,7 @@ class Trainer:
 
             def engine_step(p, ms, os_, batch, key):
                 p2, o2, loss, aux = built.step(p, os_, batch, key)
+                built.report_attention(self.config.log)  # once traced
                 return p2, ms, o2, loss, aux
 
             self.step = engine_step
@@ -357,9 +358,12 @@ class Trainer:
                 "all_reduce"
             )
 
+        # batches are sharded over the mesh's leading axis (`evaluate`):
+        # a partitioned program
+        said = parallel.partitioned_over(mesh, batch_axes=mesh.axis_names[:1])
+
         def eval_apply(params, state, x):
-            # batches are sharded over the mesh: a partitioned program
-            with parallel.partitioned_over(mesh):
+            with said:
                 return model.apply(params, state, x, train=False)[0]
 
         self._eval_apply = jax.jit(eval_apply)
