@@ -891,6 +891,57 @@ class TestLatentCachesAreNotRelayoutOnTheV5e:
         assert not copies, "\n".join(copies[:4])
 
 
+@pytest.fixture(scope="module")
+def v5e_gated_engine():
+    """Gated grouped-query layers with the published head layout (six query
+    heads to a K/V head of 128, a head size that is not ``dim / heads``): a
+    windowed one, whose ring of 33 - 1 + 16 = 48 rows is three blocks a
+    slot, and a full one in the paged pool."""
+    from tpu_dist import serve
+
+    sizes = dict(heads=12, kv_heads=2, head_dim=128)
+    lm = models.HybridLM(
+        vocab=128, dim=128, layer_types=["gated_sliding_attention", "gated_attention"],
+        mixers={"gated_attention": sizes,
+                "gated_sliding_attention": dict(sizes, window=33, chunk=16)},
+        n_experts=8, experts_per_token=2, expert_width=32, shared_width=32,
+        held_experts=(0, 4), expert_scoring="sigmoid_normalised", route_scale=2.448,
+        dense_layers=1, dense_width=64, tied_head=False, embedding_multiplier=128 ** 0.5,
+        sandwich_norms=True, max_seq=64)
+    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), lm.init(jax.random.key(0))[0])
+    return serve.ServeEngine(lm, params, serve.ServeConfig(
+        max_batch=4, block_size=16, num_blocks=256, max_seq=64, prefill_chunk=16,
+        prefill_batch=2))
+
+
+class TestTheRingsAreReadThroughTheKernelOnTheV5e:
+    """A windowed grouped-query layer's rings lie in one pair of arrays of
+    the pool's layout (`serve.paged_kv.init_ring_cache`), so compiled for
+    the v5e the decode program reads them through the same
+    `paged_attn_decode` kernel as the full layer's pool, under a table that
+    wraps, and neither kind of cache is copied or relayouted."""
+
+    @pytest.mark.parametrize("program,rows,kernels", [("serve_decode_greedy", None, 2),
+                                                      ("serve_prefill", 2, 0)])
+    def test_a_kernel_a_layer_and_no_cache_sized_copy(self, v5e_gated_engine, v5e_chip,
+                                                      program, rows, kernels):
+        import math
+        import re
+
+        text, _, cache = _compiled_for_the_v5e(v5e_gated_engine, v5e_chip, program, rows)
+        kept = {"pool": cache["kv"][1]["k"].shape, "ring": cache["state"]["layers"][0]["k"].shape}
+        assert kept == {"pool": (257, 16, 256), "ring": (4 * 3 + 1, 16, 256)}
+        calls = re.findall(r"^\s*%paged_attn_decode\S* = .*custom-call\(.*"
+                           r'custom_call_target="tpu_custom_call"', text, re.M)
+        assert len(calls) == kernels
+        copy_of = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(")
+        sizes = {math.prod(shape) for shape in kept.values()}
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if (m := copy_of.search(line))
+                  and math.prod(int(d) for d in m.group(1).split(",") if d) in sizes]
+        assert not copies, "\n".join(copies[:4])
+
+
 def test_the_environment_decides_nothing_that_is_compiled():
     """The ``TPU_DIST_*`` names `tpu_dist/` knows are a deployment's: where
     telemetry, metrics and dumps go, where the data is, how processes find

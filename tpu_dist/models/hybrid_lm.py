@@ -10,6 +10,9 @@ shared expert.
     logits = RMSNorm(h_L) @ W_head^T / logits_scaling    (float32)
 
 ``W_head`` is the embedding table (``tied_head``) or a matrix of its own.
+With ``sandwich_norms`` each sublayer's OUTPUT is normed too, four gains a
+layer: ``h <- h + RMSNorm(mixer_l(RMSNorm(h)))`` and the same around the
+feed-forward.
 
 **The mixers** (`MIXERS`; each offers its weights, its dense form, what
 it keeps between calls and its cached form to the ONE block function):
@@ -23,6 +26,13 @@ it keeps between calls and its cached form to the ONE block function):
 - ``"attention"``: bias-free grouped-query attention with no positions,
   scores scaled by ``attention_multiplier``, causal.  Keeps a ``{"k",
   "v"}`` pool of `serve.paged_kv`'s layout.
+- ``"gated_attention"``, ``"gated_sliding_attention"``: grouped-query
+  attention with a head size of its own, an RMSNorm over each head's query
+  and key and an elementwise sigmoid gate of the layer's input on the
+  output; the first with no positions, over the whole context, keeping a
+  ``{"k", "v"}`` pool as ``"attention"`` does; the second with rope and a
+  window, keeping no pool but a ``{"k", "v"}`` ring of ``window - 1 +
+  chunk`` positions a decode slot (`serve.paged_kv.init_ring_cache`).
 - ``"full_attention"``, ``"sliding_attention"``: `nn.LatentAttention`
   (low-rank queries and keys/values, a rope part shared by the heads, a
   gate a head), the first with the learned top-k selection of its keys,
@@ -196,6 +206,48 @@ class GroupedQueryMixer:
         return y, {"k": k, "v": v}, {}, ()
 
 
+class GatedQueryMixer(GroupedQueryMixer):
+    """Both gated grouped-query kinds: one over the whole context with no
+    positions (a pool under the block tables) and one with a ``window``
+    and rope (a ring a slot, which holds the window and the ``chunk`` new
+    tokens a call may bring a row)."""
+
+    def __init__(self, dim: int, norm: RMSNorm, *, heads: int, kv_heads: int, head_dim: int,
+                 window: int | None = None, chunk: int = 1):
+        self.dim, self.chunk = dim, chunk
+        self.attn = MultiHeadAttention(
+            dim, heads, causal=True, kv_heads=kv_heads, use_bias=False, head_dim=head_dim,
+            qk_norm=norm.eps, gated=True, use_rope=window is not None, sliding_window=window)
+        self.counters = (("attn_rows_attended",) if window is None
+                         else ("swa_rows_attended", "swa_rows_unwindowed"))
+
+    def init_cache(self, max_batch, num_blocks, block_size, dtype):
+        from tpu_dist.serve.paged_kv import init_ring_cache
+
+        if self.attn.sliding_window is None:
+            return super().init_cache(max_batch, num_blocks, block_size, dtype)
+        return {}, init_ring_cache(self.attn, max_batch, block_size, dtype, self.chunk)
+
+    def cached(self, p, x, pools, state, at: Paged):
+        from tpu_dist.serve.paged_kv import _paged_attention, ring_blocks, ring_tables
+
+        W = self.attn.sliding_window
+        held = jnp.where(at.write_mask, at.positions + 1, 0)   # places each real query sees
+        if W is None:
+            y, k, v = _paged_attention(self.attn, p, x, pools["k"], pools["v"], at.block_tables,
+                                       at.positions, at.write_mask, at.block_size)
+            return y, {"k": k, "v": v}, {}, (held.sum(dtype=jnp.int32),)
+        if x.shape[1] > self.chunk:
+            raise ValueError(f"a ring that holds a window of {W} and {self.chunk} new tokens "
+                             f"a call, not {x.shape[1]}")
+        rows, max_blocks = at.block_tables.shape
+        tables = ring_tables(at.slots, rows, ring_blocks(W, self.chunk, at.block_size), max_blocks)
+        y, k, v = _paged_attention(self.attn, p, x, state["k"], state["v"], tables, at.positions,
+                                   at.write_mask, at.block_size, ("swa/ring_rw", "swa/attend"))
+        return y, {}, {"k": k, "v": v}, (jnp.minimum(held, W).sum(dtype=jnp.int32),
+                                         held.sum(dtype=jnp.int32))
+
+
 class LatentMixer:
     """Both latent kinds: one that selects its keys (``index_topk``; pools
     under the block tables) and one with a ``window`` (a ring a slot, of
@@ -237,6 +289,7 @@ class LatentMixer:
 # layer kind -> the mixer that computes it
 MIXERS = {
     "mamba": MambaMixer, "attention": GroupedQueryMixer,
+    "gated_attention": GatedQueryMixer, "gated_sliding_attention": GatedQueryMixer,
     "full_attention": LatentMixer, "sliding_attention": LatentMixer,
 }
 
@@ -269,6 +322,7 @@ class HybridLM(Module):
         shared_width: int,
         held_experts: tuple[int, int] | None = None,
         expert_scoring: str = "softmax_of_picks",
+        route_scale: float = 1.0,
         dense_layers: int = 0,
         dense_width: int | None = None,
         tied_head: bool = True,
@@ -276,6 +330,7 @@ class HybridLM(Module):
         residual_multiplier: float = 1.0,
         attention_multiplier: float | None = None,
         logits_scaling: float = 1.0,
+        sandwich_norms: bool = False,
         norm_eps: float = 1e-5,
         max_seq: int = 2048,
     ):
@@ -296,7 +351,8 @@ class HybridLM(Module):
         self.n_experts, self.experts_per_token = n_experts, experts_per_token
         self.expert_width = expert_width
         self.held_experts = tuple(held_experts) if held_experts else (0, n_experts)
-        self.expert_scoring = expert_scoring
+        self.expert_scoring, self.route_scale = expert_scoring, route_scale
+        self.sandwich_norms = sandwich_norms
         self.dense_layers, self.tied_head = dense_layers, tied_head
         self.embedding_multiplier = embedding_multiplier
         self.residual_multiplier = residual_multiplier
@@ -332,6 +388,8 @@ class HybridLM(Module):
         def block(at, kind, k):
             ks = jax.random.split(k, 6)
             p = {"ln1": ones(D), "mixer": self.mixers[kind].init(ks[0]), "ln2": ones(D)}
+            if self.sandwich_norms:
+                p.update(ln1_out=ones(D), ln2_out=ones(D))
             if at < self.dense_layers:
                 p["mlp"] = {"w_in": _normal(ks[1], D, 2 * self.mlp.width),
                             "w_out": _normal(ks[2], self.mlp.width, D)}
@@ -372,7 +430,7 @@ class HybridLM(Module):
             flat, p["moe"]["router"], p["moe"]["w_in"], p["moe"]["w_out"],
             top_k=self.experts_per_token, held=self.held_experts,
             mask=None if mask is None else mask.reshape(-1),
-            scoring=self.expert_scoring, bias=p["moe"].get("bias"),
+            scoring=self.expert_scoring, bias=p["moe"].get("bias"), scale=self.route_scale,
         )
         with jax.named_scope("moe/shared"):
             y = y + self.shared.apply(p["shared"], {}, flat)[0]
@@ -392,10 +450,15 @@ class HybridLM(Module):
     def _block(self, p, h, mixer, mask):
         """One layer, whatever its kind: ``mixer(params, x) -> (y, kept)``
         is the layer's mixer in its dense or its cached form, ``kept``
-        what it keeps for the next call."""
+        what it keeps for the next call.  Both residual forms: a norm
+        before each sublayer, and with ``sandwich_norms`` one after it."""
         y, kept = mixer(p["mixer"], self._ln(p["ln1"], h))
+        if self.sandwich_norms:
+            y = self._ln(p["ln1_out"], y)
         h = h + self.residual_multiplier * y.astype(h.dtype)
         f, counts = self._feed_forward(p, self._ln(p["ln2"], h), mask)
+        if self.sandwich_norms:
+            f = self._ln(p["ln2_out"], f)
         return h + self.residual_multiplier * f, kept, counts
 
     def _embed(self, params, tokens):

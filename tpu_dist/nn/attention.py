@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from tpu_dist import ops
 from tpu_dist.ops import partitioning
 from tpu_dist.nn.core import Module
-from tpu_dist.nn.layers import Dense
+from tpu_dist.nn.layers import Dense, RMSNorm
 
 
 def dot_product_attention(
@@ -187,7 +187,13 @@ class MultiHeadAttention(Module):
     layer is exactly the classic fused-QKV MHA, param structure and all.
     ``use_bias=False`` drops the projections' biases; ``scale`` replaces
     the ``head_dim ** -0.5`` on the scores (an explicit attention
-    multiplier).
+    multiplier).  ``head_dim`` is a head's size where it is not ``dim //
+    heads`` (the projections are then ``heads * head_dim`` wide and only
+    the output is ``dim``).  ``qk_norm=eps`` puts an RMSNorm over each
+    head's query and key, one gain of ``head_dim`` values for all query
+    heads and one for all key heads, before the rotation.  ``gated``
+    multiplies the attention's output, before the output projection, by
+    ``sigmoid(x @ W_g)`` of the layer's own input, elementwise.
     """
 
     def __init__(
@@ -201,12 +207,16 @@ class MultiHeadAttention(Module):
         sliding_window: int | None = None,
         use_bias: bool = True,
         scale: float | None = None,
+        head_dim: int | None = None,
+        qk_norm: float | None = None,
+        gated: bool = False,
     ):
-        if dim % heads:
+        if head_dim is None and dim % heads:
             raise ValueError(f"dim {dim} not divisible by heads {heads}")
         self.dim = dim
         self.heads = heads
-        self.head_dim = dim // heads
+        self.head_dim = dim // heads if head_dim is None else head_dim
+        self.inner = heads * self.head_dim   # what the heads hold together
         self.scale = self.head_dim**-0.5 if scale is None else scale
         self.causal = causal
         self.use_rope = use_rope
@@ -226,36 +236,61 @@ class MultiHeadAttention(Module):
         self.sliding_window = sliding_window
         self.group = heads // self.kv_heads
         if self.group == 1:
-            self._qkv = Dense(3 * dim, use_bias=use_bias)
+            self._qkv = Dense(3 * self.inner, use_bias=use_bias)
         else:
-            self._q = Dense(dim, use_bias=use_bias)
+            self._q = Dense(self.inner, use_bias=use_bias)
             self._kv = Dense(2 * self.kv_heads * self.head_dim, use_bias=use_bias)
         self._out = Dense(dim, use_bias=use_bias)
+        self._norm = None if qk_norm is None else RMSNorm(qk_norm)
+        self._gate = Dense(self.inner, use_bias=False) if gated else None
 
     def init(self, key, input_shape):
         k1, k2, k3 = jax.random.split(key, 3)
-        po, _ = self._out.init(k3, input_shape[:-1] + (self.dim,))
+        p = {"out": self._out.init(k3, input_shape[:-1] + (self.inner,))[0]}
         if self.group == 1:
-            pq, _ = self._qkv.init(k1, input_shape)
-            return {"qkv": pq, "out": po}, {}
-        pq, _ = self._q.init(k1, input_shape)
-        pkv, _ = self._kv.init(k2, input_shape)
-        return {"q": pq, "kv": pkv, "out": po}, {}
+            p["qkv"] = self._qkv.init(k1, input_shape)[0]
+        else:
+            p["q"] = self._q.init(k1, input_shape)[0]
+            p["kv"] = self._kv.init(k2, input_shape)[0]
+        if self._norm is not None:
+            p["q_norm"] = {"scale": jnp.ones((self.head_dim,))}
+            p["k_norm"] = {"scale": jnp.ones((self.head_dim,))}
+        if self._gate is not None:
+            p["gate"] = self._gate.init(jax.random.fold_in(key, 3), input_shape)[0]
+        return p, {}
 
     def _project(self, params, x):
-        """-> q (b, heads, s, hd), k/v (b, kv_heads, s, hd)."""
+        """-> q (b, heads, s, hd), k/v (b, kv_heads, s, hd), q and k
+        normed where the layer norms them."""
         b, s, _ = x.shape
         if self.group == 1:
             qkv, _ = self._qkv.apply(params["qkv"], {}, x)
             qkv = qkv.reshape(b, s, 3, self.heads, self.head_dim)
             q, k, v = (jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3))
-            return q, k, v
-        q, _ = self._q.apply(params["q"], {}, x)
-        q = jnp.moveaxis(q.reshape(b, s, self.heads, self.head_dim), 1, 2)
-        kv, _ = self._kv.apply(params["kv"], {}, x)
-        kv = kv.reshape(b, s, 2, self.kv_heads, self.head_dim)
-        k, v = (jnp.moveaxis(kv[:, :, i], 1, 2) for i in range(2))
+        else:
+            q, _ = self._q.apply(params["q"], {}, x)
+            q = jnp.moveaxis(q.reshape(b, s, self.heads, self.head_dim), 1, 2)
+            kv, _ = self._kv.apply(params["kv"], {}, x)
+            kv = kv.reshape(b, s, 2, self.kv_heads, self.head_dim)
+            k, v = (jnp.moveaxis(kv[:, :, i], 1, 2) for i in range(2))
+        if self._norm is not None:
+            with jax.named_scope("attn/qk_norm"):
+                q = self._norm.apply(params["q_norm"], {}, q)[0]
+                k = self._norm.apply(params["k_norm"], {}, k)[0]
         return q, k, v
+
+    def _output(self, params, x, o):
+        """``o (b, heads, s, hd)`` -> the layer's output ``(b, s, dim)``:
+        the heads side by side, the gate of the layer's input ``x`` where
+        it has one, the output projection."""
+        b, s, _ = x.shape
+        o = jnp.moveaxis(o, 1, 2).reshape(b, s, self.inner)
+        if self._gate is not None:
+            with jax.named_scope("attn/gate"):
+                g, _ = self._gate.apply(params["gate"], {}, x)
+                o = (o * jax.nn.sigmoid(g.astype(jnp.float32))).astype(o.dtype)
+        y, _ = self._out.apply(params["out"], {}, o)
+        return y
 
     def _expand_kv(self, t, axis: int = 1):
         """Repeat each kv head (on ``axis``) across its query-head group
@@ -281,9 +316,7 @@ class MultiHeadAttention(Module):
             causal=self.causal, mask=mask, window=self.sliding_window,
             scale=self.scale,
         )
-        o = jnp.moveaxis(o, 1, 2).reshape(b, s, self.dim)
-        y, _ = self._out.apply(params["out"], {}, o)
-        return y, state
+        return self._output(params, x, o), state
 
     def apply_cached(self, params, x, k_cache, v_cache, index):
         """Incremental (KV-cache) forward for autoregressive decode.
@@ -338,9 +371,7 @@ class MultiHeadAttention(Module):
             weights,
             self._expand_kv(v_cache).astype(q.dtype),
         )
-        o = jnp.moveaxis(o, 1, 2).reshape(b, s, self.dim)
-        y, _ = self._out.apply(params["out"], {}, o)
-        return y, k_cache, v_cache
+        return self._output(params, x, o), k_cache, v_cache
 
 
 def sliding_window_mask(seq: int, window: int) -> jax.Array:

@@ -57,10 +57,9 @@ from jax import lax
 
 from tpu_dist.nn.core import Module
 from tpu_dist.nn.layers import RMSNorm
+from tpu_dist.ops import SCORE_BYTES
 
 F32 = jnp.float32
-# bytes of float32 scores one product may hold before it is walked in parts
-SCORE_BYTES = 1 << 29
 INDEX_EPS = 1e-6   # of the LayerNorm over the indexer's key
 
 

@@ -29,6 +29,10 @@ from tpu_dist.ops.matmul import matmul
 from tpu_dist.ops.paged_attention import paged_attention_decode
 from tpu_dist.ops.pallas_ring import ring_all_reduce_pallas
 
+# bytes of float32 scores one plain attention product may hold before its
+# caller walks the keys in parts (`nn.latent_attention`, `serve.paged_kv`)
+SCORE_BYTES = 1 << 29
+
 
 def _partitioned_by_compiler() -> bool:
     """Whether the computation being traced is one that XLA's SPMD

@@ -41,6 +41,7 @@ from jax import lax
 from tpu_dist.comm.collectives import all_to_all
 
 EXPERT_AXIS = "expert"
+LEAD_ROWS = 128   # `routed_experts` hands the grouped product whole tiles of this many picks
 
 
 def capacity_for(tokens_per_rank: int, n_experts: int, factor: float = 1.25) -> int:
@@ -283,6 +284,7 @@ def routed_experts(
     activation=jax.nn.silu,
     scoring: str = "softmax_of_picks",
     bias: jax.Array | None = None,
+    scale: float = 1.0,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Top-``top_k`` routed gated experts, the part the held experts give.
 
@@ -301,6 +303,8 @@ def routed_experts(
         groups): ``sig = sigmoid(r)``, ``idx = top_k(sig + bias)`` with
         ``bias (n_experts,)`` a selection bias that does not enter the
         gate, ``g_j = sig_j / sum over the picks of sig``.
+      scale: what every gate is multiplied by after that (a model's routed
+        scaling factor; the shared expert is the caller's and not scaled).
 
     ``y[t] = sum_j g[t, j] * expert_{idx[t, j]}(x[t])`` over the picks
     with ``idx[t, j]`` held; ``g`` is normalised over ALL ``top_k`` picks,
@@ -328,6 +332,8 @@ def routed_experts(
             gates = top_v / top_v.sum(axis=-1, keepdims=True)
         else:
             raise ValueError(f"scoring {scoring!r}: 'softmax_of_picks' or 'sigmoid_normalised'")
+        if scale != 1.0:
+            gates = gates * scale
     with jax.named_scope("moe/sort"):
         real = jnp.ones((T,), bool) if mask is None else mask
         local = top_e.reshape(-1) - lo
@@ -337,11 +343,28 @@ def routed_experts(
         sizes = jnp.zeros((H + 1,), jnp.int32).at[group].add(1)[:H]
         undo = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
     with jax.named_scope("moe/experts"):
-        rows = x[order // top_k]
-        ab = lax.ragged_dot(rows, w_in, sizes)
         width = w_out.shape[1]
-        hidden = (activation(ab[:, :width]) * ab[:, width:]).astype(x.dtype)
-        out = lax.ragged_dot(hidden, w_out, sizes)
+
+        def grouped(tokens):
+            """The held experts over the picks' rows in sorted order, as
+            many of them as ``tokens`` names: the held picks lead."""
+            ab = lax.ragged_dot(x[tokens], w_in, sizes)
+            hidden = (activation(ab[:, :width]) * ab[:, width:]).astype(x.dtype)
+            return lax.ragged_dot(hidden, w_out, sizes)
+
+        # the grouped product's time follows the rows it is HANDED, not the
+        # rows its groups cover (PERF.md section 6, PR 38): where a small
+        # share of the experts is held, hand it twice that share of the
+        # picks, and all of them only when more than that landed here
+        tokens, n = order // top_k, order.size
+        lead = LEAD_ROWS * math.ceil(2 * n * H / (router_w.shape[1] * LEAD_ROWS))
+        if n < 4 * LEAD_ROWS or 2 * lead > n:
+            out = grouped(tokens)
+        else:
+            out = lax.cond(
+                sizes.sum() <= lead,
+                lambda: jnp.pad(grouped(tokens[:lead]), ((0, n - lead), (0, 0))),
+                lambda: grouped(tokens))
     with jax.named_scope("moe/combine"):
         # rows past the last group were never computed: take none of them
         weight = jnp.where(here, gates.reshape(-1), 0.0)

@@ -58,6 +58,14 @@ import jax.numpy as jnp
 
 from tpu_dist import ops
 from tpu_dist.nn.latent_attention import top_visible
+from tpu_dist.ops import SCORE_BYTES
+
+# keys a step of `_walked_attention` fetches a row.  One call of a layer on
+# the v5e at 48 query heads over 8 K/V heads of 128, a chunk of 256 (PERF.md
+# section 6, PR 38), by 256 | 512 | 1024 | 2048 keys a step: one row at a
+# context of 2k 0.34 | 0.30 | 0.36 | 0.32 ms, at 24k 2.24 | 2.15 | 1.86 |
+# 1.73 ms; four rows at 2k 0.88 | 0.97 | 1.97 | 2.15 ms
+WALK_TOKENS = 512
 
 
 class BlockAllocator:
@@ -174,6 +182,57 @@ def _gathered_attention(q, k_pool, v_pool, block_tables, positions, *,
         return jnp.einsum("bhqk,bkhd->bhqd", weights, v_full)
 
 
+def _walked_attention(q, k_pool, v_pool, block_tables, positions, *,
+                      sliding_window):
+    """`_gathered_attention` where a chunk's scores over a whole table's
+    view would not fit (a context of tens of thousands): the keys are
+    fetched through the tables `WALK_TOKENS` a step and attended under a
+    running maximum, each row from the first block its window reaches (the
+    table's first without one) and all rows as far as the call's longest
+    context, so a chunk reads what it attends and not what a table could
+    hold.  Scores and statistics are float32; a K/V head is read once for
+    its group of query heads."""
+    S, heads, s, hd = q.shape
+    bs = k_pool.shape[1]
+    kv_heads = k_pool.shape[2] // hd
+    MB = block_tables.shape[1]
+    nb = max(1, min(WALK_TOKENS // bs, MB))    # pool blocks a step
+    T = nb * bs
+    q = q.reshape(S, kv_heads, heads // kv_heads, s, hd)
+    qpos = positions[:, None, None, :, None]
+    first = (jnp.zeros((S,), jnp.int32) if sliding_window is None else
+             jnp.maximum(positions.min(axis=1) - sliding_window + 1, 0) // T)
+    steps = ((positions.max(axis=1) + T) // T - first).max()
+
+    def walk(i, carry):
+        top, total, acc = carry
+        j = (first + i)[:, None] * nb + jnp.arange(nb)
+        ids = jnp.take_along_axis(block_tables, jnp.minimum(j, MB - 1), axis=1)
+        k = k_pool[ids].reshape(S, T, kv_heads, hd).astype(q.dtype)
+        v = v_pool[ids].reshape(S, T, kv_heads, hd).astype(q.dtype)
+        # a block past the table's end is some other block read again: its
+        # places lie past every query's, so none is seen
+        pos_k = ((first + i) * T)[:, None, None, None, None] + jnp.arange(T)
+        seen = pos_k <= qpos
+        if sliding_window is not None:
+            seen = seen & (pos_k > qpos - sliding_window)
+        logits = jnp.einsum("bgrqd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32)
+        logits = jnp.where(seen, logits, -1e30)
+        new_top = jnp.maximum(top, logits.max(axis=-1))
+        e = jnp.where(seen, jnp.exp(logits - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "bgrqk,bkgd->bgrqd", e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return new_top, total * keep + e.sum(axis=-1), acc
+
+    lead = q.shape[:-1]
+    start = (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+             jnp.zeros(q.shape, jnp.float32))
+    _, total, acc = jax.lax.fori_loop(0, steps, walk, start)
+    o = acc / jnp.maximum(total, 1e-30)[..., None]
+    return o.reshape(S, heads, s, hd).astype(q.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("sliding_window",))
 def _attend_in_pool(q, k_pool, v_pool, block_tables, lengths, *,
                     sliding_window):
@@ -205,8 +264,11 @@ def _attend_in_pool(q, k_pool, v_pool, block_tables, lengths, *,
 
 
 def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
-                     positions, write_mask, block_size: int):
-    """One block's incremental attention against the paged pool.
+                     positions, write_mask, block_size: int,
+                     scopes=("attn/kv_scatter", "attn/scores")):
+    """One block's incremental attention against the paged pool (or a
+    layer's rings laid out as one: `init_ring_cache`; ``scopes`` names the
+    write and the read on the device).
 
     ``x``: ``(S, s, dim)`` new-token activations for S slots;
     ``positions``: ``(S, s)`` global positions; ``write_mask``:
@@ -216,14 +278,16 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
     `MultiHeadAttention.apply_cached`, with the contiguous cache
     replaced by the scatter and, on the read side, `_attend_in_pool`
     (``s == 1``: the decode kernel on a TPU) or the gathered view
-    (``s > 1``)."""
+    (``s > 1``; walked in parts where its scores would pass
+    `SCORE_BYTES`)."""
     S, s, _ = x.shape
+    write, read = scopes
     with jax.named_scope("attn/qkv"):
         q, k, v = attn._project(params, x)
         if attn.use_rope:
             q, k = _rope_slots(q, positions), _rope_slots(k, positions)
 
-    with jax.named_scope("attn/kv_scatter"):
+    with jax.named_scope(write):
         scratch = k_pool.shape[0] - 1
         blk = jnp.take_along_axis(
             block_tables, positions // block_size, axis=1
@@ -240,20 +304,57 @@ def _paged_attention(attn, params, x, k_pool, v_pool, block_tables,
         # decode: one query a slot attends the blocks its slot holds, in
         # the pool (the new token's row was written just above); a slot
         # that writes nothing attends nothing
-        with jax.named_scope("attn/scores"):
+        with jax.named_scope(read):
             o = _attend_in_pool(
                 (q * attn.scale)[:, :, 0], k_pool, v_pool, block_tables,
                 jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0),
                 sliding_window=attn.sliding_window,
             )[:, :, None]
     else:
-        o = _gathered_attention(q * attn.scale, k_pool, v_pool, block_tables,
-                                positions,
-                                sliding_window=attn.sliding_window)
+        L = block_tables.shape[1] * block_size
+        attend = (_walked_attention if 4 * S * attn.heads * s * L > SCORE_BYTES
+                  else _gathered_attention)
+        with jax.named_scope(read):
+            o = attend(q * attn.scale, k_pool, v_pool, block_tables, positions,
+                       sliding_window=attn.sliding_window)
     with jax.named_scope("attn/out"):
-        o = jnp.moveaxis(o, 1, 2).reshape(S, s, attn.dim)
-        y, _ = attn._out.apply(params["out"], {}, o)
+        y = attn._output(params, x, o)
     return y, k_pool, v_pool
+
+
+def ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Blocks a slot's ring has: the window and ``chunk`` new tokens."""
+    return -(-(window - 1 + chunk) // block_size)
+
+
+def init_ring_cache(attn, max_batch: int, block_size: int, dtype, chunk: int):
+    """What a windowed grouped-query layer keeps: no pool under the
+    engine's tables but for every decode slot a ring of ``window - 1 +
+    chunk`` positions (``chunk``: the most new tokens a call may bring a
+    row), rounded up to whole blocks, position ``p`` at row ``p mod
+    rows``: it never holds more of a request, however long.  All slots'
+    rings lie in ONE pair of arrays of `init_paged_cache`'s layout,
+    ``(max_batch * blocks + 1, block_size, kv_heads * head_dim)``, slot
+    ``i``'s blocks at ``i * blocks``, the last block scratch, so that
+    `_paged_attention` writes and attends a ring through the tables of
+    `ring_tables` as it does the pool through the engine's."""
+    blocks = ring_blocks(attn.sliding_window, chunk, block_size)
+    shape = (max_batch * blocks + 1, block_size, attn.kv_heads * attn.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def ring_tables(slots, rows: int, blocks: int, max_blocks: int):
+    """``(rows, max_blocks)``: the block table under which row ``r``'s
+    ring of ``blocks`` blocks reads as a paged sequence: the block of
+    positions ``[j * bs, (j + 1) * bs)`` is block ``j mod blocks`` of its
+    slot's ring
+    (``slots``: each row's decode slot, None where row ``i`` IS slot
+    ``i``).  Within a query's window every position lies in a block this
+    request wrote after whatever it held before (the ring holds the window
+    and the call's new tokens), so a slot's earlier tenant and a wrapped
+    block are never seen and admission resets nothing."""
+    slot = jnp.arange(rows, dtype=jnp.int32) if slots is None else slots
+    return slot[:, None] * blocks + jnp.arange(max_blocks, dtype=jnp.int32) % blocks
 
 
 def paged_apply_cached(lm, params, tokens, cache, block_tables, positions,
