@@ -646,9 +646,13 @@ class TestEngineSpans:
             mine = [s.name for s in phases if s.parent == step.id]
             assert mine[0] == "engine.admit" and mine[-1] == "engine.publish"
             assert len(mine) == len(set(mine))  # each phase once a step at most
-            # only the readbacks wait for the device, each after its dispatch
+            # only the readbacks wait for the device; a step's decode
+            # dispatch comes before its decode wait, which reads the step
+            # the call BEFORE launched (a call with nothing left to launch
+            # still reads: the pipeline empties itself)
             if "engine.decode_wait" in mine:
-                assert mine.index("engine.decode_dispatch") < mine.index("engine.decode_wait")
+                if "engine.decode_dispatch" in mine:
+                    assert mine.index("engine.decode_dispatch") < mine.index("engine.decode_wait")
                 assert mine.index("engine.decode_wait") + 1 == mine.index("engine.decode_apply")
             if "engine.prefill_wait" in mine:
                 assert mine.index("engine.prefill_wait") + 1 == mine.index("engine.prefill_apply")
@@ -778,3 +782,289 @@ class TestEngineSpans:
     def test_the_audit_is_bounded(self, lm, lm_params):
         eng = serve.ServeEngine(lm, lm_params, _cfg())
         assert eng.audit.maxlen >= 2 * 10_000  # two tuples a request
+
+
+# --------------------------------------------- the look-ahead of one step
+
+
+def _family_lm(name):
+    """A family's rehearsal-size model through its own builder (float32)."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    family = importlib.import_module(f"chipbench.families.{name}")
+    config = {"granitemoehybrid": "granite-4.0-h-small", "afmoe": "Trinity-Large-Preview"}[name]
+    published = json.loads(
+        (Path(__file__).resolve().parents[1] / f"chipbench/configs/{config}.json").read_text())
+    model = family.make_lm(dict(published, **family.tiny(published)), jax.random.key(7), "float32")
+    return model, model.init()[0], 512
+
+
+@pytest.fixture(scope="module")
+def served_models():
+    """{kind: (model, params, vocab)}: paged K/V alone, a recurrent state a
+    slot beside it (Mamba-2 layers), per-slot K/V rings beside it."""
+    roomy = models.TransformerLM(vocab=64, dim=32, depth=2, heads=4, max_seq=96)
+    made = {"paged": (roomy, roomy.init(jax.random.key(7))[0], 64)}
+
+    def get(kind):
+        if kind not in made:
+            made[kind] = _family_lm({"recurrent": "granitemoehybrid", "ring": "afmoe"}[kind])
+        return made[kind]
+
+    return get
+
+
+def _alone(model, params, cfg, prompt, n, sampling=None):
+    """The recorded stream: the request served alone to its length by a
+    fresh engine, so no slot is reused and nothing stops early."""
+    eng = serve.ServeEngine(model, params, cfg)
+    rid = eng.submit(prompt, n, sampling=sampling)
+    return eng.run_until_drained()[rid].tokens
+
+
+def _histograms():
+    from tpu_dist.observe.registry import REGISTRY
+
+    return (REGISTRY.histogram("tpu_dist_serve_ttft_seconds").count(),
+            REGISTRY.histogram("tpu_dist_serve_tpot_seconds").count())
+
+
+class TestLookAhead:
+    """`ServeEngine.step` launches decode step n before it reads step n-1.
+    The tokens are the ones the engine served before; a stop token or a
+    cancel finds one step in flight, whose token for that slot is never
+    seen; a finish by length is counted ahead and overruns nothing."""
+
+    SAMPLED = dict(temperature=0.9, top_k=8, top_p=0.95)
+
+    @pytest.mark.parametrize("finish", ["length", "stop", "cancel"])
+    @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+    def test_the_tokens_are_the_recorded_ones(self, lm, lm_params, sampled, finish):
+        from tpu_dist.observe import spans
+
+        N = 14
+        prompts = models.synthetic_tokens(5, 6, 64, seed=21)
+        sampling = [serve.SamplingParams(seed=40 + i, **self.SAMPLED) if sampled else None
+                    for i in range(len(prompts))]
+        if sampled:
+            want = [_alone(lm, lm_params, _cfg(max_batch=2), np.asarray(p), N, sp)
+                    for p, sp in zip(prompts, sampling)]
+        else:
+            want = list(np.asarray(lm.generate(lm_params, prompts, N, cache_len=32)))
+        # request 1 is the one that ends early; its stop token is one its
+        # own stream holds past the first token and before the last
+        cut = next(i for i in range(2, N - 2) if want[1][i] not in want[1][:i])
+        stop = int(want[1][cut]) if finish == "stop" else None
+        ttft0, tpot0 = _histograms()
+        t0 = spans.time.perf_counter()
+        eng = serve.ServeEngine(lm, lm_params, _cfg(max_batch=2))
+        rids = [eng.submit(np.asarray(p), N, sampling=sp, stop_token=stop if i == 1 else None)
+                for i, (p, sp) in enumerate(zip(prompts, sampling))]
+        reqs = list(eng.queue)
+        if finish == "cancel":
+            while len(reqs[1].tokens) < cut + 1:
+                eng.step()
+            assert eng._unread is not None and any(r is reqs[1] for _, r in eng._unread[1])
+            assert eng.cancel(rids[1])
+        res = eng.run_until_drained()
+        early = {"length": "length", "stop": "stop", "cancel": "cancelled"}[finish]
+        for i, rid in enumerate(rids):
+            r = res[rid]
+            if i == 1 and finish != "length":
+                assert r.finish_reason == early and r.emitted == cut + 1
+            else:
+                assert r.finish_reason == "length" and r.emitted == N
+            np.testing.assert_array_equal(r.tokens, want[i][: r.emitted])
+            # the overrun is in no token list and no time list
+            assert reqs[i].tokens == r.tokens.tolist()
+            assert len(r.token_times) == len(reqs[i].token_times) == r.emitted
+        emitted = sum(res[rid].emitted for rid in rids)
+        ttft1, tpot1 = _histograms()
+        assert ttft1 - ttft0 == len(rids) and tpot1 - tpot0 == emitted - len(rids)
+        # every token after a request's first came from a decode step; the
+        # one step more is the overrun, and only a stop or a cancel has one
+        fed = sum(s.attrs["fed"] for s in spans.recent(since=t0)
+                  if s.name == "engine.decode_dispatch")
+        assert fed == emitted - len(rids) + (finish != "length")
+        assert eng.allocator.used == 0
+
+    @pytest.mark.parametrize("kind", ["paged", "recurrent", "ring"])
+    def test_a_freed_slot_serves_its_next_tenant_exactly(self, served_models, kind):
+        """One slot and just the blocks one request needs: every request
+        lives where the last one lived, admitted the step after it left,
+        behind a stop's or a cancel's overrun step."""
+        model, params, vocab = served_models(kind)
+        cfg = serve.ServeConfig(max_batch=1, block_size=8, num_blocks=9, max_seq=72,
+                                prefill_chunk=16)
+        N = 30   # past the ring's 24 rows
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, vocab, (n,), dtype=np.int32) for n in (23, 40, 9, 33)]
+        want = [_alone(model, params, cfg, p, N) for p in prompts]
+        assert len({w.tolist()[-1] for w in want}) > 1, "the answers differ"
+        cut = next(i for i in range(8, N - 2) if want[0][i] not in want[0][:i])
+        eng = serve.ServeEngine(model, params, cfg)
+        rids = [eng.submit(p, N, stop_token=int(want[0][cut]) if i == 0 else None)
+                for i, p in enumerate(prompts)]
+        reqs = list(eng.queue)
+        while len(reqs[1].tokens) < 12:
+            eng.step()
+        assert eng.cancel(rids[1])
+        res = eng.run_until_drained()
+        np.testing.assert_array_equal(res[rids[0]].tokens, want[0][: cut + 1])
+        np.testing.assert_array_equal(res[rids[1]].tokens, want[1][:12])
+        np.testing.assert_array_equal(res[rids[2]].tokens, want[2])
+        np.testing.assert_array_equal(res[rids[3]].tokens, want[3])
+        assert [res[r].finish_reason for r in rids] == ["stop", "cancelled", "length", "length"]
+        admits = {a[1]: a for a in eng.audit if a[0] == "admit"}
+        finishes = {a[1]: a for a in eng.audit if a[0] == "finish"}
+        for before, after in zip(rids, rids[1:]):
+            # (admit, id, slot, blocks, step) / (finish, id, reason, emitted, step)
+            assert admits[after][2] == admits[before][2] == 0
+            assert set(admits[after][3]) & set(admits[before][3])
+            # a cancel is applied where the next call begins, ahead of its
+            # admission; any other finish where a call reads its step
+            same_call = finishes[before][2] == "cancelled"
+            assert admits[after][4] == finishes[before][4] + (not same_call)
+
+    @staticmethod
+    def _churn(lm, lm_params, after_each_step):
+        """Answers of 2-9 tokens on three slots, a stop token and a cancel
+        among them, one prefill row a round; the engine runs empty, then
+        serves one request more.  -> (engine, request ids)"""
+        eng = serve.ServeEngine(lm, lm_params, _cfg(max_batch=3, prefill_batch=1))
+
+        def drain():
+            while eng.pending:
+                eng.step()
+                after_each_step(eng)
+
+        rng = np.random.default_rng(3)
+        rids = []
+        for i in range(16):
+            prompt = models.synthetic_tokens(1, int(rng.integers(2, 12)), 64, seed=i)[0]
+            rids.append(eng.submit(np.asarray(prompt), int(rng.integers(2, 10)),
+                                   stop_token=7 if i % 5 == 0 else None))
+        for _ in range(9):
+            eng.step()
+            after_each_step(eng)
+        running = next(r for r in eng.slots if r is not None and r.state == "decode")
+        assert eng.cancel(running.request_id)
+        drain()
+        rids.append(eng.submit(np.asarray(models.synthetic_tokens(1, 5, 64, seed=99)[0]), 6))
+        drain()
+        return eng, rids
+
+    def test_the_devices_rows_are_the_hosts_but_one_step_on(self, lm, lm_params):
+        """After every call: a row the host has not marked stale reads on
+        the device what the host's mirrors say (block table, sampling
+        columns, fed or not), with position and counter one on where its
+        slot is in the step still unread; a stale row is the host's to
+        write before the next launch, whatever the device holds."""
+        checked = [0, 0]
+
+        def check(eng):
+            MB = eng.blocks_per_seq
+            ints = np.asarray(eng._dint)
+            fresh = ~eng._stale
+            fed = eng.active & (eng._unfed > 0)
+            ahead = np.zeros_like(eng.index)
+            if eng._unread is not None:
+                for slot, req in eng._unread[1]:
+                    ahead[slot] += req.state != "finished"
+            np.testing.assert_array_equal(ints[fresh, :MB], eng.block_tables[fresh])
+            np.testing.assert_array_equal(ints[fresh, MB + eng._ACTIVE], fed[fresh])
+            live = fresh & eng.active
+            for col, mirror in ((eng._INDEX, eng.index), (eng._COUNTER, eng.counters)):
+                np.testing.assert_array_equal(ints[live, MB + col], (mirror + ahead)[live])
+            np.testing.assert_array_equal(ints[live, MB + eng._SEED], eng.seeds[live])
+            checked[0] += int(live.sum())
+            checked[1] += int(eng._stale.sum())
+
+        eng, rids = self._churn(lm, lm_params, check)
+        assert checked[0] > 40 and checked[1] > 15, checked
+        assert len(eng.results) == len(rids) and eng.allocator.used == 0
+
+    def test_ahead_on_every_dispatch_the_causes_do_not_name(self, lm, lm_params):
+        """Slots join and leave every few steps (answers of 2-9 tokens on
+        three slots, a stop and a cancel among them): a stale row does not
+        drain the look-ahead.  The dispatches that were not ahead are the
+        first after the engine ran empty and those behind a step skipped
+        for prefill; the counters say what the spans say."""
+        from tpu_dist.observe import spans
+        from tpu_dist.observe.registry import REGISTRY
+
+        counter = lambda n: REGISTRY.counter(f"tpu_dist_serve_{n}_total")  # noqa: E731
+        causes = ("drained", "prefill_priority", "prefill_join")
+        before = {n: counter(n).value() for n in ("decode_steps", "decode_ahead", "state_repacks")}
+        gaps0 = {c: counter("decode_gaps").value(cause=c) for c in causes}
+        t0 = spans.time.perf_counter()
+        eng, rids = self._churn(lm, lm_params, lambda eng: None)
+        got = spans.recent(since=t0)
+        steps = [s for s in got if s.name == "engine.step"]
+        kids = {s.id: [c for c in got if c.parent == s.id] for s in steps}
+        launched = [(n, d) for n, s in enumerate(steps) for d in kids[s.id]
+                    if d.name == "engine.decode_dispatch"]
+        assert len(launched) > 30
+        behind = [(n, d) for n, d in launched if not d.attrs["ahead"]]
+        for n, d in launched:
+            names = [c.name for c in kids[steps[n - 1].id]] if n else []
+            gap = d.attrs.get("gap")
+            # ahead: the call before launched a step that this call reads
+            assert d.attrs["ahead"] == ("engine.decode_dispatch" in names)
+            assert d.attrs["ahead"] == ("engine.decode_wait" in [c.name for c in kids[steps[n].id]])
+            if not d.attrs["ahead"]:
+                assert gap in ("drained", "prefill_priority")
+            elif gap is not None:
+                # the call before waited for a round's first tokens, and
+                # with them for the step it had launched ahead of the round
+                assert gap == "prefill_join" and "engine.prefill_wait" in names
+            else:
+                assert "engine.prefill_wait" not in names
+        # slots joined and left under steps that stayed ahead
+        assert sum(d.attrs["repacked"] and d.attrs["ahead"] for _, d in launched) >= 10
+        assert any(d.attrs.get("gap") == "prefill_join" for _, d in launched)
+        assert behind[0] == launched[0]
+        assert behind[-1][1].attrs["gap"] == "drained"   # it ran empty, then served again
+        assert len(behind) < len(launched) // 4
+        delta = {n: counter(n).value() - v for n, v in before.items()}
+        assert delta["decode_steps"] == len(launched) == eng.steps_with_decode
+        assert delta["decode_ahead"] == len(launched) - len(behind)
+        assert delta["state_repacks"] == sum(d.attrs["repacked"] for _, d in launched)
+        for c in causes:
+            assert (counter("decode_gaps").value(cause=c) - gaps0[c]
+                    == sum(d.attrs.get("gap") == c for _, d in launched))
+        assert all(eng.results[r].finish_reason in ("length", "stop", "cancelled") for r in rids)
+
+    @pytest.mark.parametrize("finish", ["length", "stop", "cancel"])
+    def test_drained_leaves_no_step_unread(self, lm, lm_params, finish):
+        """`pending` holds until the last token of the last request is
+        applied AND nothing is in flight: a stop's or a cancel's overrun
+        step is read (and dropped) before the engine calls itself idle."""
+        prompt = np.asarray(models.synthetic_tokens(1, 5, 64, seed=3)[0])
+        free = np.asarray(lm.generate(lm_params, prompt[None], 12, cache_len=32))[0]
+        cut = next(i for i in range(2, 10) if free[i] not in free[:i])
+        eng = serve.ServeEngine(lm, lm_params, _cfg())
+        rid = eng.submit(prompt, 12, stop_token=int(free[cut]) if finish == "stop" else None)
+        req = eng.queue[0]
+        reads = 0
+        while eng.pending:
+            had = eng._unread is not None
+            if finish == "cancel" and len(req.tokens) == cut + 1 and req.state == "decode":
+                eng.cancel(rid)
+            eng.step()
+            reads += had
+            if req.state == "finished" and finish != "length" and eng._unread is not None:
+                # the overrun is still to be read: not idle yet
+                assert eng.pending and not any(r is not None for r in eng.slots)
+        assert eng._unread is None and not eng.pending
+        assert reads == eng.steps_with_decode
+        want = 12 if finish == "length" else cut + 1
+        assert eng.results[rid].emitted == want
+        np.testing.assert_array_equal(eng.results[rid].tokens, free[:want])
+        # emitted - 1 decode steps gave tokens; a stop or a cancel ran one more
+        assert eng.steps_with_decode == want - 1 + (finish != "length")
+        calls = eng.step_count
+        eng.run_until_drained()   # idle: nothing left to call for
+        assert eng.step_count == calls

@@ -11,10 +11,12 @@ of the time).  This engine is the standard fix:
   eviction;
 - a step loop that, EVERY step, evicts finished requests, admits queued
   ones into the freed slots (FIFO; head-of-line blocks on pool
-  exhaustion, so admission order is deterministic), runs at most one
-  chunked PREFILL (prompt ingestion never stalls in-flight decodes for
-  more than one chunk), then one batched DECODE step over every active
-  slot;
+  exhaustion, so admission order is deterministic), launches one batched
+  DECODE step over every active slot and at most one chunked PREFILL
+  round (prompt ingestion never stalls in-flight decodes for more than
+  one chunk), and only then reads the decode step launched the call
+  BEFORE: a look-ahead of one, so the chip runs step n+1 while the host
+  keeps its books for step n (`ServeEngine.step`);
 - per-request sampling params (`sample_slots` — temperature/top_k/top_p
   are per-slot runtime values, so one compiled step program serves any
   request mix), per-request PRNG streams keyed by (seed, token index);
@@ -26,13 +28,18 @@ of the time).  This engine is the standard fix:
   phases as children (``engine.admit``, ``.decode_dispatch``,
   ``.prefill_dispatch``, ``.decode_wait``, ``.decode_apply``,
   ``.prefill_wait``, ``.prefill_apply``, ``.publish``; a phase that had
-  nothing to do leaves no span), and per request ``request.queued`` /
+  nothing to do leaves no span; ``.decode_wait`` / ``.decode_apply`` read
+  the step the call before launched), and per request ``request.queued`` /
   ``request.prefill`` / ``request.decode`` sharing ``request_id``
   (docs/observability.md lists the attrs).
 
 Greedy decode through the engine is token-identical to the dense
 `generate` (tested across block sizes) — continuous batching changes
-WHEN a request computes, never WHAT it computes.
+WHEN a request computes, never WHAT it computes.  The look-ahead changes
+when the host HAS a token (a call later), never which: a finish by
+length is counted ahead and nothing runs past it; a stop token or a
+cancel is seen with one step in flight, whose token for that slot is
+dropped.
 """
 
 from __future__ import annotations
@@ -222,17 +229,32 @@ class ServeEngine:
 
         self._decode_fn = self._build_decode_fn(greedy=False)
         self._decode_fn_greedy = self._build_decode_fn(greedy=True)
+        self._rows_fn = self._build_rows_fn()
         self._prefill_fn = self._build_prefill_fn()
+        # tokens of each slot's request not yet launched: the host counts
+        # what it has dispatched, so a finish by length is known a step
+        # before its token arrives and the slot is not fed past it
+        self._unfed = np.zeros((S,), np.int32)
         # device-resident decode state: the per-slot scheduling arrays
         # ride the jitted step's output back into the next step's input
         # as ONE packed int32 array (block tables, active mask, sampling
         # ints, last token, position, token counter) plus one small f32
         # array (temperature, top_p) — a steady-state decode step
-        # transfers nothing host->device, and a slot-map change (admit /
-        # activate / evict) rebuilds both with two device_puts
-        self._dint = None
-        self._dflt = None
-        self._dirty = True
+        # transfers nothing host->device.  A slot that joins, leaves or is
+        # granted blocks marks its row `_stale`; the next dispatch writes
+        # those rows over the device's (`serve_slot_rows`) and leaves the
+        # others what the device carried forward, which the host's mirrors
+        # (one readback behind) could not give back
+        self._dint, self._dflt = jax.device_put(self._pack_state())
+        self._stale = np.zeros((S,), bool)
+        # the decode step launched by the last call and not read yet:
+        # (tokens' device handle, [(slot, request) it fed])
+        self._unread = None
+        # why the next decode launch finds the chip idle, None while it
+        # is fed: the last call launched no decode step (`drained`,
+        # `prefill_priority`) or waited for a prefill round's first tokens
+        # behind the one it launched (`prefill_join`)
+        self._gap = "drained"
         self._warming = False
         self._g_occ = REGISTRY.gauge(
             "tpu_dist_serve_batch_occupancy",
@@ -274,9 +296,18 @@ class ServeEngine:
         )
         self._c_repacks = counter(
             "state_repacks",
-            "decode dispatches that rebuilt the packed slot state",
+            "decode dispatches that wrote slot rows over the packed state",
         )
         self._c_decode_steps = counter("decode_steps", "decode steps dispatched")
+        self._c_ahead = counter(
+            "decode_ahead",
+            "decode steps dispatched while the step before was still unread",
+        )
+        self._c_gaps = counter(
+            "decode_gaps",
+            "decode steps dispatched onto a chip the host had let run dry, "
+            "by cause",
+        )
         # what the model's serving programs count themselves (routed
         # picks, tokens an expert): running totals that ride the decode
         # step's readback, position by position as `lm.serve_counters` says
@@ -321,10 +352,13 @@ class ServeEngine:
     _ACTIVE, _TOPK, _SEED, _LASTTOK, _INDEX, _COUNTER = range(6)
 
     def _pack_state(self):
+        """The host's mirrors as the packed state.  A row is the device's
+        truth only while it is stale (just joined, left or granted); a
+        row that goes on decoding is a readback behind the device's."""
         MB = self.blocks_per_seq
         ints = np.empty((self.cfg.max_batch, MB + 6), np.int32)
         ints[:, :MB] = self.block_tables
-        ints[:, MB + self._ACTIVE] = self.active
+        ints[:, MB + self._ACTIVE] = self.active & (self._unfed > 0)
         ints[:, MB + self._TOPK] = self.top_k
         ints[:, MB + self._SEED] = self.seeds
         ints[:, MB + self._LASTTOK] = self.last_tok
@@ -379,6 +413,22 @@ class ServeEngine:
         # the program's name on the trace's `XLA Modules` line
         fn.__name__ = "serve_decode_greedy" if greedy else "serve_decode_sampled"
         return jax.jit(fn, donate_argnums=(1, 2))
+
+    def _build_rows_fn(self):
+        """The rows of ``rows`` (a mask over the slots) written over the
+        device's packed state, every other row kept as the decode steps
+        carried it: ahead of a decode dispatch whose slot map changed,
+        behind the step still in flight on the same donated chain."""
+
+        def serve_slot_rows(ints, flt, rows, new_ints, new_flt):
+            with jax.named_scope("state_update"):
+                rows = rows[:, None]
+                return (
+                    jnp.where(rows, new_ints, ints),
+                    jnp.where(rows, new_flt, flt),
+                )
+
+        return jax.jit(serve_slot_rows, donate_argnums=(0, 1))
 
     def _build_prefill_fn(self):
         """One prompt chunk for EACH of P pending requests (P = however
@@ -580,8 +630,9 @@ class ServeEngine:
     def cancel(self, request_id: int) -> bool:
         """Cancel a queued or in-flight request.  Queued: removed
         immediately.  Running: evicted at the start of the next step
-        (its partial tokens are returned with ``finish_reason
-        'cancelled'``).  Returns False for unknown/finished ids."""
+        (the tokens the host has are returned with ``finish_reason
+        'cancelled'``; the token of a decode step still in flight is
+        dropped).  Returns False for unknown/finished ids."""
         for i, req in enumerate(self.queue):
             if req.request_id == request_id:
                 del self.queue[i]
@@ -595,7 +646,13 @@ class ServeEngine:
 
     @property
     def pending(self) -> bool:
-        return bool(self.queue) or any(r is not None for r in self.slots)
+        """True until the last token of the last request has been applied
+        and no decode step is left unread."""
+        return (
+            bool(self.queue)
+            or any(r is not None for r in self.slots)
+            or self._unread is not None
+        )
 
     def run_until_drained(self, max_steps: int = 100_000):
         """Drive `step()` until queue and slots are empty; returns the
@@ -615,10 +672,25 @@ class ServeEngine:
     # ------------------------------------------------------------ the step
 
     def step(self) -> None:
-        """One engine step: evict cancels, admit, one decode step plus
-        one batched prefill round — DISPATCHED back-to-back before
-        either is read back, so the host's bookkeeping for one overlaps
-        the device's compute for the other — then publish telemetry.
+        """One engine step: evict cancels, admit, LAUNCH decode step n
+        and one batched prefill round, then READ decode step n-1 (the
+        one the call before launched) and the round, then publish
+        telemetry.  The chip runs step n while the host keeps step
+        n-1's books, returns to its caller and comes back to launch
+        n+1: between two decode programs it does not wait for the host.
+
+        What the host shows (``active``, ``index``, ``Request.tokens``,
+        the events, the histograms) is therefore one step behind the
+        device; a token counts, and is timed, when the host has it.  A
+        finish by length is counted ahead (`_unfed`): the slot is not
+        fed past its last token.  A stop token or a cancel is seen with
+        one step in flight; that step's token for the slot is dropped.
+        Its write lands inside the request's own grant or its slot's own
+        state, and whatever touches those next is dispatched behind it on
+        the one donated cache chain.  The first token of a prefill round
+        is read in the call that launched the round, as before.  A call
+        with nothing to launch reads what is unread: the pipeline empties
+        itself.
 
         Prefill-priority at low occupancy: while more prefills would
         remain after this round and no more than half the decode slots
@@ -634,9 +706,10 @@ class ServeEngine:
                 len(self._prefillq) > self.cfg.prefill_batch
                 and self.occupancy() <= self.cfg.max_batch // 2
             )
+            unread = self._unread
             try:
-                decode_toks = (
-                    None if prefer_prefill else self._decode_dispatch()
+                self._unread = self._decode_dispatch(
+                    ahead=unread is not None, skip=prefer_prefill
                 )
             except Exception as e:
                 self._oom(e, "decode")
@@ -647,7 +720,7 @@ class ServeEngine:
                 self._oom(e, "prefill")
                 raise
             try:
-                did_decode = self._decode_complete(decode_toks)
+                did_decode = self._decode_complete(unread)
             except Exception as e:
                 self._oom(e, "decode")
                 raise
@@ -741,7 +814,7 @@ class ServeEngine:
             s32 = sp.seed & 0xFFFFFFFF
             self.seeds[s] = s32 - (1 << 32) if s32 >= 1 << 31 else s32
             self.counters[s] = 0
-            self._dirty = True
+            self._stale[s] = True
             self._prefillq.append(s)
             self.audit.append(
                 ("admit", req.request_id, s, tuple(blocks), self.step_count)
@@ -813,9 +886,11 @@ class ServeEngine:
         ]
         toks_np = None
         if finishing:
-            # the one place the host waits for the prefill round
+            # the one place the host waits for the prefill round, and
+            # with it for the decode step launched ahead of the round
             with spans.span("engine.prefill_wait"):
                 toks_np = np.asarray(first_toks)
+            self._gap = self._gap or "prefill_join"
         tnow = self._now()
         with spans.span("engine.prefill_apply"):
             for r, (s, req, start, size) in enumerate(chunks):
@@ -847,52 +922,76 @@ class ServeEngine:
                 self.index[s] = req.prompt.size
                 req.state = "decode"
                 self.active[s] = True
-                self._dirty = True
+                self._unfed[s] = req.max_new_tokens - 1
+                self._stale[s] = True
                 if self._finished_by(req, tok):
                     self._evict(s, self._finish_reason(req, tok), tnow)
         return True
 
-    def _decode_dispatch(self):
-        """Dispatch one batched token for every active slot (no
-        readback yet).  Returns the tokens' device handle, or None."""
-        if not self.active.any():
+    def _decode_dispatch(self, *, ahead: bool, skip: bool):
+        """Launch one batched token for every slot with a token still to
+        be fed, from the state the device carried forward; ``ahead``: the
+        step before is still unread; ``skip``: prefill has priority this
+        step.  Returns what `_decode_complete` reads a call later, (the
+        tokens' device handle, the (slot, request) pairs fed), or None."""
+        feed = self.active & (self._unfed > 0)
+        fed = np.nonzero(feed)[0]
+        if skip or not fed.size:
+            self._gap = "prefill_priority" if fed.size else "drained"
             return None
-        repacked = self._dirty
-        with spans.span("engine.decode_dispatch", repacked=repacked):
+        repacked = bool(self._stale.any())
+        attrs = {"repacked": repacked, "ahead": ahead, "fed": int(fed.size)}
+        gap, self._gap = self._gap, None
+        if gap:
+            attrs["gap"] = gap
+        with spans.span("engine.decode_dispatch", **attrs):
             if repacked:
-                self._dint, self._dflt = self._pack_state()
-                self._dirty = False
+                ints, flt = self._pack_state()
+                self._dint, self._dflt = self._rows_fn(
+                    self._dint, self._dflt, self._stale, ints, flt
+                )
+                self._stale = np.zeros_like(self._stale)
             fn = (
                 self._decode_fn_greedy
-                if not self.temperature[self.active].any()
+                if not self.temperature[feed].any()
                 else self._decode_fn
             )
             toks, self._dint, self.cache = fn(
                 self.params, self.cache, self._dint, self._dflt
             )
+        self._unfed[fed] -= 1
+        # fed its last: off the device's batch before the next launch
+        self._stale[fed[self._unfed[fed] == 0]] = True
         self._count(self._c_decode_steps)
         if repacked:
             self._count(self._c_repacks)
-        return toks
+        if ahead:
+            self._count(self._c_ahead)
+        if gap:
+            self._count(self._c_gaps, cause=gap)
+        return toks, [(int(s), self.slots[s]) for s in fed]
 
-    def _decode_complete(self, toks) -> bool:
-        """Read back a dispatched decode step, then finish/evict the
-        streams that completed — THE every-step admit/evict cycle's
-        compute half."""
-        if toks is None:
+    def _decode_complete(self, unread) -> bool:
+        """Read back the decode step the call before launched, then
+        finish/evict the streams that completed — THE every-step
+        admit/evict cycle's compute half."""
+        if unread is None:
             return False
+        toks, fed = unread
         with spans.span("engine.decode_wait"):
             toks_np = np.asarray(toks)  # host sync: the step boundary
         tnow = self._now()
         with spans.span("engine.decode_apply") as sp:
             if self._model_counters:
                 self._count_model(toks_np[self.cfg.max_batch:], sp)
-            active = np.nonzero(self.active)[0]
-            self.last_tok[active] = toks_np[active]
-            self.index[active] += 1
-            self.counters[active] += 1
-            for s in active:
-                req = self.slots[s]
+            # a request that stopped or was cancelled while this step was
+            # in flight: its token here is the overrun, never seen
+            fed = [(s, req) for s, req in fed if req.state != "finished"]
+            live = [s for s, _ in fed]
+            self.last_tok[live] = toks_np[live]
+            self.index[live] += 1
+            self.counters[live] += 1
+            for s, req in fed:
                 tok = int(toks_np[s])
                 if req.token_times and not self._warming:
                     self._h_tpot.observe(tnow - req.token_times[-1])
@@ -945,7 +1044,8 @@ class ServeEngine:
         self.slots[s] = None
         self.block_tables[s, :] = self.scratch
         self.active[s] = False
-        self._dirty = True
+        self._unfed[s] = 0
+        self._stale[s] = True
         self._finalize(req, reason, tnow)
 
     def _finalize(self, req: Request, reason: str, tnow: float) -> None:
