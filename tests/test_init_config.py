@@ -39,7 +39,6 @@ class TestInitConfig:
         from tpu_dist.utils import platform as platform_mod
 
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
-        monkeypatch.setattr(platform_mod, "_cache_listener_installed", True)
         keys = (
             "jax_compilation_cache_dir",
             "jax_persistent_cache_min_entry_size_bytes",
@@ -71,7 +70,6 @@ class TestInitConfig:
         # copies the tree as it stands): same code path, scratch target
         cache_dir = tmp_path / ".jax_cache"
         monkeypatch.setattr(platform_mod, "DEFAULT_COMPILE_CACHE", cache_dir)
-        monkeypatch.setattr(platform_mod, "_cache_listener_installed", False)
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         tdir = tmp_path / "telemetry"
         monkeypatch.setenv(events.ENV_DIR, str(tdir))

@@ -616,8 +616,8 @@ def _serve_traced(lm, lm_params, lengths, *, max_new=5, **cfg):
 
     from tpu_dist.observe import spans
 
-    t0 = time.perf_counter()
     eng = serve.ServeEngine(lm, lm_params, _cfg(**cfg))
+    t0 = time.perf_counter()  # after its construction's own spans (`engine.init`)
     rids = [
         eng.submit(models.synthetic_tokens(1, n, 64, seed=i)[0], max_new)
         for i, n in enumerate(lengths)

@@ -46,6 +46,9 @@ from tpu_dist import (
 
 __version__ = "0.1.0"
 
+# every compilation from here on leaves its stages on the span ring
+observe.compile_spans.install()
+
 __all__ = [
     "comm",
     "data",

@@ -73,6 +73,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from tpu_dist.models.init_span import InitSpan
 from tpu_dist.nn.attention import MultiHeadAttention
 from tpu_dist.nn.core import Module
 from tpu_dist.nn.latent_attention import LatentAttention
@@ -294,7 +295,7 @@ MIXERS = {
 }
 
 
-class HybridLM(Module):
+class HybridLM(InitSpan, Module):
     """``mixers``: by layer kind, the sizes its `MIXERS` entry is built
     with.  The ``ssm_*`` and ``heads`` / ``kv_heads`` /
     ``attention_multiplier`` arguments are the sizes of ``"mamba"`` and

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from tpu_dist import nn
 from tpu_dist.ops import partitioning
 from tpu_dist.nn.core import Module
+from tpu_dist.models.init_span import InitSpan
 from tpu_dist.models.vit import EncoderBlock
 
 
@@ -61,7 +62,7 @@ def _make_sampler(temperature, top_k, top_p, dtype):
     return sample
 
 
-class TransformerLM(Module):
+class TransformerLM(InitSpan, Module):
     def __init__(
         self,
         *,

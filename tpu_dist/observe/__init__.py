@@ -12,6 +12,10 @@ is the measurement substrate the ROADMAP's perf PRs cite:
                 text-exposition endpoint (``TPU_DIST_METRICS_PORT``)
 - `spans`     — host-side span tracing emitted as Chrome-trace JSON,
                 correlated with `jax.profiler` device traces by step id
+- `compile_spans` — JAX's trace / lower / compile-or-load stages as
+                kept spans of the same ring, by program name
+                (`jax.monitoring` listeners; `install` is called when
+                ``tpu_dist`` is imported)
 - `heartbeat` — per-rank progress heartbeats, stall attribution
                 ("rank N is K seconds behind"), and goodput accounting
 - `flightrec` — always-on per-rank ring buffer of step/phase/collective
@@ -39,6 +43,7 @@ it EXECUTES compiled programs, so it needs jax); import it explicitly.
 """
 
 from tpu_dist.observe import (
+    compile_spans,
     events,
     flightrec,
     heartbeat,
@@ -49,6 +54,6 @@ from tpu_dist.observe import (
 )
 
 __all__ = [
-    "events", "flightrec", "heartbeat", "memory", "registry",
+    "compile_spans", "events", "flightrec", "heartbeat", "memory", "registry",
     "results", "spans",
 ]

@@ -9,7 +9,12 @@ step-scoped ones ``step``.
 
 Every span lands in ONE process-global bounded ring (a
 ``deque(maxlen=RING_SIZE)`` like `flightrec`'s: no lock, no I/O), read
-with `recent`.  It is always on.  Opening a span also enters
+with `recent`.  It is always on.  A span made with ``keep=True`` (a stage
+of JAX's compile pipeline, a phase of a trainer's or an engine's
+construction: tens to hundreds a process) goes to the ring like any other
+AND to a second, small one that the hot path's spans never reach, read
+with `kept`: what set-up cost can still be read after hours of steps have
+wrapped the ring.  Opening a span also enters
 ``jax.profiler.TraceAnnotation("tpu_dist/<name>")``: a flag test while no
 profiler session is open, and while one is the span sits in the
 ``.xplane.pb`` host plane on the device trace's clock, so idle gaps on
@@ -42,12 +47,14 @@ from collections import deque
 from tpu_dist.observe import events as _events
 
 RING_SIZE = 65536
+KEPT_SIZE = 4096
 PREFIX = "tpu_dist/"
 
 # wall clock minus perf_counter, read once: the export's only other clock
 WALL_OFFSET = time.time() - time.perf_counter()
 
 _ring: deque = deque(maxlen=RING_SIZE)
+_kept: deque = deque(maxlen=KEPT_SIZE)
 _ids = itertools.count(1)
 _local = threading.local()
 _annotation = None  # jax.profiler.TraceAnnotation, resolved at the first span
@@ -65,10 +72,10 @@ class Span:
     """One span; also its own context manager (`span` hands it out, so a
     caller can add to ``attrs`` what it learns inside)."""
 
-    __slots__ = ("name", "start", "end", "id", "parent", "attrs", "tid", "_ann")
+    __slots__ = ("name", "start", "end", "id", "parent", "attrs", "tid", "keep", "_ann")
 
-    def __init__(self, name: str, attrs: dict):
-        self.name, self.attrs = name, attrs
+    def __init__(self, name: str, attrs: dict, keep: bool = False):
+        self.name, self.attrs, self.keep = name, attrs, keep
         self.start = self.end = 0.0
         self.id = self.parent = None
         self.tid = threading.get_ident() & 0xFFFFFF  # the export's lane
@@ -99,6 +106,8 @@ class Span:
         self._ann = None
         _open_ids().pop()
         _ring.append(self)
+        if self.keep:
+            _kept.append(self)
         return False
 
     def __repr__(self) -> str:
@@ -106,18 +115,27 @@ class Span:
                 f"id={self.id}, parent={self.parent}, {self.attrs})")
 
 
-def span(name: str, **attrs) -> Span:
-    """``with span("engine.step", step=3) as sp:`` times the block."""
-    return Span(name, attrs)
+def span(name: str, keep: bool = False, **attrs) -> Span:
+    """``with span("engine.step", step=3) as sp:`` times the block;
+    ``keep=True`` also holds the span past the ring's wrap (`kept`)."""
+    return Span(name, attrs, keep)
 
 
-def record(name: str, start: float, end: float, **attrs) -> Span:
+def record(name: str, start: float, end: float, keep: bool = False, nest: bool = False,
+           **attrs) -> Span:
     """A span whose two ends the caller read itself (`time.perf_counter`),
     for a stretch no ``with`` block covers: a request's wait in the queue.
-    It has no parent and, lying in the past, no profiler annotation."""
-    sp = Span(name, attrs)
+    Lying in the past it has no profiler annotation, and no parent unless
+    ``nest=True`` says that it lay inside the span now open on the calling
+    thread (a compile stage inside the dispatch that paid for it)."""
+    sp = Span(name, attrs, keep)
     sp.start, sp.end, sp.id = start, end, next(_ids)
+    if nest:
+        opened = _open_ids()
+        sp.parent = opened[-1] if opened else None
     _ring.append(sp)
+    if keep:
+        _kept.append(sp)
     return sp
 
 
@@ -125,6 +143,13 @@ def recent(since: float = float("-inf")) -> list[Span]:
     """The ring's spans that started at or after ``since``, in the order
     they ended (a span is kept when it closes)."""
     return [s for s in list(_ring) if s.start >= since]
+
+
+def kept(since: float = float("-inf")) -> list[Span]:
+    """The ``keep=True`` spans that started at or after ``since``, in the
+    order they ended, whether or not the ring still holds them (the newest
+    `KEPT_SIZE` of them)."""
+    return [s for s in list(_kept) if s.start >= since]
 
 
 def complete_since(since: float) -> bool:
@@ -145,7 +170,8 @@ class SpanRecorder:
     """The Chrome-trace file of the ring: `span` and `instant` are the
     module's own with ``step`` named, and `save` writes every span the ring
     holds that started since this recorder was made (the ring bounds a
-    multi-day run's file to its newest `RING_SIZE` spans)."""
+    multi-day run's file to its newest `RING_SIZE` spans, and the kept
+    spans that came before them)."""
 
     enabled = True
 
@@ -183,12 +209,19 @@ class SpanRecorder:
         """Write the Chrome-trace JSON; returns the path (None if this
         recorder has nowhere to write).  Idempotent — call at every
         fit-exit; later spans simply extend the file on the next save.
-        A span still open is not in the ring yet: the next save has it."""
+        A span still open is not in the ring yet: the next save has it.
+        A kept span is drawn once, from the ring while that holds it and
+        from `kept` after (``complete`` speaks of the ring alone)."""
         path = path or self.path
         if path is None:
             return None
+        drawn = recent(self.since)
+        if not complete_since(self.since):
+            # a kept span the ring has dropped ended before every span it holds
+            held = {sp.id for sp in drawn}
+            drawn = [sp for sp in kept(self.since) if sp.id not in held] + drawn
         doc = {
-            "traceEvents": [self._event(sp) for sp in recent(self.since)],
+            "traceEvents": [self._event(sp) for sp in drawn],
             "displayTimeUnit": "ms",
             "otherData": {
                 "producer": "tpu_dist.observe.spans",

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_dist.observe import spans
 from tpu_dist.parallel.data_parallel import partitioned_over
 
 DP_AXIS = "dp"
@@ -1248,13 +1249,18 @@ def make_partitioned_train_step(
         out_shardings=(p_sh, o_sh_step, None, None),
         donate_argnums=(0, 1) if donate else (),
     )
-    placed_params = jax.tree_util.tree_map(
-        lambda a, s: jax.device_put(np.asarray(a), s), params, p_sh
-    )
+    # both kept: what the state's placement costs every start (the
+    # parameters' trip through the host, the optimizer init's compile)
+    with spans.span("partition.place_params", keep=True) as sp:
+        placed_params = jax.tree_util.tree_map(
+            lambda a, s: jax.device_put(np.asarray(a), s), params, p_sh
+        )
+        sp.attrs["bytes"] = sum(a.nbytes for a in jax.tree.leaves(placed_params))
     # Opt state is born sharded: init compiled with the opt shardings as
     # out-shardings, so each device writes only its own shard (no full
     # host copy, no device->host->device round trip).
-    placed_opt = jax.jit(optimizer.init, out_shardings=o_sh)(placed_params)
+    with spans.span("partition.init_opt", keep=True):
+        placed_opt = jax.jit(optimizer.init, out_shardings=o_sh)(placed_params)
     if ccfg is not None and wrap_ef:
         placed_opt = {
             "opt": placed_opt,

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist.observe import events as ev_mod
-from tpu_dist.observe import spans
+from tpu_dist.observe import compile_spans, spans
 from tpu_dist.observe.registry import REGISTRY
 from tpu_dist.serve.paged_kv import BlockAllocator
 from tpu_dist.serve.sampling import sample_slots, slot_keys
@@ -166,6 +166,12 @@ class ServeEngine:
 
     def __init__(self, lm, params, config: ServeConfig | None = None, *,
                  now=time.perf_counter, events=None):
+        compile_spans.install()
+        # what building an engine costs the host, kept past the ring's wrap
+        with spans.span("engine.init", keep=True):
+            self._init(lm, params, config, now, events)
+
+    def _init(self, lm, params, config, now, events) -> None:
         cfg = config or ServeConfig()
         if cfg.max_seq > lm.max_seq:
             raise ValueError(
@@ -197,9 +203,15 @@ class ServeEngine:
         dtype = cfg.cache_dtype or params["embed"]["table"].dtype
         # the model's own: paged pools under "kv", and under "state"
         # whatever it keeps per decode slot (a recurrent state)
-        self.cache = lm.init_serve_cache(
-            cfg.max_batch, cfg.num_blocks, cfg.block_size, dtype
-        )
+        from tpu_dist.parallel import per_device_bytes
+
+        with spans.span("engine.init_cache", keep=True) as sp:
+            self.cache = lm.init_serve_cache(
+                cfg.max_batch, cfg.num_blocks, cfg.block_size, dtype
+            )
+            self.kv_pool_bytes = int(per_device_bytes(self.cache["kv"]))
+            self.state_bytes = int(per_device_bytes(self.cache["state"]))
+            sp.attrs.update(kv_bytes=self.kv_pool_bytes, state_bytes=self.state_bytes)
         self.scratch = cfg.num_blocks
 
         S, MB = cfg.max_batch, self.blocks_per_seq
@@ -322,11 +334,7 @@ class ServeEngine:
         # that pool) vs whatever headroom the device has left for
         # activations.  `bytes_limit` comes from the config (tests/
         # operators) or the live device limit (None on CPU-sim).
-        from tpu_dist.parallel import per_device_bytes
-
         self.weights_bytes = int(per_device_bytes(self.params))
-        self.kv_pool_bytes = int(per_device_bytes(self.cache["kv"]))
-        self.state_bytes = int(per_device_bytes(self.cache["state"]))
         # the pool holds num_blocks grantable blocks + 1 scratch block
         self.kv_block_bytes = self.kv_pool_bytes // (cfg.num_blocks + 1)
         self.bytes_limit = (

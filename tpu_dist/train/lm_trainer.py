@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from tpu_dist import parallel
 from tpu_dist.models.transformer_lm import lm_loss, lm_perplexity
+from tpu_dist.observe import compile_spans, spans
 from tpu_dist.train.optim import Optimizer, adamw, clip_by_global_norm
 
 
@@ -166,6 +167,14 @@ class LMTrainer:
         *,
         optimizer: Optimizer | None = None,
     ):
+        compile_spans.install()
+        # what building a trainer costs the host, kept past the ring's
+        # wrap: the weights' draw (`model.init`) and the state's placement
+        # (`trainer.place_state`) are children, the compile stages theirs
+        with spans.span("trainer.init", keep=True):
+            self._init(lm, mesh, config, optimizer)
+
+    def _init(self, lm, mesh, config, optimizer) -> None:
         self.lm = lm
         self.mesh = mesh
         self.config = config or LMTrainConfig()
@@ -504,56 +513,57 @@ class LMTrainer:
             if sp is not None
             else None
         )
-        if self._engine_mode:
-            # Partition-engine path: the DENSE loss on the global batch;
-            # XLA's SPMD partitioner derives the per-device program and
-            # collectives from the rule-matched shardings (tp rules give
-            # the Megatron layout without a tensor-parallel loss fn).
-            def engine_loss(p, batch, key):
-                (tokens,) = batch
-                logits, _ = self.lm.apply(cast(p), {}, tokens)
-                with jax.named_scope("loss"):  # the f32 logits are the loss's
-                    return lm_loss(logits.astype(jnp.float32), tokens), {}
+        with spans.span("trainer.place_state", keep=True):
+            if self._engine_mode:
+                # Partition-engine path: the DENSE loss on the global batch;
+                # XLA's SPMD partitioner derives the per-device program and
+                # collectives from the rule-matched shardings (tp rules give
+                # the Megatron layout without a tensor-parallel loss fn).
+                def engine_loss(p, batch, key):
+                    (tokens,) = batch
+                    logits, _ = self.lm.apply(cast(p), {}, tokens)
+                    with jax.named_scope("loss"):  # the f32 logits are the loss's
+                        return lm_loss(logits.astype(jnp.float32), tokens), {}
 
-            built = parallel.make_partitioned_train_step(
-                engine_loss, self.optimizer, mesh, params, self._ruleset,
-                accum_steps=self.config.accum_steps,
-                compress=self._compress,
-            )
-            self.params, self.opt_state = built.params, built.opt_state
-            self._param_template = jax.tree.map(
-                lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params
-            )
-            self._partition = built
+                built = parallel.make_partitioned_train_step(
+                    engine_loss, self.optimizer, mesh, params, self._ruleset,
+                    accum_steps=self.config.accum_steps,
+                    compress=self._compress,
+                )
+                self.params, self.opt_state = built.params, built.opt_state
+                self._param_template = jax.tree.map(
+                    lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params
+                )
+                self._partition = built
 
-            def engine_step(p, ms, os_, batch, key):
-                p2, o2, loss, aux = built.step(p, os_, batch, key)
-                built.report_attention(self.config.log)  # once traced
-                return p2, ms, o2, loss, aux
+                def engine_step(p, ms, os_, batch, key):
+                    p2, o2, loss, aux = built.step(p, os_, batch, key)
+                    built.report_attention(self.config.log)  # once traced
+                    return p2, ms, o2, loss, aux
 
-            self.step = engine_step
-        else:
-            extra = ()
-            if tp is not None:
-                extra = (self.config.model_axis,)
-            elif sp is not None:
-                extra = (self.config.seq_axis,)
-            self.params = parallel.replicate(params, mesh)
-            self.opt_state = parallel.replicate(
-                self.optimizer.init(params), mesh
-            )
-            assert_no_aliasing(self.params, self.opt_state)
-            self.step = parallel.make_spmd_train_step(
-                loss_fn, self.optimizer, mesh,
-                accum_steps=self.config.accum_steps,
-                extra_grad_axes=extra,
-                # pipeline: per-rank grads PARTITION the dense gradient
-                # over stages — sum, don't average
-                grad_psum_axes=(
-                    (self.config.pipe_axis,) if pp is not None else ()
-                ),
-                batch_spec=self._batch_spec,
-            )
+                self.step = engine_step
+            else:
+                extra = ()
+                if tp is not None:
+                    extra = (self.config.model_axis,)
+                elif sp is not None:
+                    extra = (self.config.seq_axis,)
+                self.params = parallel.replicate(params, mesh)
+                self.opt_state = parallel.replicate(
+                    self.optimizer.init(params), mesh
+                )
+                assert_no_aliasing(self.params, self.opt_state)
+                self.step = parallel.make_spmd_train_step(
+                    loss_fn, self.optimizer, mesh,
+                    accum_steps=self.config.accum_steps,
+                    extra_grad_axes=extra,
+                    # pipeline: per-rank grads PARTITION the dense gradient
+                    # over stages — sum, don't average
+                    grad_psum_axes=(
+                        (self.config.pipe_axis,) if pp is not None else ()
+                    ),
+                    batch_spec=self._batch_spec,
+                )
         self._model_state = parallel.replicate({}, mesh)
         # Pipeline-schedule accounting for telemetry (static per step):
         # the measured bubble fraction of the executed table.

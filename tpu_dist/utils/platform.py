@@ -21,8 +21,6 @@ from pathlib import Path
 # never hits.
 DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
-_cache_listener_installed = False
-
 
 def setup_compile_cache() -> str:
     """Place JAX's persistent compilation cache and return its directory.
@@ -35,11 +33,11 @@ def setup_compile_cache() -> str:
     ``benchmarks/*.py``, the demos, `comm.init`); idempotent.
 
     Every cache hit/miss surfaces as telemetry: a ``compile_cache`` event
-    (when ``TPU_DIST_TELEMETRY`` is set) and the
-    ``tpu_dist_compile_cache_{hits,misses}_total`` registry counters, via
-    a `jax.monitoring` listener installed on the first call.
+    (when ``TPU_DIST_TELEMETRY`` is set), the
+    ``tpu_dist_compile_cache_{hits,misses}_total`` registry counters and
+    the ``cache`` attribute of a kept ``compile.backend`` span, through
+    `observe.compile_spans`' `jax.monitoring` listeners.
     """
-    global _cache_listener_installed
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -53,35 +51,9 @@ def setup_compile_cache() -> str:
             from jax._src import compilation_cache
 
             compilation_cache.reset_cache()
-    if _cache_listener_installed:
-        return path
-    _cache_listener_installed = True
+    from tpu_dist.observe import compile_spans
 
-    from tpu_dist.observe import events as events_mod
-    from tpu_dist.observe import registry
-
-    hits = registry.REGISTRY.counter(
-        "tpu_dist_compile_cache_hits_total",
-        "XLA programs loaded from the persistent compilation cache",
-    )
-    misses = registry.REGISTRY.counter(
-        "tpu_dist_compile_cache_misses_total",
-        "XLA programs compiled and written to the persistent cache",
-    )
-
-    def _listen(event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            hits.inc()
-            events_mod.from_env().emit(
-                "compile_cache", outcome="hit", dir=path
-            )
-        elif event == "/jax/compilation_cache/cache_misses":
-            misses.inc()
-            events_mod.from_env().emit(
-                "compile_cache", outcome="miss", dir=path
-            )
-
-    jax.monitoring.register_event_listener(_listen)
+    compile_spans.install()
     return path
 
 
