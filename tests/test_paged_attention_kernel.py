@@ -18,6 +18,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from tpu_dist import nn, ops
 from tpu_dist.ops import paged_attention
@@ -75,6 +76,31 @@ def _pools(rng, row, lengths, dtype, window=None):
     return tables, clean, dirty
 
 
+def _assert_is_the_view(got, attn, q, clean, tables, lengths, dtype):
+    """The kernel's rows ``got`` against the gathered view over the clean
+    pools: zeros for a slot that holds nothing, else the view's numbers."""
+    n = jnp.asarray(lengths, jnp.int32)
+
+    def view(dt):
+        o = _gathered_attention(
+            (q * attn.scale).astype(dt), *jnp.asarray(clean, dt), tables,
+            jnp.maximum(n, 1)[:, None] - 1,
+            sliding_window=attn.sliding_window)
+        return np.asarray(o.astype(jnp.float32))[:, :, 0]
+
+    held = np.asarray(lengths) > 0
+    assert np.isfinite(got).all()
+    assert (got[~held] == 0).all()  # read nothing, wrote zeros
+    exact = view(jnp.float32)  # on the same (rounded) numbers
+    if dtype == "float32":
+        np.testing.assert_allclose(got[held], exact[held], rtol=1e-5, atol=1e-5)
+    elif held.any():
+        # the dense path's own tolerance: what the gathered view loses in
+        # bfloat16 (its statistics included; the kernel's are float32)
+        dense = np.abs(view(jnp.bfloat16) - exact)[held].max()
+        assert np.abs(got - exact)[held].max() <= max(dense, 2.0 ** -7)
+
+
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_kernel_matches_the_gathered_view(case, monkeypatch):
     name, dtype, lengths, chunk = case
@@ -95,25 +121,7 @@ def test_kernel_matches_the_gathered_view(case, monkeypatch):
     ))(q, *jnp.asarray(dirty, dtype))
     assert got.shape == (S, heads, hd) and got.dtype == q.dtype
     got = np.asarray(got.astype(jnp.float32))
-
-    def view(dt):
-        o = _gathered_attention(
-            (q * attn.scale).astype(dt), *jnp.asarray(clean, dt), tables,
-            jnp.maximum(n, 1)[:, None] - 1,
-            sliding_window=attn.sliding_window)
-        return np.asarray(o.astype(jnp.float32))[:, :, 0]
-
-    held = np.asarray(lengths) > 0
-    assert np.isfinite(got).all()
-    assert (got[~held] == 0).all()  # read nothing, wrote zeros
-    exact = view(jnp.float32)  # on the same (rounded) numbers
-    if dtype == "float32":
-        np.testing.assert_allclose(got[held], exact[held], rtol=1e-5, atol=1e-5)
-    elif held.any():
-        # the dense path's own tolerance: what the gathered view loses in
-        # bfloat16 (its statistics included; the kernel's are float32)
-        dense = np.abs(view(jnp.bfloat16) - exact)[held].max()
-        assert np.abs(got - exact)[held].max() <= max(dense, 2.0 ** -7)
+    _assert_is_the_view(got, attn, q, clean, tables, lengths, dtype)
 
 
 @pytest.mark.parametrize("name", LAYOUTS)
@@ -162,3 +170,146 @@ def test_shapes_that_hold_no_heads_are_refused(bad):
     with pytest.raises(ValueError, match="heads"):
         ops.paged_attention_decode(q, pool, pool, jnp.zeros((2, 2), jnp.int32),
                                    jnp.ones((2,), jnp.int32), interpret=True)
+
+
+def _plain_schedule(block_tables, lengths, bs, G, window):
+    """The grid's tables laid out one (step, operand) place at a time, over
+    ``slots * ceil(max_blocks / G)`` steps whatever a window can reach: the
+    form `paged_attention._schedule` had before it was laid out by rows,
+    kept as its plain reference.  Also -> which places are held."""
+    S, MB = block_tables.shape
+    first = (jnp.maximum(lengths - window, 0) // bs if window is not None
+             else jnp.zeros_like(lengths))
+    last = (lengths + bs - 1) // bs
+    chunks = (last - first + G - 1) // G
+    ends = jnp.cumsum(chunks)
+    t = jnp.arange(S * -(-MB // G), dtype=jnp.int32)
+    slot = jnp.minimum(
+        (t[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32), S - 1)
+    chunk = t - (ends - chunks)[slot]
+    j = (first[slot][:, None] + chunk[:, None] * G
+         + jnp.arange(G, dtype=jnp.int32))
+    held = (j < last[slot][:, None]) & (t < ends[-1])[:, None]
+    ids = block_tables[slot[:, None], jnp.minimum(j, MB - 1)]
+    fetched = lax.cummax(jnp.where(held, t[:, None], -1), axis=0)
+    ids = jnp.where(
+        fetched >= 0,
+        jnp.take_along_axis(ids, jnp.maximum(fetched, 0), axis=0),
+        ids[0, 0])
+    return (jnp.maximum(ends[-1], 1), slot, chunk, ids.reshape(-1), first,
+            chunks), held
+
+
+def _assert_same_grid(tables, lengths, bs, G, window):
+    """`_schedule`'s tables are the plain ones for every step of the grid,
+    and an un-held place names what its operand named one step earlier
+    (the pipeline then fetches nothing for it)."""
+    tables = jnp.asarray(tables, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    want, held = _plain_schedule(tables, lengths, bs, G, window)
+    got = paged_attention._schedule(tables, lengths, bs, G, window)
+    steps = int(want[0])
+    assert int(got[0]) == steps <= got[1].shape[0] == got[2].shape[0]
+    assert got[3].shape[0] == got[1].shape[0] * G
+    for name, a, b, n in (("slot", got[1], want[1], steps),
+                          ("chunk", got[2], want[2], steps),
+                          ("ids", got[3], want[3], steps * G),
+                          ("first", got[4], want[4], None),
+                          ("chunks", got[5], want[5], None)):
+        np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b)[:n],
+                                      err_msg=name)
+    ids = np.asarray(got[3])[:steps * G].reshape(steps, G)
+    before = np.concatenate([np.full((1, G), ids[0, 0]), ids[:-1]])
+    loose = ~np.asarray(held)[:steps]
+    np.testing.assert_array_equal(ids[loose], before[loose])
+    return got
+
+
+SCHEDULE_BS = 4
+# (G, max_blocks): the table narrower than a chunk, one chunk wide, no
+# whole number of chunks
+SCHEDULE_SHAPES = [(G, MB) for G in (1, 2, 8, 16)
+                   for MB in sorted({max(G - 1, 1), G, 2 * G + 1})]
+
+
+def _window(kind, G, MB):
+    return {"none": None, "in_a_block": SCHEDULE_BS - 1,
+            "in_a_chunk": G * SCHEDULE_BS - 1 if G > 1 else SCHEDULE_BS + 1,
+            "in_the_table": max(MB * SCHEDULE_BS - 2 * SCHEDULE_BS, 1),
+            }[kind]
+
+
+@pytest.mark.parametrize("lengths", ["ragged", "empty", "one_slot"])
+@pytest.mark.parametrize("window", ["none", "in_a_block", "in_a_chunk",
+                                    "in_the_table"])
+@pytest.mark.parametrize("G,MB", SCHEDULE_SHAPES)
+def test_schedule_is_the_plain_one(G, MB, window, lengths):
+    rng = np.random.default_rng(G * 100 + MB)
+    S = 1 if lengths == "one_slot" else 7
+    tables = rng.permutation(S * MB).reshape(S, MB)
+    places = MB * SCHEDULE_BS
+    for _ in range(3):
+        n = rng.integers(0, places + 1, S)
+        if lengths == "empty":
+            n[:] = 0
+        elif lengths == "ragged":  # zeros among them, a full one, edges
+            n[rng.integers(S)] = places
+            n[rng.random(S) < 0.3] = 0
+            n[rng.integers(S)] = min(SCHEDULE_BS + 1, places)
+        _assert_same_grid(tables, n, SCHEDULE_BS, G, _window(window, G, MB))
+
+
+RING = dict(window=4096, chunk=256, bs=16, max_blocks=1536)  # a long-context server's
+
+
+@pytest.mark.parametrize("lengths", [
+    "ragged", "empty", "full", "at_the_wrap"])
+def test_schedule_of_a_ring_is_bounded_by_its_window(lengths):
+    """A ring's table is as wide as the pool's (writes index it by
+    position), 272 blocks repeating over 1,536 columns; its schedule is
+    laid out over what the window can reach, 17 chunks a slot, and is the
+    plain one's for every step of the grid."""
+    bs, W, MB = RING["bs"], RING["window"], RING["max_blocks"]
+    blocks = paged_kv.ring_blocks(W, RING["chunk"], bs)
+    assert blocks == 272
+    S, G = 5, paged_attention.CHUNK_TOKENS // bs
+    tables = paged_kv.ring_tables(None, S, blocks, MB)
+    n = {"ragged": np.array([0, 3, W - 1, 9000, MB * bs]),
+         "empty": np.zeros(S, int),
+         "full": np.full(S, MB * bs),
+         "at_the_wrap": blocks * bs + np.arange(-2, 3) * (bs - 1),
+         }[lengths]
+    got = _assert_same_grid(tables, n, bs, G, W)
+    assert got[1].shape[0] == S * 17 < S * MB // G
+
+
+# before, at and past the ring's wrap (its 4,352 rows), and a table's end
+RING_LENGTHS = [0, 1, 4095, 4096, 4097, 4351, 4352, 4353, 4352 + 4096 + 7, 24576]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernel_reads_a_ring_as_the_gathered_view_does(dtype):
+    """The kernel under a ring's wrapping table and the window-bounded
+    schedule, against the gathered view through the same table: the rings
+    carry NaN wherever a slot's window does not reach."""
+    bs, W, MB = RING["bs"], RING["window"], RING["max_blocks"]
+    blocks = paged_kv.ring_blocks(W, RING["chunk"], bs)
+    attn = nn.MultiHeadAttention(256, 2, causal=True, kv_heads=1, head_dim=128,
+                                 sliding_window=W)
+    S, row = len(RING_LENGTHS), attn.kv_heads * attn.head_dim
+    rng = np.random.default_rng(11)
+    tables = np.asarray(paged_kv.ring_tables(None, S, blocks, MB))
+    clean = rng.normal(size=(2, S * blocks + 1, bs, row)).astype(np.float32)
+    clean = np.asarray(jnp.asarray(clean, dtype).astype(jnp.float32))
+    dirty = np.full_like(clean, np.nan)
+    for s, n in enumerate(RING_LENGTHS):
+        for j in range(max(n - W, 0) // bs, -(-n // bs)):  # the visible blocks
+            dirty[:, tables[s, j]] = clean[:, tables[s, j]]
+    q = jnp.asarray(rng.normal(size=(S, 2, 1, 128)), dtype)
+    n = jnp.asarray(RING_LENGTHS, jnp.int32)
+
+    got = jax.jit(lambda q, k, v: ops.paged_attention_decode(
+        (q * attn.scale)[:, :, 0], k, v, tables, n, sliding_window=W,
+        interpret=True))(q, *jnp.asarray(dirty, dtype))
+    _assert_is_the_view(np.asarray(got.astype(jnp.float32)), attn, q, clean,
+                        tables, RING_LENGTHS, dtype)

@@ -11,7 +11,7 @@ of length 0.  Nothing is gathered, reshaped or repeated in HBM, so a
 step costs what the held tokens cost, not ``slots * max_blocks *
 block_size`` places.
 
-**Grid.**  One step attends a CHUNK of ``CHUNK_TOKENS // block_size``
+**Grid.**  One step attends a CHUNK of ``G = CHUNK_TOKENS // block_size``
 blocks of one slot (each block an operand of its own: the same pool,
 another index map), and the grid is the list of chunks that hold
 something, slot after slot — `_schedule` lays it out on the device from
@@ -19,7 +19,16 @@ the lengths, and its length is the grid's one, dynamic, bound.  A slot
 that holds nothing has no step; a chunk's tail past its slot's last
 block names the block that operand fetched last, which the pipeline
 does not fetch again.  The pipeline fetches step ``t + 1`` while step
-``t`` computes, across slots too.
+``t`` computes, across slots too.  The tables the scalar core is handed
+are built by ROWS of ``G`` ids, never one (step, operand) place at a
+time: a slot's chunks are one slice of its table's row, the fill of a
+tail is the row above or a carry down the slots, and one gather of rows
+puts the slots' chunks one after another.  Their static length is
+``slots * C``, ``C`` the chunks a slot can have: ``ceil(max_blocks /
+G)``, and under ``sliding_window = w`` no more than the
+``ceil((w + block_size - 1) / block_size)`` blocks a window can reach —
+a ring's table as wide as the pool's (`serve.paged_kv.ring_tables`)
+costs a schedule of its window's length.
 
 **A step.**  The softmax streams over a slot's chunks (running max,
 denominator and accumulator in float32 scratch; the two products take
@@ -80,28 +89,75 @@ def _schedule(block_tables, lengths, bs: int, G: int, window: int | None):
     of its table, ``g < G``, of which ``ids[t * G + g]`` is the pool's
     id, or, past the slot's last block, the id operand ``g`` held last
     (no fetch).  ``chunks[s]`` is 0 for a slot that holds nothing; the
-    grid has ``max(sum(chunks), 1)`` steps."""
+    grid has ``max(sum(chunks), 1)`` steps.
+
+    The tables are ``S * C`` steps long, ``C`` the most chunks a slot can
+    have: its table's ``ceil(max_blocks / G)``, or, under a window, the
+    ``ceil((window + bs - 1) / bs)`` blocks a window can reach, whatever
+    the table's width.  They are laid out SLOT-MAJOR first, by rows of
+    ``G`` ids: slot ``s``'s ``C`` chunks are its table's row from column
+    ``first[s]`` on, and only a slot's last chunk has a tail, which names
+    the chunk above it or, in a slot of one chunk, what the operand held
+    when the last slot that had a step ended (a carry down the ``S``
+    slots).  One gather of rows then lists the chunks that hold
+    something, slot after slot.  Nothing is fetched or filled one (step,
+    operand) place at a time, and nothing is scanned down the steps."""
     S, MB = block_tables.shape
     first = (jnp.maximum(lengths - window, 0) // bs if window is not None
              else jnp.zeros_like(lengths))
     last = (lengths + bs - 1) // bs  # blocks first .. last-1 are visible
     chunks = (last - first + G - 1) // G
     ends = jnp.cumsum(chunks)
-    t = jnp.arange(S * -(-MB // G), dtype=jnp.int32)
-    slot = jnp.minimum(
-        (t[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32), S - 1)
-    chunk = t - (ends - chunks)[slot]
-    j = (first[slot][:, None] + chunk[:, None] * G
-         + jnp.arange(G, dtype=jnp.int32))
-    held = (j < last[slot][:, None]) & (t < ends[-1])[:, None]
-    ids = block_tables[slot[:, None], jnp.minimum(j, MB - 1)]
-    # forward fill: the step that last fetched a block into operand g
-    # (before its first, the grid's first block: held, if anything is)
-    fetched = lax.cummax(jnp.where(held, t[:, None], -1), axis=0)
-    ids = jnp.where(
-        fetched >= 0,
-        jnp.take_along_axis(ids, jnp.maximum(fetched, 0), axis=0),
-        ids[0, 0])
+    C = -(-MB // G)
+    if window is not None:
+        C = min(C, -(-(-(-(window + bs - 1) // bs)) // G))
+    t = jnp.arange(S * C, dtype=jnp.int32)
+    after = t[:, None] >= ends[None, :]
+    slot = jnp.minimum(after.sum(axis=1, dtype=jnp.int32), S - 1)
+    # a slot starts where the last one that had a step ended
+    chunk = t - jnp.max(jnp.where(after, ends[None, :], 0), axis=1)
+
+    # slot-major, (S, C, G): chunk c of slot s, held or not; a column past
+    # the table's end reads its last
+    if window is None:  # from column 0
+        ids = jnp.pad(block_tables, ((0, 0), (0, C * G - MB)), mode="edge")
+    else:
+        # C * G columns from `first` on.  The TPU gathers whole rows only:
+        # the aligned groups of G columns that hold them, then the G
+        # shifts a `first % G` can ask for, of which each slot takes its own
+        groups = -(-MB // G) + C + 1
+        wide = jnp.pad(block_tables, ((0, 0), (0, groups * G - MB)), mode="edge")
+        rows = ((jnp.arange(S, dtype=jnp.int32) * groups + first // G)[:, None]
+                + jnp.arange(C + 1, dtype=jnp.int32))
+        near = jnp.take(wide.reshape(S * groups, G), rows.reshape(-1), axis=0,
+                        mode="clip").reshape(S, (C + 1) * G)
+        shifts = jnp.stack([near[:, g:g + C * G] for g in range(G)], axis=1)
+        ids = jnp.take_along_axis(shifts, (first % G)[:, None, None], axis=1)
+    ids = ids.reshape(S, C, G)
+    column = first[:, None] + jnp.arange(C * G, dtype=jnp.int32)
+    held = (column < last[:, None]).reshape(S, C, G)
+    above = jnp.concatenate([ids[:, :1], ids[:, :-1]], axis=1)
+    # what each operand holds when its slot ends: nothing new where the
+    # slot has no step, or one chunk that does not reach the operand
+    tail = jnp.maximum(chunks - 1, 0)
+    ended = jnp.take_along_axis(
+        jnp.where(held, ids, above), tail[:, None, None], axis=1)[:, 0]
+    fresh = (chunks > 1)[:, None] | (
+        (first + tail * G)[:, None] + jnp.arange(G, dtype=jnp.int32)
+        < last[:, None])  # ... or its one chunk holds a block for it
+    # ... carried down the slots (before the first, the grid's first block:
+    # held, if anything is)
+    source = lax.cummax(
+        jnp.where(fresh, jnp.arange(S, dtype=jnp.int32)[:, None], -1), axis=0)
+    before = jnp.broadcast_to(ids[slot[0], 0, 0], (1, G))
+    carried = jnp.where(
+        source >= 0,
+        jnp.take_along_axis(ended, jnp.maximum(source, 0), axis=0), before)
+    entering = jnp.concatenate([before, carried[:-1]], axis=0)
+    ids = jnp.where(held, ids, jnp.where(
+        jnp.arange(C)[None, :, None] > 0, above, entering[:, None, :]))
+    # the chunks that hold something, slot after slot: one gather of rows
+    ids = jnp.take(ids.reshape(S * C, G), slot * C + chunk, axis=0, mode="clip")
     return jnp.maximum(ends[-1], 1), slot, chunk, ids.reshape(-1), first, chunks
 
 
