@@ -239,6 +239,10 @@ def test_the_engine_serves_the_references_tokens(model):
     one's lay (nothing is reset at admission); prompts that end inside a
     chunk, prefill chunks beside decode, eviction and refill; answers long
     enough that every ring wraps."""
+    from tpu_dist.observe.registry import REGISTRY
+
+    total = lambda name: REGISTRY.counter(f"tpu_dist_serve_{name}_total").value()  # noqa: E731
+    before = {name: total(name) for name in ("attn_rows_attended", "swa_rows_unwindowed", "swa_rows_attended", "moe_picks", "moe_picks_held")}   # the registry is the process's
     lm, params, p_ref = model
     eng = ServeEngine(lm, params, ServeConfig(
         max_batch=3, block_size=8, num_blocks=36, max_seq=96, prefill_chunk=16, prefill_batch=2))
@@ -251,9 +255,7 @@ def test_the_engine_serves_the_references_tokens(model):
         assert _reference_gap(p_ref, p, results[i].tokens) < ATOL
     assert eng.allocator.used == 0
     # the model's own counters rode the decode readback into the registry
-    from tpu_dist.observe.registry import REGISTRY
-
-    count = lambda name: REGISTRY.counter(f"tpu_dist_serve_{name}_total").value()  # noqa: E731
+    count = lambda name: total(name) - before[name]  # noqa: E731
     n_full = CFG["layer_types"].count("full_attention")
     n_swa = CFG["layer_types"].count("sliding_attention")
     # every position of every request but its last token was a query once

@@ -942,6 +942,55 @@ class TestTheRingsAreReadThroughTheKernelOnTheV5e:
         assert not copies, "\n".join(copies[:4])
 
 
+@pytest.fixture(scope="module")
+def v5e_shortcut_engine():
+    """LongCat-Flash's layer at a small size: two sublayers of ungated
+    latent attention over every row (a latent row of 128 + 8 values, kept as
+    256 lanes), a dense feed-forward each, one routed branch with zero
+    experts beside them."""
+    from tpu_dist import serve
+
+    sizes = dict(heads=4, q_rank=64, kv_rank=128, nope_dim=32, rope_dim=8, v_dim=32,
+                 rope_base=1e7, gated=False)
+    lm = models.HybridLM(
+        vocab=128, dim=128, layer_types=["latent_attention"] * 2,
+        mixers={"latent_attention": sizes}, shortcut=2, n_experts=12, zero_experts=4,
+        experts_per_token=3, expert_width=32, held_experts=(0, 4), expert_scoring="softmax",
+        route_scale=6.0, dense_width=64, tied_head=False, max_seq=64)
+    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), lm.init(jax.random.key(0))[0])
+    return serve.ServeEngine(lm, params, serve.ServeConfig(
+        max_batch=4, block_size=16, num_blocks=256, max_seq=64, prefill_chunk=16,
+        prefill_batch=2))
+
+
+class TestTheLatentPoolIsReadWhereItLiesOnTheV5e:
+    """A latent layer with neither selection nor window keeps ONE pool, and
+    compiled for the v5e the decode program reads it through the kernel
+    `paged_latent_decode`, one call a sublayer, with no gathered view of the
+    pool and no pool-sized copy; prefill still gathers its view."""
+
+    @pytest.mark.parametrize("program,rows,kernels", [("serve_decode_greedy", None, 2),
+                                                      ("serve_prefill", 2, 0)])
+    def test_a_kernel_a_sublayer_and_no_pool_sized_copy(self, v5e_shortcut_engine, v5e_chip,
+                                                        program, rows, kernels):
+        import math
+        import re
+
+        text, _, cache = _compiled_for_the_v5e(v5e_shortcut_engine, v5e_chip, program, rows)
+        assert [{k: v.shape for k, v in kv.items()} for kv in cache["kv"]] == [
+            {"ckv": (257, 16, 256)}] * 2
+        calls = re.findall(r"^\s*%paged_latent_decode\S* = .*custom-call\(.*"
+                           r'custom_call_target="tpu_custom_call"', text, re.M)
+        assert len(calls) == kernels
+        pool, view = 257 * 16 * 256, 4 * 64 * 256   # the view: every slot's whole table
+        moved = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+(copy|gather)\(")
+        least = {"copy": pool // 4, "gather": view if rows is None else pool}
+        big = [line.strip()[:160] for line in text.splitlines()
+               if (m := moved.search(line))
+               and math.prod(int(d) for d in m.group(1).split(",") if d) >= least[m.group(2)]]
+        assert not big, "\n".join(big[:4])
+
+
 def test_the_environment_decides_nothing_that_is_compiled():
     """The ``TPU_DIST_*`` names `tpu_dist/` knows are a deployment's: where
     telemetry, metrics and dumps go, where the data is, how processes find

@@ -145,9 +145,16 @@ def test_the_engine_serves_the_dense_paths_tokens(model):
     """More requests than slots, so every slot is reused after an eviction
     (a state not reset at admission would carry the last request's over);
     prompts that end inside a chunk; the normal path: submit, step."""
+    from tpu_dist.observe.registry import REGISTRY
+
     lm, params, _ = model
     eng = ServeEngine(lm, params, ServeConfig(
         max_batch=3, block_size=8, num_blocks=36, max_seq=96, prefill_chunk=16, prefill_batch=2))
+    # the registry is the process's: what other tests' engines counted is taken off
+    total = lambda name, **kw: REGISTRY.counter(f"tpu_dist_serve_{name}_total").value(**kw)  # noqa: E731
+    experts = [str(e) for e in range(*CFG["held_experts"])]
+    before = {name: total(name) for name in ("moe_picks", "moe_picks_held", "moe_experts_hit")}
+    before_expert = [total("moe_expert_tokens", expert=e) for e in experts]
     prompts = [_tokens((n,), seed=100 + n) for n in (5, 16, 23, 40, 17, 33, 9, 48)]
     ids = [eng.submit(p, 7) for p in prompts]
     results = eng.run_until_drained()
@@ -158,15 +165,12 @@ def test_the_engine_serves_the_dense_paths_tokens(model):
         assert results[i].tokens.tolist() == _dense_greedy(apply, p, 7)
     assert eng.allocator.used == 0
     # the model's own counters rode the decode readback into the registry
-    from tpu_dist.observe.registry import REGISTRY
-
-    picks = REGISTRY.counter("tpu_dist_serve_moe_picks_total").value()
-    held = REGISTRY.counter("tpu_dist_serve_moe_picks_held_total").value()
-    per_expert = [REGISTRY.counter("tpu_dist_serve_moe_expert_tokens_total").value(expert=str(e))
-                  for e in range(*CFG["held_experts"])]
+    picks = total("moe_picks") - before["moe_picks"]
+    held = total("moe_picks_held") - before["moe_picks_held"]
+    per_expert = [total("moe_expert_tokens", expert=e) - b for e, b in zip(experts, before_expert)]
     assert picks > 0 and 0 < held < picks and sum(per_expert) == held
     # a held expert given a token in a layer's call is one whose weights were read
-    hit = REGISTRY.counter("tpu_dist_serve_moe_experts_hit_total").value()
+    hit = total("moe_experts_hit") - before["moe_experts_hit"]
     assert 0 < hit <= held
     assert picks % (CFG["num_experts_per_tok"] * CFG["num_hidden_layers"]) == 0
 

@@ -222,6 +222,10 @@ def test_the_engine_serves_the_references_tokens(model):
     slot has a second tenant whose ring and pool rows lie where the first
     one's lay (nothing is reset at admission); prompts that end inside a
     chunk, prefill chunks beside decode, eviction and refill."""
+    from tpu_dist.observe.registry import REGISTRY
+
+    total = lambda name: REGISTRY.counter(f"tpu_dist_serve_{name}_total").value()  # noqa: E731
+    before = {name: total(name) for name in ("dsa_keys_scored", "dsa_rows_selected", "swa_rows_attended", "moe_picks", "moe_picks_held")}   # the registry is the process's
     lm, params, p_ref = model
     eng = ServeEngine(lm, params, ServeConfig(
         max_batch=3, block_size=8, num_blocks=36, max_seq=96, prefill_chunk=16, prefill_batch=2))
@@ -234,9 +238,7 @@ def test_the_engine_serves_the_references_tokens(model):
         assert _reference_gap(p_ref, p, results[i].tokens) < ATOL
     assert eng.allocator.used == 0
     # the model's own counters rode the decode readback into the registry
-    from tpu_dist.observe.registry import REGISTRY
-
-    count = lambda name: REGISTRY.counter(f"tpu_dist_serve_{name}_total").value()  # noqa: E731
+    count = lambda name: total(name) - before[name]  # noqa: E731
     n_full = CFG["layer_types"].count("full_attention")
     n_swa = CFG["layer_types"].count("sliding_attention")
     # every position of every request but its last token was a query once
@@ -307,7 +309,7 @@ def test_sigmoid_scoring_is_a_loop_over_the_experts_and_the_bias_only_selects():
         same, _ = routed_experts(x, router, w_in, w_out, bias=bias + 7.0, **kw)
         np.testing.assert_allclose(np.asarray(same), np.asarray(got), atol=1e-6)
         with pytest.raises(ValueError, match="scoring"):
-            routed_experts(x, router, w_in, w_out, top_k=3, scoring="softmax")
+            routed_experts(x, router, w_in, w_out, top_k=3, scoring="tanh")
 
 
 def test_the_older_scoring_is_bit_for_bit_what_it_was():

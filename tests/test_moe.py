@@ -4,6 +4,7 @@ implementation of the same routing."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tests.conftest import spmd_run as run
 from tpu_dist import comm
@@ -459,3 +460,85 @@ class TestExpertChoice:
 
         ys = run(fn, x, gate_w, ups, downs, world=2)
         assert np.isfinite(np.asarray(ys)).all()
+
+
+class TestZeroComputeExperts:
+    """`routed_experts(scoring="softmax", zero_experts=n)`: the router's last
+    ``n`` outputs have no weights; a pick on one adds ``g * x``."""
+
+    D, E, ZERO, WIDTH, K = 16, 6, 3, 8, 3
+
+    def _weights(self, seed=31):
+        k = jax.random.split(jax.random.key(seed), 5)
+        return (jax.random.normal(k[0], (14, self.D)),
+                jax.random.normal(k[1], (self.D, self.E + self.ZERO)) * 0.7,
+                jax.random.normal(k[2], (self.E, self.D, 2 * self.WIDTH)) * 0.3,
+                jax.random.normal(k[3], (self.E, self.WIDTH, self.D)) * 0.3,
+                jax.random.normal(k[4], (self.E + self.ZERO,)) * 0.02)
+
+    def _loop(self, x, router, w_in, w_out, bias, held=None, scale=1.0):
+        """The definition, one token and one pick at a time."""
+        from jax import lax
+
+        lo, hi = held or (0, self.E)
+        prob = jax.nn.softmax(jnp.dot(x, router, precision=lax.Precision.HIGHEST), axis=-1)
+        _, idx = lax.top_k(prob + bias, self.K)
+        y, zero = np.zeros(x.shape, np.float32), 0
+        for t in range(x.shape[0]):
+            for e in idx[t].tolist():
+                g = scale * float(prob[t, e])
+                if e >= self.E:
+                    y[t] += g * np.asarray(x[t])
+                    zero += 1
+                elif lo <= e < hi:
+                    a, b = np.split(np.asarray(x[t] @ w_in[e - lo]), 2)
+                    y[t] += g * np.asarray((jax.nn.silu(a) * b) @ w_out[e - lo])
+        return y, idx, zero
+
+    @pytest.mark.parametrize("held", [None, (2, 5)])
+    def test_is_a_loop_over_the_picks_with_the_scores_unnormalised(self, held):
+        from tpu_dist.parallel.moe import routed_experts
+
+        x, router, w_in, w_out, bias = self._weights()
+        lo, hi = held or (0, self.E)
+        with jax.default_matmul_precision("highest"):
+            want, idx, zero = self._loop(x, router, w_in[lo:hi], w_out[lo:hi], bias, held, 6.0)
+            got, c = routed_experts(x, router, w_in[lo:hi], w_out[lo:hi], top_k=self.K, held=held,
+                                    scoring="softmax", bias=bias, scale=6.0,
+                                    zero_experts=self.ZERO)
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+        assert int(c["picks"]) == 14 * self.K and int(c["picks_zero"]) == zero > 0
+        assert int(c["picks_held"]) == int(((idx >= lo) & (idx < hi)).sum())
+        # the default range is the experts that HAVE weights, not the router's width
+        if held is None:
+            assert c["expert_tokens"].shape == (self.E,)
+
+    def test_a_token_whose_picks_are_all_zero_gets_its_gates_times_itself(self):
+        """... and hands the grouped product no row; `picks_zero` counts it."""
+        from jax import lax
+
+        from tpu_dist.parallel.moe import routed_experts
+
+        x, router, w_in, w_out, _ = self._weights(seed=32)
+        bias = jnp.where(jnp.arange(self.E + self.ZERO) >= self.E, 5.0, 0.0)   # the zero experts win
+        got, c = routed_experts(x, router, w_in, w_out, top_k=self.K, scoring="softmax", bias=bias,
+                                scale=6.0, zero_experts=self.ZERO)
+        prob = jax.nn.softmax(jnp.dot(x, router, precision=lax.Precision.HIGHEST), axis=-1)
+        gates = 6.0 * prob[:, self.E:].sum(-1, keepdims=True)     # the bias enters no gate
+        np.testing.assert_allclose(np.asarray(got), np.asarray(gates * x), rtol=1e-5, atol=1e-6)
+        assert int(c["picks_zero"]) == 14 * self.K == int(c["picks"])
+        assert int(c["picks_held"]) == 0 and not np.asarray(c["expert_tokens"]).any()
+        # a pad token's picks count nowhere and add nothing
+        mask = jnp.arange(14) % 2 == 0
+        _, c = routed_experts(x, router, w_in, w_out, top_k=self.K, scoring="softmax", bias=bias,
+                              zero_experts=self.ZERO, mask=mask)
+        assert int(c["picks_zero"]) == 7 * self.K == int(c["picks"])
+
+    def test_without_zero_experts_nothing_is_counted_or_added(self):
+        from tpu_dist.parallel.moe import routed_experts
+
+        x, router, w_in, w_out, _ = self._weights()
+        _, c = routed_experts(x, router[:, :self.E], w_in, w_out, top_k=self.K, scoring="softmax")
+        assert "picks_zero" not in c
+        with pytest.raises(ValueError, match="held experts"):
+            routed_experts(x, router, w_in, w_out, top_k=self.K, scoring="softmax")  # 9 outputs, 6 weights

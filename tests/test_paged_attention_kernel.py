@@ -313,3 +313,97 @@ def test_kernel_reads_a_ring_as_the_gathered_view_does(dtype):
         interpret=True))(q, *jnp.asarray(dirty, dtype))
     _assert_is_the_view(np.asarray(got.astype(jnp.float32)), attn, q, clean,
                         tables, RING_LENGTHS, dtype)
+
+
+# ------------------------------------------------------- the latent pool
+
+
+def _latent_case(rng, lengths, dtype, *, heads=4, kv_rank=128, nope=16, rope=8):
+    """A `nn.LatentAttention` of ``kv_rank`` (the value: whole 128-lane
+    tiles) and a pool of its rows padded to 256 lanes, with NaN in every
+    block a slot does not hold; -> what the kernel and `absorbed` take."""
+    from tpu_dist.nn.latent_attention import LatentAttention
+
+    attn = LatentAttention(64, heads, q_rank=32, kv_rank=kv_rank, nope_dim=nope, rope_dim=rope,
+                           v_dim=16, rope_base=1e4, gated=False)
+    p = jax.tree.map(lambda a: jnp.asarray(a, dtype), attn.init(jax.random.key(3))[0])
+    S = len(lengths)
+    width = paged_kv._whole_tiles(attn.row)
+    tables, clean, dirty = _pools(rng, width, lengths, dtype)
+    clean, dirty = clean[0].copy(), dirty[0].copy()
+    clean[..., attn.row:] = 0.0    # the pad lanes of a written row are zero
+    dirty[..., attn.row:] = np.where(np.isnan(dirty[..., attn.row:]), np.nan, 0.0)
+    q_n = jnp.asarray(rng.normal(size=(S, 1, heads, nope)), dtype)
+    q_r = jnp.asarray(rng.normal(size=(S, 1, heads, rope)), dtype)
+    return attn, p, tables, clean, dirty, q_n, q_r
+
+
+@pytest.mark.parametrize("chunk", [256, 2 * BS, BS])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernel_matches_absorbed_over_the_gathered_view(dtype, chunk, monkeypatch):
+    """`ops.paged_latent.paged_latent_decode` (interpreted) against
+    `LatentAttention.absorbed` over the gathered view: ragged lengths, an
+    empty slot, a length that ends mid-block, several chunks a slot; the
+    kernel's pool carries NaN wherever a slot holds nothing.  float32 to
+    rounding; bfloat16 within what the absorbed form itself loses there."""
+    from tpu_dist.ops import paged_latent
+
+    monkeypatch.setattr(paged_latent, "CHUNK_TOKENS", chunk)
+    rng = np.random.default_rng(chunk)
+    attn, p, tables, clean, dirty, q_n, q_r = _latent_case(rng, LENGTHS, dtype)
+    n = jnp.asarray(LENGTHS, jnp.int32)
+    S, L = len(LENGTHS), MB * BS
+    q = jnp.concatenate([jnp.einsum("shd,hdr->shr", q_n[:, 0], p["w_uk"]), q_r[:, 0]], axis=-1)
+    got = jax.jit(lambda q, pool: paged_latent.paged_latent_decode(
+        paged_kv._padded(q, pool.shape[-1]), pool, tables, n, v_width=attn.kv_rank,
+        scale=attn.scale, interpret=True))(q, jnp.asarray(dirty, dtype))
+    got = np.asarray(jnp.einsum("shr,hrd->shd", got, p["w_uv"]).astype(jnp.float32))
+
+    def absorbed(dt):
+        pd = jax.tree.map(lambda a: a.astype(dt), p)
+        rows = jnp.asarray(clean, dt)[tables].reshape(S, L, -1)[..., :attn.row]
+        visible = jnp.arange(L)[None, None, :] < jnp.maximum(n, 1)[:, None, None]
+        return np.asarray(attn.absorbed(pd, q_n.astype(dt), q_r.astype(dt), rows, visible)
+                          .astype(jnp.float32))[:, 0]
+
+    held = np.asarray(LENGTHS) > 0
+    assert np.isfinite(got).all() and (got[~held] == 0).all()
+    exact = absorbed(jnp.float32)
+    if dtype == "float32":
+        np.testing.assert_allclose(got[held], exact[held], rtol=1e-5, atol=1e-5)
+    else:
+        lost = np.abs(absorbed(jnp.bfloat16) - exact)[held].max()
+        assert np.abs(got - exact)[held].max() <= max(2 * lost, 2.0 ** -7)
+    assert np.abs(exact[held]).max() > 0.05
+
+
+def test_latent_decode_step_is_the_same_through_either_read_side(monkeypatch):
+    """`serve.paged_kv._whole_latent_attention`'s decode step through the
+    interpreted kernel and through the gathered view, pool and all."""
+    from tpu_dist.ops import paged_latent
+
+    rng = np.random.default_rng(11)
+    attn, p, tables, clean, _, _, _ = _latent_case(rng, LENGTHS, "float32")
+    x = jnp.asarray(rng.normal(size=(len(LENGTHS), 1, 64)), jnp.float32)
+    n = np.asarray(LENGTHS)
+    pos, mask = jnp.asarray(np.maximum(n - 1, 0)[:, None]), jnp.asarray(n[:, None] > 0)
+    step = lambda: paged_kv._whole_latent_attention(  # noqa: E731
+        attn, p, x, jnp.asarray(clean), jnp.asarray(tables), pos, mask, BS)
+    y_view, pool_view, rows = step()
+    monkeypatch.setattr(
+        paged_kv, "_attend_rows_in_pool",
+        lambda *a, v_width, scale: paged_latent.paged_latent_decode(
+            *a, v_width=v_width, scale=scale, interpret=True))
+    y_kernel, pool_kernel, _ = step()
+    np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_view), rtol=1e-5, atol=1e-5)
+    assert np.array_equal(np.asarray(pool_kernel), np.asarray(pool_view))
+    assert int(rows) == int(n.sum())
+
+
+def test_latent_kernel_refuses_a_pool_of_other_rows():
+    from tpu_dist.ops.paged_latent import paged_latent_decode
+
+    q, pool = jnp.zeros((2, 4, 256)), jnp.zeros((5, BS, 128))
+    with pytest.raises(ValueError, match="over a pool of rows of 128"):
+        paged_latent_decode(q, pool, jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32),
+                            v_width=128, interpret=True)

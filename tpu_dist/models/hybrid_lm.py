@@ -14,6 +14,16 @@ With ``sandwich_norms`` each sublayer's OUTPUT is normed too, four gains a
 layer: ``h <- h + RMSNorm(mixer_l(RMSNorm(h)))`` and the same around the
 feed-forward.
 
+With ``shortcut = n`` (LongCat-Flash's shortcut-connected experts, ``n =
+2``) ``layer_types`` lists SUBLAYERS, each ``mixer -> dense feed-forward``,
+and the routed experts are a branch beside them: the first of every ``n``
+sublayers LAUNCHES it from its own post-mixer norm (the dense
+feed-forward's input), the last JOINS it, after its own feed-forward:
+
+    a <- h + mixer_0(RMSNorm(h));  u = RMSNorm(a);  m = experts(u)
+    h <- a + ff_0(u);  ... the sublayers between ...
+    h <- h + mixer_{n-1}(RMSNorm(h));  h <- h + ff_{n-1}(RMSNorm(h)) + m
+
 **The mixers** (`MIXERS`; each offers its weights, its dense form, what
 it keeps between calls and its cached form to the ONE block function):
 
@@ -33,17 +43,21 @@ it keeps between calls and its cached form to the ONE block function):
   ``{"k", "v"}`` pool as ``"attention"`` does; the second with rope and a
   window, keeping no pool but a ``{"k", "v"}`` ring of ``window - 1 +
   chunk`` positions a decode slot (`serve.paged_kv.init_ring_cache`).
-- ``"full_attention"``, ``"sliding_attention"``: `nn.LatentAttention`
-  (low-rank queries and keys/values, a rope part shared by the heads, a
-  gate a head), the first with the learned top-k selection of its keys,
-  the second windowed; each with its own sizes (``latent[kind]``).  The
-  first keeps a pool of latent rows and one of index keys under the
-  engine's block tables; the second no pool but a ring of ``window - 1 +
-  chunk`` positions a decode slot (`serve.paged_kv.init_latent_cache`).
+- ``"full_attention"``, ``"sliding_attention"``, ``"latent_attention"``:
+  `nn.LatentAttention` (low-rank queries and keys/values, a rope part
+  shared by the heads, a gate a head unless ``gated=False``), the first
+  with the learned top-k selection of its keys, the second windowed, the
+  third over every causal row; each with its own sizes (``mixers[kind]``).
+  The first keeps a pool of latent rows and one of index keys under the
+  engine's block tables, the third the pool of rows alone, which decode
+  reads where it lies (`ops.paged_latent`); the second no pool but a ring
+  of ``window - 1 + chunk`` positions a decode slot
+  (`serve.paged_kv.init_latent_cache`).
 
 **Experts**: `parallel.moe.routed_experts` (top-k over ``n_experts``
-router outputs, gates by ``expert_scoring``) over the ``held_experts``
-this rank holds, and a `nn.GatedMLP` of ``shared_width`` computed whole.
+router outputs, the last ``zero_experts`` of them experts with no weights,
+gates by ``expert_scoring``) over the ``held_experts`` this rank holds,
+and, where ``shared_width`` is given, a `nn.GatedMLP` computed whole.
 
 There is ONE block function (`_block`), which takes the mixer as a
 callable: the dense `apply` (whole sequences, no cache: tests and
@@ -250,17 +264,19 @@ class GatedQueryMixer(GroupedQueryMixer):
 
 
 class LatentMixer:
-    """Both latent kinds: one that selects its keys (``index_topk``; pools
-    under the block tables) and one with a ``window`` (a ring a slot, of
+    """The latent kinds: one that selects its keys (``index_topk``; pools
+    under the block tables), one with a ``window`` (a ring a slot, of
     ``window - 1 + chunk`` rows: ``chunk`` is the most new tokens a call
-    may bring a row)."""
+    may bring a row) and one with neither, over every row its slot holds
+    (the pool of rows alone)."""
 
     def __init__(self, dim: int, norm: RMSNorm, *, chunk: int = 1, **sizes):
         self.attn = LatentAttention(dim, eps=norm.eps, **sizes)
         windowed = self.attn.window is not None
         self.ring_rows = self.attn.window - 1 + chunk if windowed else None
         self.counters = (("swa_rows_attended",) if windowed
-                         else ("dsa_keys_scored", "dsa_rows_selected"))
+                         else ("dsa_keys_scored", "dsa_rows_selected") if self.attn.index_topk
+                         else ("mla_rows_attended",))
 
     def init(self, key):
         return self.attn.init(key)[0]
@@ -275,8 +291,17 @@ class LatentMixer:
                                  self.ring_rows)
 
     def cached(self, p, x, pools, state, at: Paged):
-        from tpu_dist.serve.paged_kv import _paged_latent_attention, _ring_latent_attention
+        from tpu_dist.serve.paged_kv import (
+            _paged_latent_attention,
+            _ring_latent_attention,
+            _whole_latent_attention,
+        )
 
+        if self.attn.window is None and not self.attn.index_topk:
+            y, ckv, attended = _whole_latent_attention(
+                self.attn, p, x, pools["ckv"], at.block_tables, at.positions, at.write_mask,
+                at.block_size)
+            return y, {"ckv": ckv}, {}, (attended,)
         if self.attn.window is None:
             y, pools, counts = _paged_latent_attention(
                 self.attn, p, x, pools, at.block_tables, at.positions, at.write_mask,
@@ -292,6 +317,7 @@ MIXERS = {
     "mamba": MambaMixer, "attention": GroupedQueryMixer,
     "gated_attention": GatedQueryMixer, "gated_sliding_attention": GatedQueryMixer,
     "full_attention": LatentMixer, "sliding_attention": LatentMixer,
+    "latent_attention": LatentMixer,
 }
 
 
@@ -320,10 +346,12 @@ class HybridLM(InitSpan, Module):
         n_experts: int,
         experts_per_token: int,
         expert_width: int,
-        shared_width: int,
+        shared_width: int | None = None,
         held_experts: tuple[int, int] | None = None,
         expert_scoring: str = "softmax_of_picks",
         route_scale: float = 1.0,
+        zero_experts: int = 0,
+        shortcut: int = 0,
         dense_layers: int = 0,
         dense_width: int | None = None,
         tied_head: bool = True,
@@ -349,17 +377,22 @@ class HybridLM(InitSpan, Module):
         }
         self.mixers = {kind: MIXERS[kind](dim, self.norm, **sizes[kind])
                        for kind in dict.fromkeys(self.layer_types)}
+        if shortcut and (len(self.layer_types) % shortcut or dense_layers):
+            raise ValueError(f"a routed branch spans {shortcut} sublayers, each with a dense "
+                             f"feed-forward: not {len(self.layer_types)} of them, nor "
+                             f"dense_layers {dense_layers}")
         self.n_experts, self.experts_per_token = n_experts, experts_per_token
-        self.expert_width = expert_width
-        self.held_experts = tuple(held_experts) if held_experts else (0, n_experts)
+        self.expert_width, self.zero_experts, self.shortcut = expert_width, zero_experts, shortcut
+        self.held_experts = (tuple(held_experts) if held_experts
+                             else (0, n_experts - zero_experts))
         self.expert_scoring, self.route_scale = expert_scoring, route_scale
         self.sandwich_norms = sandwich_norms
         self.dense_layers, self.tied_head = dense_layers, tied_head
         self.embedding_multiplier = embedding_multiplier
         self.residual_multiplier = residual_multiplier
         self.logits_scaling = logits_scaling
-        self.shared = GatedMLP(shared_width)
-        self.mlp = GatedMLP(dense_width) if dense_layers else None
+        self.shared = GatedMLP(shared_width) if shared_width else None
+        self.mlp = GatedMLP(dense_width) if dense_layers or shortcut else None
         lo, hi = self.held_experts
         # what `apply_paged`'s counters count, position by position:
         # (name, label key, label values) -> tpu_dist_serve_<name>_total
@@ -369,17 +402,21 @@ class HybridLM(InitSpan, Module):
             ("moe_picks_held", None, ()),
             ("moe_expert_tokens", "expert", tuple(range(lo, hi))),
             ("moe_experts_hit", None, ()),
+            *([("moe_picks_zero", None, ())] if zero_experts else []),
             *((name, None, ()) for name in own),
         )
-        self._count_at = {name: 3 + hi - lo + i for i, name in enumerate(own)}
-        self._counts = 3 + hi - lo + len(own)
+        routed = 3 + hi - lo + bool(zero_experts)
+        self._count_at = {name: routed + i for i, name in enumerate(own)}
+        self._counts = routed + len(own)
 
     # ------------------------------------------------------------ weights
 
     def init(self, key=None, input_shape=None):
         """Seeded weights: normal(0, 0.02) matrices (grouped-query
         attention's as `MultiHeadAttention` draws them), unit norms, zero
-        selection bias, each mixer's own as its ``init`` says."""
+        selection bias, each mixer's own as its ``init`` says.  Under
+        ``shortcut`` every sublayer has a dense feed-forward and the first
+        of each span the experts beside it."""
         del input_shape
         key = jax.random.key(0) if key is None else key
         D, E = self.dim, self.n_experts
@@ -391,17 +428,21 @@ class HybridLM(InitSpan, Module):
             p = {"ln1": ones(D), "mixer": self.mixers[kind].init(ks[0]), "ln2": ones(D)}
             if self.sandwich_norms:
                 p.update(ln1_out=ones(D), ln2_out=ones(D))
-            if at < self.dense_layers:
-                p["mlp"] = {"w_in": _normal(ks[1], D, 2 * self.mlp.width),
-                            "w_out": _normal(ks[2], self.mlp.width, D)}
-                return p
+            if at < self.dense_layers or self.shortcut:
+                k_in, k_out = (jax.random.split(jax.random.fold_in(k, 1)) if self.shortcut
+                               else (ks[1], ks[2]))   # beside experts: keys of its own
+                p["mlp"] = {"w_in": _normal(k_in, D, 2 * self.mlp.width),
+                            "w_out": _normal(k_out, self.mlp.width, D)}
+                if not self.shortcut or at % self.shortcut:
+                    return p
             p["moe"] = {"router": _normal(ks[1], D, E),
                         "w_in": _normal(ks[2], H, D, 2 * self.expert_width),
                         "w_out": _normal(ks[3], H, self.expert_width, D)}
-            if self.expert_scoring == "sigmoid_normalised":
+            if self.expert_scoring != "softmax_of_picks":
                 p["moe"]["bias"] = jnp.zeros((E,))
-            p["shared"] = {"w_in": _normal(ks[4], D, 2 * self.shared.width),
-                           "w_out": _normal(ks[5], self.shared.width, D)}
+            if self.shared is not None:
+                p["shared"] = {"w_in": _normal(ks[4], D, 2 * self.shared.width),
+                               "w_out": _normal(ks[5], self.shared.width, D)}
             return p
 
         k_emb, k_head, *k_blocks = jax.random.split(key, len(self.layer_types) + 2)
@@ -422,45 +463,64 @@ class HybridLM(InitSpan, Module):
             return self.norm.apply(p, {}, x)[0]
 
     def _experts(self, p, u, mask):
-        """Routed experts held here plus the shared expert, over ``u
-        (rows, s, dim)`` -> ``(y, counts (3 + held,))``: picks, picks held,
-        tokens a held expert, held experts given a token (those whose
-        weights the grouped product has to read)."""
+        """Routed experts held here plus the shared expert, if there is
+        one, over ``u (rows, s, dim)`` -> ``(y, counts (3 + held,))``:
+        picks, picks held, tokens a held expert, held experts given a token
+        (those whose weights the grouped product has to read), and with
+        ``zero_experts`` the picks that cost nothing."""
         flat = u.reshape(-1, u.shape[-1])
         y, c = routed_experts(
             flat, p["moe"]["router"], p["moe"]["w_in"], p["moe"]["w_out"],
             top_k=self.experts_per_token, held=self.held_experts,
             mask=None if mask is None else mask.reshape(-1),
             scoring=self.expert_scoring, bias=p["moe"].get("bias"), scale=self.route_scale,
+            zero_experts=self.zero_experts,
         )
-        with jax.named_scope("moe/shared"):
-            y = y + self.shared.apply(p["shared"], {}, flat)[0]
+        if self.shared is not None:
+            with jax.named_scope("moe/shared"):
+                y = y + self.shared.apply(p["shared"], {}, flat)[0]
         hit = (c["expert_tokens"] > 0).sum(dtype=jnp.int32)
         counts = jnp.concatenate([jnp.stack([c["picks"], c["picks_held"]]), c["expert_tokens"],
-                                  hit[None]])
+                                  hit[None], *([c["picks_zero"][None]] if self.zero_experts else [])])
         return y.reshape(u.shape), counts
 
     def _feed_forward(self, p, u, mask):
-        """The layer's own: dense where its weights are (``counts`` None),
-        else the experts."""
+        """The sublayer's own, ``-> (f, counts, launched)``: the experts
+        where it has no dense weights; else the dense one (``counts``
+        None), and beside it, where the sublayer has experts too, the
+        routed branch it launches from the same ``u``."""
         if "mlp" not in p:
-            return self._experts(p, u, mask)
+            return *self._experts(p, u, mask), None
+        launched, counts = self._experts(p, u, mask) if "moe" in p else (None, None)
         with jax.named_scope("mlp"):
-            return self.mlp.apply(p["mlp"], {}, u)[0], None
+            return self.mlp.apply(p["mlp"], {}, u)[0], counts, launched
 
-    def _block(self, p, h, mixer, mask):
-        """One layer, whatever its kind: ``mixer(params, x) -> (y, kept)``
-        is the layer's mixer in its dense or its cached form, ``kept``
-        what it keeps for the next call.  Both residual forms: a norm
-        before each sublayer, and with ``sandwich_norms`` one after it."""
+    def _block(self, p, h, mixer, mask, shortcut=None, join=False):
+        """One layer (under ``shortcut`` one sublayer), whatever its kind:
+        ``mixer(params, x) -> (y, kept)`` is the layer's mixer in its dense
+        or its cached form, ``kept`` what it keeps for the next call.  Both
+        residual forms: a norm before each sublayer, and with
+        ``sandwich_norms`` one after it.  ``shortcut``: the routed branch an
+        earlier sublayer launched and none has joined yet; this one hands
+        it on, or its own, or, where it is to ``join``, adds it to the
+        stream after its own feed-forward and hands on nothing."""
         y, kept = mixer(p["mixer"], self._ln(p["ln1"], h))
         if self.sandwich_norms:
             y = self._ln(p["ln1_out"], y)
         h = h + self.residual_multiplier * y.astype(h.dtype)
-        f, counts = self._feed_forward(p, self._ln(p["ln2"], h), mask)
+        f, counts, launched = self._feed_forward(p, self._ln(p["ln2"], h), mask)
         if self.sandwich_norms:
             f = self._ln(p["ln2_out"], f)
-        return h + self.residual_multiplier * f, kept, counts
+        h = h + self.residual_multiplier * f
+        shortcut = shortcut if launched is None else launched
+        if join and shortcut is not None:
+            with jax.named_scope("moe/join"):
+                h, shortcut = h + self.residual_multiplier * shortcut, None
+        return h, kept, counts, shortcut
+
+    def _joins(self, at: int) -> bool:
+        """Whether sublayer ``at`` ends a span of ``shortcut`` sublayers."""
+        return bool(self.shortcut) and at % self.shortcut == self.shortcut - 1
 
     def _embed(self, params, tokens):
         with jax.named_scope("embed"):
@@ -479,10 +539,11 @@ class HybridLM(InitSpan, Module):
         """``tokens (batch, seq)`` -> logits ``(batch, seq, vocab)`` in
         float32: every sequence whole, from a zero state, no cache."""
         del train, key
-        h = self._embed(params, tokens)
-        for kind, p in zip(self.layer_types, params["blocks"]):
+        h, shortcut = self._embed(params, tokens), None
+        for at, (kind, p) in enumerate(zip(self.layer_types, params["blocks"])):
             dense = self.mixers[kind].dense
-            h, _, _ = self._block(p, h, lambda pm, x, f=dense: (f(pm, x), None), None)
+            h, _, _, shortcut = self._block(p, h, lambda pm, x, f=dense: (f(pm, x), None), None,
+                                            shortcut, self._joins(at))
         return self._head(params, h), state
 
     # ----------------------------------------------------------- serving
@@ -504,17 +565,18 @@ class HybridLM(InitSpan, Module):
         h = self._embed(params, tokens)
         fresh = write_mask[:, 0] & (positions[:, 0] == 0)
         at = Paged(block_tables, positions, write_mask, slots, block_size, fresh)
-        kv, state = [], []
+        kv, state, shortcut = [], [], None
         counts = cache["state"]["counts"]
-        for kind, p, ckv, cst in zip(self.layer_types, params["blocks"], cache["kv"],
-                                     cache["state"]["layers"]):
+        for at_, (kind, p, ckv, cst) in enumerate(zip(
+                self.layer_types, params["blocks"], cache["kv"], cache["state"]["layers"])):
             mixer = self.mixers[kind]
 
             def cached(pm, x, m=mixer, c=ckv, s=cst):
                 y, pools, kept, own = m.cached(pm, x, c, s, at)
                 return y, (pools, kept, own)
 
-            h, (k_new, s_new, own), c = self._block(p, h, cached, write_mask)
+            h, (k_new, s_new, own), c, shortcut = self._block(
+                p, h, cached, write_mask, shortcut, self._joins(at_))
             kv.append(k_new)
             state.append(s_new)
             if c is not None:   # the experts' counts lead the vector

@@ -14,6 +14,7 @@ rotated key part.  With ``x`` a row of the layer's normed input:
     s_h(t, j) = (q_n,h(t) . k_n,h(j) + q_r,h(t) . k_r(j)) / sqrt(nope_dim + rope_dim)
     o_h = sum_j softmax_j(s_h)(t, j) v_h(j) over the visible j
     y = [sigmoid(x W_g)_h * o_h]_h W_o               a gate a head, no biases
+                                                     (``gated=False``: no W_g, y = [o_h]_h W_o)
 
 `expanded` computes exactly that from the rows (it builds ``k_n`` and
 ``v`` of every row: right for whole sequences and for chunks of queries
@@ -100,7 +101,7 @@ class LatentAttention(Module):
     def __init__(self, dim: int, heads: int, *, q_rank: int, kv_rank: int, nope_dim: int,
                  rope_dim: int, v_dim: int, rope_base: float, window: int | None = None,
                  index_heads: int = 0, index_dim: int = 0, index_topk: int = 0,
-                 eps: float = 1e-5):
+                 gated: bool = True, eps: float = 1e-5):
         if window is not None and index_topk:
             raise ValueError("a layer is windowed or selects its keys, not both")
         if rope_dim % 2 or (index_topk and index_dim < rope_dim):
@@ -110,6 +111,7 @@ class LatentAttention(Module):
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
         self.rope_base, self.window = rope_base, window
         self.index_heads, self.index_dim, self.index_topk = index_heads, index_dim, index_topk
+        self.gated = gated
         self.row = kv_rank + rope_dim          # what a token leaves behind
         self.scale = (nope_dim + rope_dim) ** -0.5
         self.q_gain, self.kv_gain = math.sqrt(dim / q_rank), math.sqrt(dim / kv_rank)
@@ -124,6 +126,8 @@ class LatentAttention(Module):
             "w_uv": (H, self.kv_rank, self.v_dim),
             "w_gate": (D, H), "w_out": (H * self.v_dim, D),
         }
+        if not self.gated:
+            del shapes["w_gate"]
         if self.index_topk:
             shapes.update(index_wq=(self.index_heads * self.index_dim, self.q_rank),
                           index_wk=(D, self.index_dim), index_ww=(D, self.index_heads))
@@ -265,8 +269,10 @@ class LatentAttention(Module):
         return jnp.einsum("bhsr,hrd->bshd", o_c, p["w_uv"])
 
     def output(self, p, x, o):
-        """The gate a head from the layer's input, then ``W_o``."""
+        """The gate a head from the layer's input (a gated layer), then ``W_o``."""
         with jax.named_scope("mla/out"):
+            if not self.gated:
+                return o.astype(x.dtype).reshape(*x.shape[:2], -1) @ p["w_out"]
             g = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, p["w_gate"],
                                           preferred_element_type=F32))
             o = (o * g[..., None]).astype(x.dtype)

@@ -285,6 +285,7 @@ def routed_experts(
     scoring: str = "softmax_of_picks",
     bias: jax.Array | None = None,
     scale: float = 1.0,
+    zero_experts: int = 0,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Top-``top_k`` routed gated experts, the part the held experts give.
 
@@ -295,14 +296,21 @@ def routed_experts(
       w_in, w_out: the HELD experts' stacked weights ``(H, d, 2 * width)``
         (gate's columns first, then the value's) and ``(H, width, d)``.
       held: ``(lo, hi)``, the experts ``lo .. hi - 1`` whose weights these
-        are; default all of them.
+        are; default all that have weights.
+      zero_experts: the LAST so many router outputs are experts with no
+        weights (identity: a pick on one adds ``g * x`` and costs no matrix
+        product).  They are no chip's to hold: computed here for every
+        token, whatever ``held``.
       mask: ``(T,)``, False for a pad token: its picks do no work.
       scoring: how picks and gates come from the router's logits ``r = x @
         router_w``.  ``"softmax_of_picks"``: ``(v, idx) = top_k(r)``, ``g =
         softmax(v)``.  ``"sigmoid_normalised"`` (DeepSeek-V3's gate with no
         groups): ``sig = sigmoid(r)``, ``idx = top_k(sig + bias)`` with
         ``bias (n_experts,)`` a selection bias that does not enter the
-        gate, ``g_j = sig_j / sum over the picks of sig``.
+        gate, ``g_j = sig_j / sum over the picks of sig``.  ``"softmax"``
+        (LongCat-Flash's): ``p = softmax(r)`` over the router's WHOLE width,
+        ``idx = top_k(p + bias)``, ``g_j = p_j``, not renormalised over the
+        picks.
       scale: what every gate is multiplied by after that (a model's routed
         scaling factor; the shared expert is the caller's and not scaled).
 
@@ -312,11 +320,14 @@ def routed_experts(
     renormalised away.
 
     Returns ``(y (T, d), counts)``; ``counts``: int32 ``picks`` (real
-    tokens x top_k), ``picks_held`` and ``expert_tokens (H,)``.
+    tokens x top_k), ``picks_held`` and ``expert_tokens (H,)``, and with
+    ``zero_experts`` ``picks_zero``, the real tokens' picks that cost
+    nothing.
     """
     T, d = x.shape
     H = w_in.shape[0]
-    lo, hi = held if held is not None else (0, router_w.shape[1])
+    weighted = router_w.shape[1] - zero_experts   # router outputs that have weights
+    lo, hi = held if held is not None else (0, weighted)
     if hi - lo != H:
         raise ValueError(f"held experts [{lo}, {hi}) but weights of {H}")
     with jax.named_scope("moe/router"):
@@ -330,8 +341,13 @@ def routed_experts(
             _, top_e = lax.top_k(sig if bias is None else sig + bias.astype(jnp.float32), top_k)
             top_v = jnp.take_along_axis(sig, top_e, axis=-1)
             gates = top_v / top_v.sum(axis=-1, keepdims=True)
+        elif scoring == "softmax":
+            prob = jax.nn.softmax(scores, axis=-1)
+            _, top_e = lax.top_k(prob if bias is None else prob + bias.astype(jnp.float32), top_k)
+            gates = jnp.take_along_axis(prob, top_e, axis=-1)
         else:
-            raise ValueError(f"scoring {scoring!r}: 'softmax_of_picks' or 'sigmoid_normalised'")
+            raise ValueError(
+                f"scoring {scoring!r}: 'softmax_of_picks', 'sigmoid_normalised' or 'softmax'")
         if scale != 1.0:
             gates = gates * scale
     with jax.named_scope("moe/sort"):
@@ -369,12 +385,19 @@ def routed_experts(
         # rows past the last group were never computed: take none of them
         weight = jnp.where(here, gates.reshape(-1), 0.0)
         picked = jnp.where(here[:, None], out[undo].astype(jnp.float32), 0.0)
-        y = (picked * weight[:, None]).reshape(T, top_k, d).sum(axis=1).astype(x.dtype)
+        y = (picked * weight[:, None]).reshape(T, top_k, d).sum(axis=1)
+    if zero_experts:
+        with jax.named_scope("moe/zero"):
+            free = (top_e >= weighted) & real[:, None]
+            y = y + jnp.where(free, gates, 0.0).sum(axis=-1, keepdims=True) * x.astype(jnp.float32)
+    y = y.astype(x.dtype)
     counts = {
         "picks": real.sum(dtype=jnp.int32) * top_k,
         "picks_held": sizes.sum(dtype=jnp.int32),
         "expert_tokens": sizes,
     }
+    if zero_experts:
+        counts["picks_zero"] = free.sum(dtype=jnp.int32)
     return y, counts
 
 

@@ -409,10 +409,11 @@ def _whole_tiles(width: int) -> int:
 def init_latent_cache(attn, max_batch: int, num_blocks: int, block_size: int,
                       dtype, ring_rows: int | None = None):
     """What a `nn.LatentAttention` layer keeps, as ``(pools, per-slot
-    state)``.  A layer that selects its keys: under the engine's block
+    state)``.  A layer over the whole context: under the engine's block
     tables a pool of ONE latent row a token, ``ckv (num_blocks + 1,
-    block_size, row)``, and a pool of the indexer's key, ``ik (...,
-    index_dim)``; no state.  A windowed layer: no pool, and a ring of
+    block_size, row)``, and, where it selects its keys, a pool of the
+    indexer's key, ``ik (..., index_dim)``; no state.  A windowed layer:
+    no pool, and a ring of
     ``ring_rows`` positions a decode slot, ``ring (max_batch, ring_rows,
     row)``, position ``p`` at row ``p mod ring_rows``: it never holds more
     of a request, however long.
@@ -429,14 +430,24 @@ def init_latent_cache(attn, max_batch: int, num_blocks: int, block_size: int,
     row = _whole_tiles(attn.row)
     if attn.window is None:
         pool = (num_blocks + 1, block_size)
-        return {"ckv": jnp.zeros(pool + (row,), dtype),
-                "ik": jnp.zeros(pool + (attn.index_dim,), dtype)}, {}
+        pools = {"ckv": jnp.zeros(pool + (row,), dtype)}
+        if attn.index_topk:
+            pools["ik"] = jnp.zeros(pool + (attn.index_dim,), dtype)
+        return pools, {}
     return {}, {"ring": jnp.zeros((max_batch, ring_rows, row), dtype)}
 
 
 def _padded(rows, width: int):
     """``rows (..., w)`` with zeros behind, ``width`` wide."""
     return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, width - rows.shape[-1])])
+
+
+def _write_places(pool, block_tables, positions, write_mask, block_size: int):
+    """``(block, offset)`` of each of a call's tokens in ``pool``, flat: its
+    table's block for the position, the scratch block for a masked token."""
+    blk = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
+    blk = jnp.where(write_mask, blk, pool.shape[0] - 1).reshape(-1)
+    return blk, (positions % block_size).reshape(-1)
 
 
 def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
@@ -462,9 +473,7 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
 
     with jax.named_scope("mla/cache_write"):
         ckv, ik = pools["ckv"], pools["ik"]
-        blk = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
-        blk = jnp.where(write_mask, blk, ckv.shape[0] - 1).reshape(-1)
-        off = (positions % block_size).reshape(-1)
+        blk, off = _write_places(ckv, block_tables, positions, write_mask, block_size)
         rows = _padded(rows.astype(ckv.dtype), ckv.shape[-1])
         ckv = ckv.at[blk, off].set(rows.reshape(S * s, -1))
         ik = ik.at[blk, off].set(keys.astype(ik.dtype).reshape(S * s, -1))
@@ -492,6 +501,72 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
     scored = jnp.where(write_mask, positions + 1, 0)
     counts = (scored.sum(dtype=jnp.int32), jnp.minimum(scored, topk).sum(dtype=jnp.int32))
     return attn.output(params, x, o), {"ckv": ckv, "ik": ik}, counts
+
+
+@functools.partial(jax.jit, static_argnames=("v_width", "scale"))
+def _attend_rows_in_pool(q, pool, block_tables, lengths, *, v_width: int, scale: float):
+    """Decode's read of a latent pool: the absorbed query ``q[s]`` ``(S,
+    heads, row)`` (``W_uk`` folded in, padded to the pool's lanes) attends
+    the ``lengths[s]`` rows its slot holds -> each head's weighted sum of
+    the rows' first ``v_width`` lanes ``(S, heads, v_width)``, zeros for a
+    slot that holds nothing.  As `_attend_in_pool`: the kernel
+    (`ops.paged_latent.paged_latent_decode`) where the program is lowered
+    for a TPU, the absorbed form over the gathered view anywhere else; a
+    function of its own under `jax.jit`, so that a model's sublayers share
+    one trace and one lowered kernel."""
+    from tpu_dist.ops.paged_latent import paged_latent_decode
+
+    def view(q, pool, block_tables, lengths):
+        S, L = q.shape[0], block_tables.shape[1] * pool.shape[1]
+        rows = pool[block_tables].reshape(S, L, -1).astype(q.dtype)
+        logits = scale * jnp.einsum("shc,slc->shl", q, rows,
+                                    preferred_element_type=jnp.float32)
+        seen = jnp.arange(L)[None, None, :] < lengths[:, None, None]
+        weights = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1).astype(q.dtype)
+        o = jnp.einsum("shl,slr->shr", weights, rows[..., :v_width])
+        return jnp.where((lengths > 0)[:, None, None], o, 0).astype(q.dtype)
+
+    return ops.kernel_for_platform(
+        functools.partial(paged_latent_decode, v_width=v_width, scale=scale),
+        view, q, pool, block_tables, lengths,
+    )
+
+
+def _whole_latent_attention(attn, params, x, ckv, block_tables, positions,
+                            write_mask, block_size: int):
+    """A latent layer with neither selection nor window against its ONE
+    pool (the contract of `_paged_attention`): each new token's latent row
+    is scattered as `_paged_attention` scatters k/v, then every query
+    attends EVERY row its slot holds.  ONE query a slot (decode): the rows
+    are read where they lie (`_attend_rows_in_pool`), the query with
+    ``W_uk`` folded in, ``W_uv`` applied to what comes back.  SEVERAL (a
+    prefill chunk): absorbed attention over the gathered view, walked only
+    as far as the call's longest context reaches.  Returns ``(y, ckv, rows
+    attended)``, the count over the real queries."""
+    S, s, _ = x.shape
+    L = block_tables.shape[1] * block_size
+    _, q_n, q_r = attn.queries(params, x, positions)
+    rows = attn.rows(params, x, positions)
+
+    with jax.named_scope("mla/cache_write"):
+        blk, off = _write_places(ckv, block_tables, positions, write_mask, block_size)
+        rows = _padded(rows.astype(ckv.dtype), ckv.shape[-1])
+        ckv = ckv.at[blk, off].set(rows.reshape(S * s, -1))
+
+    t = jnp.where(write_mask, positions, -1)      # a masked query sees nothing
+    with jax.named_scope("mla/attend"):
+        if s == 1:
+            q = jnp.concatenate(
+                [jnp.einsum("shd,hdr->shr", q_n[:, 0], params["w_uk"]), q_r[:, 0]], axis=-1)
+            o_c = _attend_rows_in_pool(
+                _padded(q, ckv.shape[-1]), ckv, block_tables, t[:, 0] + 1,
+                v_width=attn.kv_rank, scale=attn.scale)
+            o = jnp.einsum("shr,hrd->shd", o_c, params["w_uv"])[:, None]
+        else:
+            seen = ckv[block_tables].reshape(S, L, -1)[..., :attn.row].astype(x.dtype)
+            causal = jnp.arange(L)[None, None, :] <= t[:, :, None]
+            o = attn.absorbed(params, q_n, q_r, seen, causal, t.max() + 1)
+    return attn.output(params, x, o), ckv, (t + 1).sum(dtype=jnp.int32)
 
 
 def _ring_latent_attention(attn, params, x, ring, positions, write_mask, slots):
