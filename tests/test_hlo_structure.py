@@ -991,6 +991,81 @@ class TestTheLatentPoolIsReadWhereItLiesOnTheV5e:
         assert not big, "\n".join(big[:4])
 
 
+def _with_callees(text):
+    """-> a function that gives the lines of a computation of a compiled
+    module's text, by name, with those of everything it calls."""
+    import re
+
+    bodies, name = {}, None
+    for line in text.splitlines():
+        start = re.match(r"^(?:ENTRY )?(%\S+) \(.*\) -> .*\{$", line)
+        if start:
+            name = start.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+
+    def with_callees(name, seen=None):
+        seen = set() if seen is None else seen
+        if name in seen or name not in bodies:
+            return []
+        seen.add(name)
+        lines = list(bodies[name])
+        for line in bodies[name]:
+            for group in re.findall(r"(?:calls|to_apply|body|condition|branch_computations)="
+                                    r"\{?((?:%[\w.\-]+(?:, )?)+)\}?", line):
+                for callee in group.split(", "):
+                    lines += with_callees(callee, seen)
+        return lines
+
+    return with_callees
+
+
+class TestASelectingLayerReadsInPlaceOrFetchesOnTheV5e:
+    """A selecting layer's decode step reaches its picks one of two ways,
+    chosen on the device by what the call holds: compiled for the v5e the
+    decode program keeps ONE conditional a selecting layer, one arm with the
+    kernel `paged_latent_decode` under the picks' mask (one more operand
+    than the kernel of a layer that attends everything, whose call is as it
+    was) and no row gather, the other with the gather of the picked rows
+    through the block table and no kernel."""
+
+    KERNEL = (r"^\s*%paged_latent_decode\S* = .*custom-call\((.*?)\), "
+              r'custom_call_target="tpu_custom_call"')
+
+    def test_one_conditional_a_layer_with_the_kernel_in_one_arm_and_the_gather_in_the_other(
+            self, v5e_latent_engine, v5e_chip):
+        import re
+
+        text, _, _ = _compiled_for_the_v5e(v5e_latent_engine, v5e_chip,
+                                               "serve_decode_greedy", None)
+        assert len(re.findall(self.KERNEL, text, re.M)) == 1
+        with_callees = _with_callees(text)
+        conds = [line for line in text.splitlines() if re.search(r" conditional\(", line)]
+        assert len(conds) == 1, conds
+        arms = re.search(r"branch_computations=\{(%\S+), (%\S+)\}", conds[0]).groups()
+        fetched, in_place = ("\n".join(with_callees(arm)) for arm in arms)
+        # S x index_topk rows of the pool, one by one
+        rows = re.compile(r"= bf16\[4,16,128\]\S* gather\(.*slice_sizes=\{1,1,128\}")
+        assert re.search(self.KERNEL, in_place, re.M) and not rows.search(in_place)
+        assert rows.search(fetched) and "tpu_custom_call" not in fetched
+        assert "dsa/gather" in fetched and "dsa/gather" not in in_place
+        assert "dsa/topk" in in_place and "mla/attend" in in_place and "mla/attend" in fetched
+
+    def test_the_mask_is_an_operand_only_where_a_layer_selects(
+            self, v5e_latent_engine, v5e_shortcut_engine, v5e_chip):
+        """The grid's bound, six tables, the queries and the pool once a
+        block of a chunk (four here): LongCat's call, as it was; a selecting
+        layer's has the mask besides."""
+        import re
+
+        operands = {}
+        for name, engine in (("selects", v5e_latent_engine), ("whole", v5e_shortcut_engine)):
+            text, _, _ = _compiled_for_the_v5e(engine, v5e_chip, "serve_decode_greedy", None)
+            operands[name] = {call.count("%") for call in re.findall(self.KERNEL, text, re.M)}
+        assert operands == {"whole": {1 + 6 + 1 + 4}, "selects": {1 + 6 + 1 + 1 + 4}}
+
+
 def test_the_environment_decides_nothing_that_is_compiled():
     """The ``TPU_DIST_*`` names `tpu_dist/` knows are a deployment's: where
     telemetry, metrics and dumps go, where the data is, how processes find

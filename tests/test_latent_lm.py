@@ -263,7 +263,7 @@ def test_the_three_kinds_of_cache_are_accounted(model):
     assert 37 * 8 * family.kv_bytes_per_token(CFG, 4) == n_full * 37 * 8 * (24 + 16) * 4
     ring = (CFG["sliding_window_size"] - 1 + 16) * 128 * 4
     held = CFG["held_experts"][1] - CFG["held_experts"][0]
-    assert eng.state_bytes == n_swa * 3 * ring + 4 * (3 + held + 3)
+    assert eng.state_bytes == n_swa * 3 * ring + 4 * (3 + held + 4)
     kinds = [(sorted(kv), sorted(st)) for kv, st in zip(eng.cache["kv"], eng.cache["state"]["layers"])]
     assert kinds == [(["ckv", "ik"], []), ([], ["ring"]), ([], ["ring"]), (["ckv", "ik"], [])]
 
@@ -443,3 +443,156 @@ def test_the_family_counts_what_a_decode_step_reads():
     near, far = (family.forward_flops_per_token(PUBLISHED, n) for n in (8192, 16384))
     index_only = 4 * 2 * 64 * 128 * (16384 - 8192) / 2
     assert far - near == pytest.approx(index_only)
+
+
+# --------------------------------- (f) a decode call's two ways to its picks
+
+
+SLOTS, PLACES, BLOCK = 3, 64, 8
+
+
+def _filled_pools(model, lengths):
+    """A selecting layer's two pools after slot ``i`` prefilled
+    ``lengths[i]`` tokens of its own seeded activations (one call, the whole
+    table a chunk); -> (attn, the layer's weights, pools, tables)."""
+    from tpu_dist.serve import paged_kv
+
+    lm, params, _ = model
+    attn, p = lm.mixers["full_attention"].attn, params["blocks"][0]["mixer"]
+    pools, _ = paged_kv.init_latent_cache(attn, SLOTS, SLOTS * PLACES // BLOCK, BLOCK, jnp.float32)
+    tables = jnp.arange(SLOTS * PLACES // BLOCK, dtype=jnp.int32).reshape(SLOTS, -1)
+    x = jnp.stack([jax.random.normal(jax.random.key(100 + i), (PLACES, CFG["hidden_size"]))
+                   for i in range(SLOTS)])
+    pos = jnp.broadcast_to(jnp.arange(PLACES), (SLOTS, PLACES))
+    _, pools, _ = paged_kv._paged_latent_attention(
+        attn, p, x, pools, tables, pos, pos < jnp.asarray(lengths)[:, None], BLOCK)
+    return attn, p, pools, tables
+
+
+def _decode_call(attn, p, pools, tables, lengths):
+    """One decode step: slot ``i``'s next token (seeded by the slot alone)
+    at place ``lengths[i]``; -> (y, pools after, counts, x, positions)."""
+    from tpu_dist.serve import paged_kv
+
+    x = jnp.stack([jax.random.normal(jax.random.key(200 + i), (1, CFG["hidden_size"]))
+                   for i in range(SLOTS)])
+    pos = jnp.asarray(lengths, jnp.int32)[:, None]
+    y, after, counts = jax.jit(lambda pools: paged_kv._paged_latent_attention(
+        attn, p, x, pools, tables, pos, jnp.ones_like(pos, bool), BLOCK))(pools)
+    return y, after, tuple(int(c) for c in counts), x, pos
+
+
+def _the_parents_decode_read(attn, p, pools, tables, x, pos):
+    """The decode step as it was before a call could read its pool in place
+    (pools already written): `lax.top_k`, the picked rows fetched through
+    the table one by one, `absorbed` over the fetched rows."""
+    L = tables.shape[1] * BLOCK
+    c_q, q_n, q_r = attn.queries(p, x, pos)
+    q_i, w = attn.index_queries(p, x, c_q, pos)
+    scores = attn.index_scores(q_i, w, pools["ik"][tables].reshape(SLOTS, L, -1))
+    causal = jnp.arange(L)[None, None, :] <= pos[:, :, None]
+    best, picks = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf)[:, 0], CFG["index_topk"])
+    blk = jnp.take_along_axis(tables, picks // BLOCK, axis=1)
+    seen = pools["ckv"][blk, picks % BLOCK][..., :attn.row]
+    o = attn.absorbed(p, q_n, q_r, seen, (best > -jnp.inf)[:, None])
+    return attn.output(p, x, o), picks
+
+
+# slot 0 holds 50 either way; what the call's other two hold decides how it reads
+FEW, MANY = [50, 7, 7], [50, 62, 62]
+
+
+def test_a_decode_call_reads_in_place_or_fetches_by_what_it_holds(model):
+    """The one `lax.cond` of a selecting layer's decode step, each arm
+    forced by the call's own held / selected: three slots that hold 51 + 8
+    + 8 places for 8 + 8 + 8 selected (2.8 a selected row: the pool is read
+    in place under the picks' mask) and 51 + 63 + 63 for the same 24 (7.4:
+    the picked rows are fetched).  Slot 0 holds the same rows and brings
+    the same token in both calls and gets the same output through either
+    arm; each call is the parent's step; `dsa_rows_read` says which arm ran."""
+    from tpu_dist.serve import paged_kv
+
+    topk = CFG["index_topk"]
+    ys = {}
+    for name, lengths in (("few", FEW), ("many", MANY)):
+        attn, p, pools, tables = _filled_pools(model, lengths)
+        y, after, (scored, selected, read), x, pos = _decode_call(attn, p, pools, tables, lengths)
+        held = sum(n + 1 for n in lengths)
+        assert (scored, selected) == (held, sum(min(n + 1, topk) for n in lengths))
+        in_place = held <= paged_kv.READ_ALL_UNDER * selected
+        assert in_place == (name == "few"), (held, selected, paged_kv.READ_ALL_UNDER)
+        assert read == (held if in_place else selected)
+        want, _ = _the_parents_decode_read(attn, p, after, tables, x, pos)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+        ys[name] = np.asarray(y[0])
+    np.testing.assert_allclose(ys["few"], ys["many"], atol=1e-5)
+    assert np.abs(ys["few"]).max() > 1e-2
+
+
+def test_a_prefill_chunk_counts_the_rows_it_holds_as_read(model):
+    from tpu_dist.serve import paged_kv
+
+    attn, p, pools, tables = _filled_pools(model, [0] * SLOTS)
+    x = jax.random.normal(jax.random.key(5), (SLOTS, 16, CFG["hidden_size"]))
+    pos = jnp.broadcast_to(jnp.arange(16), (SLOTS, 16))
+    real = pos < jnp.asarray([16, 9, 0])[:, None]
+    _, _, (scored, selected, read) = paged_kv._paged_latent_attention(
+        attn, p, x, pools, tables, pos, real, BLOCK)
+    assert (int(scored), int(read)) == (16 * 17 // 2 + 9 * 10 // 2,) * 2
+    assert int(selected) == sum(min(t + 1, CFG["index_topk"]) for n in (16, 9) for t in range(n))
+
+
+def test_ties_at_the_kth_index_score_are_top_ks_set_in_place(model, monkeypatch):
+    """Planted ties: every place a slot held before this step carries ONE
+    index key, so all of them score alike and the k-th value is a tie
+    between dozens of places.  The mask the pool is read under keeps what
+    `lax.top_k` picks, the tied places of lowest index, and the step is the
+    parent's, whose rows differ place by place."""
+    from tpu_dist.serve import paged_kv
+
+    topk = CFG["index_topk"]
+    attn, p, pools, tables = _filled_pools(model, FEW)
+    ik = pools["ik"]
+    pools["ik"] = jnp.broadcast_to(ik[0, 0], ik.shape)
+    masks = []
+    attend = paged_kv._attend_rows_in_pool
+
+    def seen(*a, keep, **how):   # the mask is the cond's own: a callback hands it out
+        jax.debug.callback(lambda k: masks.append(np.asarray(k)), keep)
+        return attend(*a, keep=keep, **how)
+
+    monkeypatch.setattr(paged_kv, "_attend_rows_in_pool", seen)
+    paged_kv._attend_picks_in_pool.clear_cache()     # traced before, it would not call `seen`
+    x = jnp.stack([jax.random.normal(jax.random.key(200 + i), (1, CFG["hidden_size"]))
+                   for i in range(SLOTS)])
+    pos = jnp.asarray(FEW, jnp.int32)[:, None]
+    y, after, (_, selected, read) = paged_kv._paged_latent_attention(
+        attn, p, x, pools, tables, pos, jnp.ones_like(pos, bool), BLOCK)
+    assert int(read) > int(selected)     # the pool was read in place
+    want, picks = _the_parents_decode_read(attn, p, after, tables, x, pos)
+    jax.effects_barrier()
+    keep, = masks
+    for s, n in enumerate(FEW):
+        assert sorted(np.flatnonzero(keep[s]).tolist()) == sorted(
+            {int(j) for j in np.asarray(picks[s]) if j <= n})
+    # slot 0: 50 tied places and its own: the 7 or 8 lowest of the tie
+    assert keep[0].sum() == topk and keep[0, :topk - 1].all() and (keep[0, topk - 1] or keep[0, 50])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+    paged_kv._attend_picks_in_pool.clear_cache()     # nor keep the trace that does
+
+
+@pytest.mark.parametrize("steps,want", [
+    # every step read its pool in place | every step fetched its picks | the parent: no such count
+    ([dict(dsa_rows_selected=100, dsa_rows_read=270), dict(dsa_rows_selected=50, dsa_rows_read=135)], 2.7),
+    ([dict(dsa_rows_selected=100, dsa_rows_read=100)], 1.0),
+    ([dict(dsa_keys_scored=270, dsa_rows_selected=100)], None),
+    ([], None),
+], ids=["in_place", "fetched", "no_counter", "no_steps"])
+def test_the_benchmarks_reader_of_rows_read_over_selected(steps, want, monkeypatch):
+    import types
+
+    from chipbench.layer_metrics import dsa_read_over_selected as reader
+
+    spans = {"engine.decode_apply": [types.SimpleNamespace(attrs=a) for a in steps]}
+    monkeypatch.setattr(reader, "window_spans", lambda run: spans)
+    assert reader.read(None) == (want if want is None else pytest.approx(want))

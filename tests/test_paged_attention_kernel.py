@@ -338,14 +338,31 @@ def _latent_case(rng, lengths, dtype, *, heads=4, kv_rank=128, nope=16, rope=8):
     return attn, p, tables, clean, dirty, q_n, q_r
 
 
+def _selection(rng, lengths):
+    """A mask ``(S, MB * BS)`` as a selecting layer's picks would give:
+    over `LENGTHS`, a slot shorter than the selection (everything kept), one
+    that keeps nothing, one whose first chunks keep nothing, and places
+    kept past a slot's length, which get no weight all the same."""
+    keep = rng.random((len(lengths), MB * BS)) < 0.4
+    keep[[1, 6, 7]] = True
+    keep[2] = False
+    keep[4, :20] = False
+    keep[5, 29:] = True
+    return keep
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["held", "selected"])
 @pytest.mark.parametrize("chunk", [256, 2 * BS, BS])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_latent_kernel_matches_absorbed_over_the_gathered_view(dtype, chunk, monkeypatch):
+def test_latent_kernel_matches_absorbed_over_the_gathered_view(dtype, chunk, selected,
+                                                               monkeypatch):
     """`ops.paged_latent.paged_latent_decode` (interpreted) against
     `LatentAttention.absorbed` over the gathered view: ragged lengths, an
     empty slot, a length that ends mid-block, several chunks a slot; the
-    kernel's pool carries NaN wherever a slot holds nothing.  float32 to
-    rounding; bfloat16 within what the absorbed form itself loses there."""
+    kernel's pool carries NaN wherever a slot holds nothing.  ``selected``:
+    under a selection's mask (`_selection`), which the absorbed form takes
+    as its visible places; a slot that keeps nothing reads zeros.  float32
+    to rounding; bfloat16 within what the absorbed form itself loses there."""
     from tpu_dist.ops import paged_latent
 
     monkeypatch.setattr(paged_latent, "CHUNK_TOKENS", chunk)
@@ -353,20 +370,27 @@ def test_latent_kernel_matches_absorbed_over_the_gathered_view(dtype, chunk, mon
     attn, p, tables, clean, dirty, q_n, q_r = _latent_case(rng, LENGTHS, dtype)
     n = jnp.asarray(LENGTHS, jnp.int32)
     S, L = len(LENGTHS), MB * BS
+    keep = _selection(rng, LENGTHS) if selected else None
     q = jnp.concatenate([jnp.einsum("shd,hdr->shr", q_n[:, 0], p["w_uk"]), q_r[:, 0]], axis=-1)
     got = jax.jit(lambda q, pool: paged_latent.paged_latent_decode(
         paged_kv._padded(q, pool.shape[-1]), pool, tables, n, v_width=attn.kv_rank,
-        scale=attn.scale, interpret=True))(q, jnp.asarray(dirty, dtype))
+        scale=attn.scale, keep=None if keep is None else jnp.asarray(keep),
+        interpret=True))(q, jnp.asarray(dirty, dtype))
     got = np.asarray(jnp.einsum("shr,hrd->shd", got, p["w_uv"]).astype(jnp.float32))
 
     def absorbed(dt):
         pd = jax.tree.map(lambda a: a.astype(dt), p)
         rows = jnp.asarray(clean, dt)[tables].reshape(S, L, -1)[..., :attn.row]
         visible = jnp.arange(L)[None, None, :] < jnp.maximum(n, 1)[:, None, None]
+        if selected:
+            visible &= keep[:, None]
         return np.asarray(attn.absorbed(pd, q_n.astype(dt), q_r.astype(dt), rows, visible)
                           .astype(jnp.float32))[:, 0]
 
     held = np.asarray(LENGTHS) > 0
+    if selected:
+        held &= (keep & (np.arange(L) < np.asarray(LENGTHS)[:, None])).any(axis=1)
+        assert held.sum() == 5
     assert np.isfinite(got).all() and (got[~held] == 0).all()
     exact = absorbed(jnp.float32)
     if dtype == "float32":
@@ -375,6 +399,39 @@ def test_latent_kernel_matches_absorbed_over_the_gathered_view(dtype, chunk, mon
         lost = np.abs(absorbed(jnp.bfloat16) - exact)[held].max()
         assert np.abs(got - exact)[held].max() <= max(2 * lost, 2.0 ** -7)
     assert np.abs(exact[held]).max() > 0.05
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernel_without_a_selection_is_the_kernel_it_was(dtype, monkeypatch):
+    """With ``keep=None`` the `pallas_call` has the operands it had before
+    the kernel took a selection (the grid's bound, six tables, the queries
+    and ``G`` blocks: no mask) and its result is, bit for bit, that under a
+    mask that keeps every place: the same arithmetic on the same values."""
+    from tpu_dist.ops import paged_latent
+
+    monkeypatch.setattr(paged_latent, "CHUNK_TOKENS", 2 * BS)
+    rng = np.random.default_rng(5)
+    attn, p, tables, _, dirty, q_n, q_r = _latent_case(rng, LENGTHS, dtype)
+    q = jnp.concatenate([jnp.einsum("shd,hdr->shr", q_n[:, 0], p["w_uk"]), q_r[:, 0]], axis=-1)
+    q, pool = paged_kv._padded(q, dirty.shape[-1]), jnp.asarray(dirty, dtype)
+    n = jnp.asarray(LENGTHS, jnp.int32)
+
+    def run(keep, **how):
+        return lambda q, pool: paged_latent.paged_latent_decode(
+            q, pool, tables, n, v_width=attn.kv_rank, scale=attn.scale, keep=keep, **how)
+
+    everything = jnp.ones((len(LENGTHS), MB * BS), bool)
+    plain, masked = (np.asarray(jax.jit(run(keep, interpret=True))(q, pool).astype(jnp.float32))
+                     for keep in (None, everything))
+    assert np.array_equal(plain, masked) and np.abs(plain).max() > 0.05
+
+    def operands(keep):
+        text = jax.jit(run(keep)).trace(q, pool).lower(lowering_platforms=("tpu",)).as_text()
+        call, = [line for line in text.splitlines() if "tpu_custom_call" in line]
+        return call.split("@tpu_custom_call(")[1].split(")")[0].count("%")
+
+    G = 2
+    assert (operands(None), operands(everything)) == (1 + 6 + 1 + G, 1 + 6 + 1 + 1 + G)
 
 
 def test_latent_decode_step_is_the_same_through_either_read_side(monkeypatch):
@@ -392,8 +449,7 @@ def test_latent_decode_step_is_the_same_through_either_read_side(monkeypatch):
     y_view, pool_view, rows = step()
     monkeypatch.setattr(
         paged_kv, "_attend_rows_in_pool",
-        lambda *a, v_width, scale: paged_latent.paged_latent_decode(
-            *a, v_width=v_width, scale=scale, interpret=True))
+        lambda *a, **how: paged_latent.paged_latent_decode(*a, interpret=True, **how))
     y_kernel, pool_kernel, _ = step()
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_view), rtol=1e-5, atol=1e-5)
     assert np.array_equal(np.asarray(pool_kernel), np.asarray(pool_view))

@@ -275,7 +275,8 @@ class LatentMixer:
         windowed = self.attn.window is not None
         self.ring_rows = self.attn.window - 1 + chunk if windowed else None
         self.counters = (("swa_rows_attended",) if windowed
-                         else ("dsa_keys_scored", "dsa_rows_selected") if self.attn.index_topk
+                         else ("dsa_keys_scored", "dsa_rows_selected", "dsa_rows_read")
+                         if self.attn.index_topk
                          else ("mla_rows_attended",))
 
     def init(self, key):

@@ -19,6 +19,14 @@ holds nothing has no step and a row of zeros).  The queries come with
 ``W_uk`` folded in and padded to the pool's lanes (the pad lanes of both
 are zero); ``scale`` multiplies the float32 scores, as the absorbed form's
 does; ``W_uv`` is the caller's, after.
+
+A layer that SELECTS its keys hands the selection over as a mask, ``keep``:
+the kernel still fetches every held block, a step takes its chunk's part of
+the mask beside the rows (4 bytes a place by the row's 1,280) and a place
+that is not kept gets no weight.  That reads more rows than were picked, and
+reads each at a block's price, not a single row's:
+`serve.paged_kv.READ_ALL_UNDER` says how many held rows a selected one is
+worth.
 """
 
 from __future__ import annotations
@@ -43,7 +51,9 @@ CHUNK_TOKENS = 512
 
 
 def _latent_kernel(slot_ref, chunk_ref, ids_ref, first_ref, chunks_ref, len_ref, q_ref, *refs,
-                   G: int, v_width: int, scale: float):
+                   G: int, v_width: int, scale: float, masked: bool):
+    if masked:
+        keep_ref, *refs = refs
     row_refs = refs[:G]
     o_ref, m_ref, l_ref, acc_ref, cat = refs[G:]
     T, _ = cat.shape
@@ -70,7 +80,13 @@ def _latent_kernel(slot_ref, chunk_ref, ids_ref, first_ref, chunks_ref, len_ref,
                                          preferred_element_type=jnp.float32)
         pos = (first_ref[s] + c * G) * bs + lax.broadcasted_iota(jnp.int32, (heads, T), 1)
         # a chunk's tail holds some other block's rows: finite, weight 0
-        scores = jnp.where(pos < n, scores, NEG_INF)
+        seen = pos < n
+        if masked:
+            # a chunk that keeps nothing before the slot's first kept place
+            # weighs every place 1 under the fill's maximum: finite, and the
+            # first real maximum scales it away (alpha = 0)
+            seen &= keep_ref[...] != 0
+        scores = jnp.where(seen, scores, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -86,24 +102,27 @@ def _latent_kernel(slot_ref, chunk_ref, ids_ref, first_ref, chunks_ref, len_ref,
 
 
 def paged_latent_decode(q, pool, block_tables, lengths, *, v_width: int, scale: float = 1.0,
-                        interpret: bool = False):
+                        keep=None, interpret: bool = False):
     """One query token a slot over the latent rows its slot holds.
 
     ``q``: ``(S, heads, row)``, the absorbed queries ``[W_uk^T q_n | q_r]``
     padded with zeros to the pool's lanes; ``pool``: ``(num_blocks + 1,
     block_size, row)``; ``block_tables``: ``(S, max_blocks)`` int32;
     ``lengths``: ``(S,)`` int32, the places a slot attends (its query sits
-    at ``lengths - 1``), 0 for a slot that is to read nothing.  Returns
-    ``(S, heads, v_width)`` in ``q``'s dtype: each head's softmax-weighted
-    sum of the rows' first ``v_width`` lanes, zeros for an empty slot.
-    Every block a table names up to its slot's length must hold finite
-    numbers."""
+    at ``lengths - 1``), 0 for a slot that is to read nothing; ``keep``:
+    ``(S, max_blocks * block_size)``, true where a place of the slot's
+    table is to get weight (default: every place under its length).
+    Returns ``(S, heads, v_width)`` in ``q``'s dtype: each head's
+    softmax-weighted sum of the rows' first ``v_width`` lanes, zeros for a
+    slot that is empty or keeps nothing.  Every block a table names up to
+    its slot's length must hold finite numbers."""
     S, heads, row = q.shape
     _, bs, width = pool.shape
     if width != row or not 0 < v_width <= row:
         raise ValueError(f"queries of {row} lanes and a value of {v_width} "
                          f"over a pool of rows of {width}")
-    G = max(1, min(CHUNK_TOKENS // bs, block_tables.shape[1]))
+    MB = block_tables.shape[1]
+    G = max(1, min(CHUNK_TOKENS // bs, MB))
     lengths = jnp.asarray(lengths, jnp.int32)
     steps, slot, chunk, ids, first, chunks = _schedule(
         jnp.asarray(block_tables, jnp.int32), lengths, bs, G, None)
@@ -113,8 +132,18 @@ def paged_latent_decode(q, pool, block_tables, lengths, *, v_width: int, scale: 
                      lambda t, slot, chunk, ids, *_, g=g: (ids[t * G + g], 0, 0))
         for g in range(G)
     ]
+    masks = []
+    if keep is not None:
+        # a step's part of its slot's mask: chunk c of the table (no window
+        # here: a slot's chunks start at its table's first block)
+        C, T = -(-MB // G), G * bs
+        masks = [jnp.pad(keep.astype(jnp.int32), ((0, 0), (0, C * T - MB * bs)))
+                 .reshape(S, C, 1, T)]
+        blocks.insert(0, pl.BlockSpec((None, None, 1, T),
+                                      lambda t, slot, chunk, *_: (slot[t], chunk[t], 0, 0)))
     o = pl.pallas_call(
-        functools.partial(_latent_kernel, G=G, v_width=v_width, scale=scale),
+        functools.partial(_latent_kernel, G=G, v_width=v_width, scale=scale,
+                          masked=keep is not None),
         name="paged_latent_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
@@ -134,6 +163,10 @@ def paged_latent_decode(q, pool, block_tables, lengths, *, v_width: int, scale: 
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(slot, chunk, ids, first, chunks, lengths, q, *[pool] * G)
-    # a slot with no step has a row nothing wrote
-    return jnp.where((lengths > 0)[:, None, None], o, 0)
+    )(slot, chunk, ids, first, chunks, lengths, q, *masks, *[pool] * G)
+    # a slot with no step has a row nothing wrote; one that keeps nothing,
+    # the mean of what it holds
+    some = lengths > 0
+    if keep is not None:
+        some = (keep & (jnp.arange(MB * bs) < lengths[:, None])).any(axis=1)
+    return jnp.where(some[:, None, None], o, 0)

@@ -66,6 +66,15 @@ from tpu_dist.ops import SCORE_BYTES
 # context of 2k 0.34 | 0.30 | 0.36 | 0.32 ms, at 24k 2.24 | 2.15 | 1.86 |
 # 1.73 ms; four rows at 2k 0.88 | 0.97 | 1.97 | 2.15 ms
 WALK_TOKENS = 512
+# rows a decode call of a selecting layer may HOLD for each row it selects
+# and still read its whole pool under the picks' mask (`paged_latent_decode`,
+# a block a fetch) rather than fetch the picked rows one by one (XLA's
+# gather).  One layer's read on the v5e at dots3's shape (16 slots, 128
+# heads, blocks of 16 rows of 640 lanes, 2,048 picks a slot; PERF.md section
+# 6, PR 44) by held / selected 2.0 | 2.7 | 4.0 | 5.0 | 5.7 | 6.2: in place
+# 419 | 539 | 748 | 904 | 1,019 | 1,099 us (99 us and 4.9 ns a held row),
+# fetched 893-898 us whatever is held: equal at 4.9
+READ_ALL_UNDER = 4.9
 
 
 class BlockAllocator:
@@ -456,13 +465,21 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
     of `_paged_attention`; ``pools = {"ckv", "ik"}``).  Each new token's
     latent row and index key are scattered as `_paged_attention` scatters
     k/v; every query then scores the index keys its slot holds.  ONE
-    query a slot (decode): `lax.top_k` of the scores, those rows of
-    ``ckv`` fetched through the block table, absorbed attention over the
-    fetched rows (a slot that holds fewer than ``index_topk`` masks the
-    rest).  SEVERAL (a prefill chunk): the same picks as a mask over the
-    gathered view, scores and attention walked over it only as far as the
-    call's longest context reaches.  Returns ``(y, pools, (keys scored, rows selected))``,
-    the counts over the real queries."""
+    query a slot (decode): `lax.top_k` of the scores, then absorbed
+    attention over the picks, their rows reached one of two ways by what
+    the call holds (one `lax.cond` on its own counts).  Where it holds no
+    more than `READ_ALL_UNDER` rows for each it selects, the picks go as a
+    mask with the absorbed query to `_attend_rows_in_pool`, which reads
+    every held row where it lies, a block a fetch; where its contexts are
+    long against ``index_topk``, the picked rows of ``ckv`` are fetched
+    through the block table one by one and attended as fetched (a slot
+    that holds fewer than ``index_topk`` masks the rest).  The same rows
+    get weight either way, the others none.  SEVERAL (a prefill chunk):
+    the same picks as a mask over the gathered view, scores and attention
+    walked over it only as far as the call's longest context reaches.
+    Returns ``(y, pools, (keys scored, rows selected, rows read))``, the
+    counts over the real queries: rows read are the rows held where the
+    read lay a mask over them, the rows selected where it fetched those."""
     S, s, _ = x.shape
     L = block_tables.shape[1] * block_size
     topk = min(attn.index_topk, L)
@@ -483,53 +500,114 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
     t = jnp.where(write_mask, positions, -1)      # a masked query sees nothing
     held = t.max() + 1                            # no row of the call sees a place past it
     scores = attn.index_scores(q_i, w, held_keys, held)
-    causal = jnp.arange(L)[None, None, :] <= t[:, :, None]
+    places = jnp.where(write_mask, positions + 1, 0)     # what each real query's slot holds
+    scored, selected = (places.sum(dtype=jnp.int32),
+                        jnp.minimum(places, topk).sum(dtype=jnp.int32))
     if s == 1:
-        with jax.named_scope("dsa/topk"):
-            best, picks = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf)[:, 0], topk)
-            picked = (best > -jnp.inf)[:, None]
-        with jax.named_scope("dsa/gather"):
-            blk = jnp.take_along_axis(block_tables, picks // block_size, axis=1)
-            seen = ckv[blk, picks % block_size][..., :attn.row].astype(x.dtype)
+        read_all = scored <= READ_ALL_UNDER * selected
+        o = _attend_picks_in_pool(
+            attn, {name: params[name] for name in ("w_uk", "w_uv")}, q_n, q_r, ckv,
+            block_tables, t, scores, read_all, block_size=block_size)
+        read = jnp.where(read_all, scored, selected)
     else:
         with jax.named_scope("dsa/topk"):
+            causal = jnp.arange(L)[None, None, :] <= t[:, :, None]
             picked = top_visible(scores, causal, topk)
         with jax.named_scope("dsa/gather"):
             seen = ckv[block_tables].reshape(S, L, -1)[..., :attn.row].astype(x.dtype)
-    with jax.named_scope("mla/attend"):
-        o = attn.absorbed(params, q_n, q_r, seen, picked, None if s == 1 else held)
-    scored = jnp.where(write_mask, positions + 1, 0)
-    counts = (scored.sum(dtype=jnp.int32), jnp.minimum(scored, topk).sum(dtype=jnp.int32))
-    return attn.output(params, x, o), {"ckv": ckv, "ik": ik}, counts
+        with jax.named_scope("mla/attend"):
+            o = attn.absorbed(params, q_n, q_r, seen, picked, held)
+        read = scored     # the mask lies over the view of everything held
+    return attn.output(params, x, o), {"ckv": ckv, "ik": ik}, (scored, selected, read)
+
+
+@functools.partial(jax.jit, static_argnames=("attn", "block_size"))
+def _attend_picks_in_pool(attn, weights, q_n, q_r, ckv, block_tables, t, scores, read_all, *,
+                          block_size: int):
+    """Decode's read of a selecting layer: of the places ``<= t (S, 1)`` of
+    each slot's table the ``index_topk`` of largest ``scores (S, 1, L)``
+    (`lax.top_k`), and absorbed attention over those rows of ``ckv`` ->
+    ``o (S, 1, H, v_dim)``; ``weights``: the layer's ``w_uk`` and ``w_uv``.
+    ``read_all`` says, on the device, how the rows are reached: as a mask
+    over everything held (`_attend_pool_absorbed`) or fetched one by one
+    through the table.  A function of its own under `jax.jit` for
+    `_attend_in_pool`'s reason: the two arms traced afresh in each selecting
+    layer of both decode programs were a second of every start."""
+    L = scores.shape[-1]
+    topk = min(attn.index_topk, L)
+    with jax.named_scope("dsa/topk"):
+        causal = jnp.arange(L)[None, :] <= t
+        visible = jnp.where(causal, scores[:, 0], -jnp.inf)
+        best, picks = jax.lax.top_k(visible, topk)
+
+    def in_place():
+        with jax.named_scope("dsa/topk"):
+            # the picks as a mask, by `top_visible`'s rule from the k-th
+            # value: every place above it, and of the places that tie with
+            # it those up to the last one picked (the lower ones)
+            kth = best[:, -1:]
+            last = jnp.where(best == kth, picks, -1).max(axis=-1, keepdims=True)
+            keep = causal & ((visible > kth) | (
+                (visible == kth) & (jnp.arange(L)[None, :] <= last)))
+        with jax.named_scope("mla/attend"):
+            return _attend_pool_absorbed(attn, weights, q_n, q_r, ckv, block_tables, t, keep)
+
+    def fetched():
+        with jax.named_scope("dsa/gather"):
+            blk = jnp.take_along_axis(block_tables, picks // block_size, axis=1)
+            seen = ckv[blk, picks % block_size][..., :attn.row].astype(q_n.dtype)
+        with jax.named_scope("mla/attend"):
+            return attn.absorbed(weights, q_n, q_r, seen, (best > -jnp.inf)[:, None])
+
+    return jax.lax.cond(read_all, in_place, fetched)
+
+
+def _attend_pool_absorbed(attn, params, q_n, q_r, ckv, block_tables, t, keep=None):
+    """``o (S, 1, H, v_dim)`` of ONE query a slot, at place ``t (S, 1)``
+    (-1: a slot that is to read nothing), over the rows its slot holds in
+    ``ckv``, read where they lie (`_attend_rows_in_pool`): the query with
+    ``W_uk`` folded in and padded to the pool's lanes, ``W_uv`` applied to
+    what comes back."""
+    q = jnp.concatenate(
+        [jnp.einsum("shd,hdr->shr", q_n[:, 0], params["w_uk"]), q_r[:, 0]], axis=-1)
+    o_c = _attend_rows_in_pool(_padded(q, ckv.shape[-1]), ckv, block_tables, t[:, 0] + 1,
+                               v_width=attn.kv_rank, scale=attn.scale, keep=keep)
+    return jnp.einsum("shr,hrd->shd", o_c, params["w_uv"])[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("v_width", "scale"))
-def _attend_rows_in_pool(q, pool, block_tables, lengths, *, v_width: int, scale: float):
+def _attend_rows_in_pool(q, pool, block_tables, lengths, *, v_width: int, scale: float,
+                         keep=None):
     """Decode's read of a latent pool: the absorbed query ``q[s]`` ``(S,
     heads, row)`` (``W_uk`` folded in, padded to the pool's lanes) attends
-    the ``lengths[s]`` rows its slot holds -> each head's weighted sum of
-    the rows' first ``v_width`` lanes ``(S, heads, v_width)``, zeros for a
-    slot that holds nothing.  As `_attend_in_pool`: the kernel
+    the ``lengths[s]`` rows its slot holds, or of them those that ``keep
+    (S, L)`` marks -> each head's weighted sum of the rows' first
+    ``v_width`` lanes ``(S, heads, v_width)``, zeros for a slot that holds
+    or keeps nothing.  As `_attend_in_pool`: the kernel
     (`ops.paged_latent.paged_latent_decode`) where the program is lowered
     for a TPU, the absorbed form over the gathered view anywhere else; a
     function of its own under `jax.jit`, so that a model's sublayers share
     one trace and one lowered kernel."""
     from tpu_dist.ops.paged_latent import paged_latent_decode
 
-    def view(q, pool, block_tables, lengths):
+    def view(q, pool, block_tables, lengths, keep=None):
         S, L = q.shape[0], block_tables.shape[1] * pool.shape[1]
         rows = pool[block_tables].reshape(S, L, -1).astype(q.dtype)
         logits = scale * jnp.einsum("shc,slc->shl", q, rows,
                                     preferred_element_type=jnp.float32)
-        seen = jnp.arange(L)[None, None, :] < lengths[:, None, None]
-        weights = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1).astype(q.dtype)
+        seen = jnp.arange(L)[None, :] < lengths[:, None]
+        if keep is not None:
+            seen &= keep
+        weights = jax.nn.softmax(jnp.where(seen[:, None], logits, -1e30), axis=-1).astype(q.dtype)
         o = jnp.einsum("shl,slr->shr", weights, rows[..., :v_width])
-        return jnp.where((lengths > 0)[:, None, None], o, 0).astype(q.dtype)
+        return jnp.where(seen.any(axis=1)[:, None, None], o, 0).astype(q.dtype)
 
-    return ops.kernel_for_platform(
-        functools.partial(paged_latent_decode, v_width=v_width, scale=scale),
-        view, q, pool, block_tables, lengths,
-    )
+    def kernel(q, pool, block_tables, lengths, keep=None):
+        return paged_latent_decode(q, pool, block_tables, lengths, v_width=v_width,
+                                   scale=scale, keep=keep)
+
+    masks = () if keep is None else (keep,)
+    return ops.kernel_for_platform(kernel, view, q, pool, block_tables, lengths, *masks)
 
 
 def _whole_latent_attention(attn, params, x, ckv, block_tables, positions,
@@ -556,12 +634,7 @@ def _whole_latent_attention(attn, params, x, ckv, block_tables, positions,
     t = jnp.where(write_mask, positions, -1)      # a masked query sees nothing
     with jax.named_scope("mla/attend"):
         if s == 1:
-            q = jnp.concatenate(
-                [jnp.einsum("shd,hdr->shr", q_n[:, 0], params["w_uk"]), q_r[:, 0]], axis=-1)
-            o_c = _attend_rows_in_pool(
-                _padded(q, ckv.shape[-1]), ckv, block_tables, t[:, 0] + 1,
-                v_width=attn.kv_rank, scale=attn.scale)
-            o = jnp.einsum("shr,hrd->shd", o_c, params["w_uv"])[:, None]
+            o = _attend_pool_absorbed(attn, params, q_n, q_r, ckv, block_tables, t)
         else:
             seen = ckv[block_tables].reshape(S, L, -1)[..., :attn.row].astype(x.dtype)
             causal = jnp.arange(L)[None, None, :] <= t[:, :, None]
