@@ -1052,6 +1052,37 @@ class TestASelectingLayerReadsInPlaceOrFetchesOnTheV5e:
         assert "dsa/gather" in fetched and "dsa/gather" not in in_place
         assert "dsa/topk" in in_place and "mla/attend" in in_place and "mla/attend" in fetched
 
+    def test_only_the_arm_that_fetches_sorts_the_index_scores(self, v5e_latent_engine, v5e_chip):
+        """The picks' mask needs two numbers a row, which the arm that
+        reads in place finds by two searches (`ops.kth_score`: two loops of
+        compare-and-count passes under ``dsa/topk``); the
+        ``sort`` that `lax.top_k` of the ``(S, L)`` scores compiles to is
+        left where the picks are needed as indices, in the arm that fetches
+        their rows."""
+        import re
+
+        text, _, _ = _compiled_for_the_v5e(v5e_latent_engine, v5e_chip,
+                                           "serve_decode_greedy", None)
+        with_callees = _with_callees(text)
+        cond, = [line for line in text.splitlines() if re.search(r" conditional\(", line)]
+        arms = re.search(r"branch_computations=\{(%\S+), (%\S+)\}", cond).groups()
+        fetched, in_place = ("\n".join(with_callees(arm)) for arm in arms)
+        sort = re.compile(r"= \(f32\[4,64\]\S*, s32\[4,64\]\S*\) sort\(.*dsa/topk")
+        search = re.compile(r" while\(.*dsa/topk.*kth_and_last")
+        assert len(search.findall(in_place)) == 2 and not sort.search(in_place)
+        assert sort.search(fetched) and "kth_and_last" not in fetched
+        assert len(sort.findall(text)) == 1 and len(search.findall(text)) == 2
+
+    def test_a_prefill_chunk_selects_without_a_sort(self, v5e_latent_engine, v5e_chip):
+        """A chunk's rows go through the same two searches: no ``sort`` and
+        no running count of the ties under ``dsa/topk``."""
+        import re
+
+        text, _, _ = _compiled_for_the_v5e(v5e_latent_engine, v5e_chip, "serve_prefill", 2)
+        under = [line for line in text.splitlines() if "dsa/topk" in line]
+        assert len([line for line in under if re.search(r" while\(.*kth_and_last", line)]) == 2
+        assert not [line[:160] for line in under if re.search(r" (sort|reduce-window)\(", line)]
+
     def test_the_mask_is_an_operand_only_where_a_layer_selects(
             self, v5e_latent_engine, v5e_shortcut_engine, v5e_chip):
         """The grid's bound, six tables, the queries and the pool once a

@@ -202,6 +202,44 @@ def test_prefill_then_decode_logits_are_the_references(model, chunk):
         np.testing.assert_allclose(mine, want, atol=ATOL)
 
 
+@pytest.fixture
+def searched(monkeypatch):
+    """-> the shapes every selection's search (`ops.kth_score`) was handed."""
+    from tpu_dist.nn import latent_attention
+    from tpu_dist.serve import paged_kv
+
+    shapes, kth_and_last = [], latent_attention.kth_and_last
+
+    def search(visible, k):
+        shapes.append(visible.shape)
+        return kth_and_last(visible, k)
+
+    monkeypatch.setattr(latent_attention, "kth_and_last", search)
+    paged_kv._attend_picks_in_pool.clear_cache()     # traced before, it would not call `search`
+    yield shapes
+    paged_kv._attend_picks_in_pool.clear_cache()     # nor keep the trace that does
+
+
+@pytest.mark.parametrize("chunk", [16, 12])
+def test_the_searched_selection_serves_the_whole_sequences_tokens(model, searched, chunk):
+    """Chunked prefill and decode against the whole-sequence forward, all
+    three selecting through the search: the same tokens, the same logits.
+    (Decode's calls hold little over what they select here, so they read
+    their pools in place: the arm that fetches its picks, and sorts for
+    them, is traced and never taken.)"""
+    lm, params, _ = model
+    prompts = [_tokens((n,), seed=n) for n in (21, 8, 33)]
+    got = _serve_logits(lm, params, prompts, new=10, chunk=chunk, slots=[2, 0, 3])
+    paged = set(searched)
+    assert paged == {(4, 64), (3 * chunk, 64), (2 * chunk, 64), (chunk, 64)}
+    for prompt, mine in zip(prompts, got):
+        seq = np.concatenate([prompt, mine.argmax(-1)[:-1].astype(np.int32)])
+        whole = np.asarray(lm.apply(params, {}, seq[None])[0][0, prompt.size - 1:])
+        assert mine.argmax(-1).tolist() == whole.argmax(-1).tolist()
+        np.testing.assert_allclose(mine, whole, atol=ATOL)
+        assert (seq.size, seq.size) in set(searched) - paged
+
+
 def test_a_ring_too_short_for_the_chunk_is_refused(model):
     lm, params, _ = model
     with pytest.raises(ValueError, match="a ring of 24 rows"):

@@ -32,8 +32,8 @@ the first ``rope_dim`` values rotated), ``k^I = LayerNorm(x W_ik)`` (the
 same rotation; the second thing a token leaves behind), ``w = x W_iw /
 sqrt(index_heads)``, ``I(t, j) = sum_i w_i(t) relu(q^I_i(t) . k^I(j)) /
 sqrt(index_dim)`` in float32; visible are the ``index_topk`` causal ``j``
-of largest ``I(t, j)``, ties to the lower ``j`` (`top_visible`,
-`lax.top_k`'s own order).
+of largest ``I(t, j)``, ties to the lower ``j`` (`top_visible`:
+`lax.top_k`'s own order, found by `ops.kth_score` without its sort).
 
 ``W_ukv`` is kept as its two parts by head, ``w_uk (H, nope_dim, kv_rank)``
 and ``w_uv (H, kv_rank, v_dim)``, the operands of the absorbed form's two
@@ -59,6 +59,7 @@ from jax import lax
 from tpu_dist.nn.core import Module
 from tpu_dist.nn.layers import RMSNorm
 from tpu_dist.ops import SCORE_BYTES
+from tpu_dist.ops.kth_score import kth_and_last
 
 F32 = jnp.float32
 INDEX_EPS = 1e-6   # of the LayerNorm over the indexer's key
@@ -87,14 +88,16 @@ def top_visible(scores, causal, k: int):
     """Of the ``causal`` places of each row of ``scores (..., L)`` the
     ``k`` of largest score, ties to the lower index (the picks of
     `lax.top_k`, as a mask); all of them where there are no more than
-    ``k``."""
-    if k >= scores.shape[-1]:
+    ``k``.  The set is stated by its k-th value and the last place that
+    ties with it and is picked, which `ops.kth_score.kth_and_last` finds
+    without sorting."""
+    L = scores.shape[-1]
+    if k >= L:
         return causal
     s = jnp.where(causal, scores, -jnp.inf)
-    kth = lax.top_k(s, k)[0][..., -1:]
-    above, tie = s > kth, s == kth
-    room = k - above.sum(-1, keepdims=True)
-    return causal & (above | (tie & (jnp.cumsum(tie, axis=-1) <= room)))
+    kth, last = (found.reshape(s.shape[:-1] + (1,))
+                 for found in kth_and_last(s.reshape(-1, L), k))
+    return causal & ((s > kth) | ((s == kth) & (jnp.arange(L) <= last)))
 
 
 class LatentAttention(Module):

@@ -464,19 +464,21 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
     """A key-selecting latent layer against its two pools (the contract
     of `_paged_attention`; ``pools = {"ckv", "ik"}``).  Each new token's
     latent row and index key are scattered as `_paged_attention` scatters
-    k/v; every query then scores the index keys its slot holds.  ONE
-    query a slot (decode): `lax.top_k` of the scores, then absorbed
-    attention over the picks, their rows reached one of two ways by what
-    the call holds (one `lax.cond` on its own counts).  Where it holds no
-    more than `READ_ALL_UNDER` rows for each it selects, the picks go as a
-    mask with the absorbed query to `_attend_rows_in_pool`, which reads
-    every held row where it lies, a block a fetch; where its contexts are
-    long against ``index_topk``, the picked rows of ``ckv`` are fetched
-    through the block table one by one and attended as fetched (a slot
-    that holds fewer than ``index_topk`` masks the rest).  The same rows
-    get weight either way, the others none.  SEVERAL (a prefill chunk):
-    the same picks as a mask over the gathered view, scores and attention
-    walked over it only as far as the call's longest context reaches.
+    k/v; every query then scores the index keys its slot holds and picks
+    the ``index_topk`` places of largest score (`top_visible`'s set).  ONE
+    query a slot (decode): absorbed attention over the picks, their rows
+    reached one of two ways by what the call holds (one `lax.cond` on its
+    own counts).  Where it holds no more than `READ_ALL_UNDER` rows for
+    each it selects, the picks go as a mask (found without a sort:
+    `ops.kth_score`) with the absorbed query to `_attend_rows_in_pool`,
+    which reads every held row where it lies, a block a fetch; where its
+    contexts are long against ``index_topk``, the picks are taken as
+    indices (`lax.top_k`) and their rows of ``ckv`` fetched through the
+    block table one by one and attended as fetched (a slot that holds fewer
+    than ``index_topk`` masks the rest).  The same rows get weight either
+    way, the others none.  SEVERAL (a prefill chunk): the same picks as a
+    mask over the gathered view, scores and attention walked over it only
+    as far as the call's longest context reaches.
     Returns ``(y, pools, (keys scored, rows selected, rows read))``, the
     counts over the real queries: rows read are the rows held where the
     read lay a mask over them, the rows selected where it fetched those."""
@@ -525,34 +527,32 @@ def _paged_latent_attention(attn, params, x, pools, block_tables, positions,
 def _attend_picks_in_pool(attn, weights, q_n, q_r, ckv, block_tables, t, scores, read_all, *,
                           block_size: int):
     """Decode's read of a selecting layer: of the places ``<= t (S, 1)`` of
-    each slot's table the ``index_topk`` of largest ``scores (S, 1, L)``
-    (`lax.top_k`), and absorbed attention over those rows of ``ckv`` ->
-    ``o (S, 1, H, v_dim)``; ``weights``: the layer's ``w_uk`` and ``w_uv``.
-    ``read_all`` says, on the device, how the rows are reached: as a mask
-    over everything held (`_attend_pool_absorbed`) or fetched one by one
-    through the table.  A function of its own under `jax.jit` for
+    each slot's table the ``index_topk`` of largest ``scores (S, 1, L)``,
+    ties to the lower place, and absorbed attention over those rows of
+    ``ckv`` -> ``o (S, 1, H, v_dim)``; ``weights``: the layer's ``w_uk`` and
+    ``w_uv``.  ``read_all`` says, on the device, how the rows are reached:
+    as a mask over everything held (`top_visible`, which sorts nothing,
+    then `_attend_pool_absorbed`) or, the picks as indices
+    (`lax.top_k`: this arm alone sorts), fetched one by one through the
+    table.  A function of its own under `jax.jit` for
     `_attend_in_pool`'s reason: the two arms traced afresh in each selecting
     layer of both decode programs were a second of every start."""
     L = scores.shape[-1]
     topk = min(attn.index_topk, L)
-    with jax.named_scope("dsa/topk"):
-        causal = jnp.arange(L)[None, :] <= t
-        visible = jnp.where(causal, scores[:, 0], -jnp.inf)
-        best, picks = jax.lax.top_k(visible, topk)
+    causal = jnp.arange(L)[None, :] <= t
 
     def in_place():
         with jax.named_scope("dsa/topk"):
-            # the picks as a mask, by `top_visible`'s rule from the k-th
-            # value: every place above it, and of the places that tie with
-            # it those up to the last one picked (the lower ones)
-            kth = best[:, -1:]
-            last = jnp.where(best == kth, picks, -1).max(axis=-1, keepdims=True)
-            keep = causal & ((visible > kth) | (
-                (visible == kth) & (jnp.arange(L)[None, :] <= last)))
+            # the picks as a mask: every place above the k-th value and, of
+            # the places that tie with it, those up to the last one picked
+            # (the lower ones): two numbers a row, found without a sort
+            keep = top_visible(scores[:, 0], causal, topk)
         with jax.named_scope("mla/attend"):
             return _attend_pool_absorbed(attn, weights, q_n, q_r, ckv, block_tables, t, keep)
 
     def fetched():
+        with jax.named_scope("dsa/topk"):
+            best, picks = jax.lax.top_k(jnp.where(causal, scores[:, 0], -jnp.inf), topk)
         with jax.named_scope("dsa/gather"):
             blk = jnp.take_along_axis(block_tables, picks // block_size, axis=1)
             seen = ckv[blk, picks % block_size][..., :attn.row].astype(q_n.dtype)
